@@ -54,7 +54,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.analysis import contracts
 from repro.analysis.contracts import (
@@ -64,7 +64,6 @@ from repro.analysis.contracts import (
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.core import population as pop
 from repro.core import shardplan
-from repro.core.compat import make_mesh
 from repro.core.layer_index import infer_layer_ids, total_layers
 from repro.core.mixing import MixingConfig, mixing_due, static_mix_comm
 from repro.optim import make_optimizer
@@ -177,7 +176,7 @@ def check_train_chunk() -> Dict[str, Any]:
     from repro.train import engine as T
 
     _require_devices()
-    mesh = make_mesh((_N,), ("ens",))
+    mesh = jax.make_mesh((_N,), ("ens",), axis_types=(AxisType.Auto,))
     key = jax.random.key(0)
     mcfg = MixingConfig(kind="wash", base_p=0.5, mode="bucketed")
     tcfg = _toy_tcfg()
@@ -249,7 +248,8 @@ def check_pipelined_train() -> Dict[str, Any]:
 
     _require_devices()
     S = 2
-    mesh = make_mesh((_N, S), ("ens", "pipe"))
+    mesh = jax.make_mesh((_N, S), ("ens", "pipe"),
+                         axis_types=(AxisType.Auto,) * 2)
     key = jax.random.key(0)
     mcfg = MixingConfig(kind="wash", base_p=0.5, mode="bucketed")
     tcfg = _toy_tcfg()

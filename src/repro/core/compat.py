@@ -1,64 +1,14 @@
-"""Version compatibility for the distributed jax APIs.
+"""Backend-dependent defaults shared by the kernels and serving runtimes.
 
-The distributed path (fused engine, dry-run, mesh constructors) is written
-against the modern surface — ``jax.shard_map``, ``jax.make_mesh(...,
-axis_types=...)``, ``jax.set_mesh`` — but must also run on jax 0.4.x where
-those live under ``jax.experimental.shard_map`` / don't exist yet.  Every
-call site imports the shims from here instead of feature-testing inline.
+Both resolve an "auto" choice from the backend JAX runs on: buffer
+donation and Pallas interpret mode are TPU/GPU-only concerns that the CPU
+test suite must bypass.  Nothing on the chip path relies on the fallback:
+``chip_smoke.py`` refuses to run unless the backend is ``tpu``.
 """
 
 from __future__ import annotations
 
-import contextlib
-from typing import Any
-
 import jax
-from jax import lax
-
-Specs = Any
-
-
-def axis_size(axis_name) -> int:
-    """Static mesh-axis size inside shard_map (``lax.axis_size`` is newer
-    than 0.4.x; ``psum`` of a literal takes the static fast path)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with fallback to ``jax.experimental.shard_map``.
-
-    ``check_vma`` maps onto the old API's ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
-def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where supported; falls back
-    to ``mesh_utils`` + ``Mesh`` on jax versions without ``make_mesh``."""
-    try:
-        from jax.sharding import AxisType
-
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    except (ImportError, TypeError, AttributeError):
-        pass
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    from jax.experimental import mesh_utils
-    from jax.sharding import Mesh
-
-    return Mesh(mesh_utils.create_device_mesh(shape), axes)
 
 
 def donate_argnums(argnums):
@@ -75,12 +25,3 @@ def resolve_interpret(interpret) -> bool:
     if interpret is None:
         return jax.default_backend() != "tpu"
     return interpret
-
-
-def use_mesh(mesh):
-    """Ambient-mesh context: ``jax.set_mesh`` or the legacy ``with mesh:``."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(mesh, "__enter__"):
-        return mesh
-    return contextlib.nullcontext()

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import shuffle as shf
-from repro.core.compat import axis_size
 from repro.core.schedules import active_window
 
 PyTree = Any
@@ -232,7 +231,7 @@ def mix_collective(
     if cfg.kind == "none" or not active_window(step, cfg.start_step, cfg.stop_step):
         return params, opt_state, zero
 
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     d = sum(x.size for x in jax.tree_util.tree_leaves(params))
 
     if cfg.kind in ("wash", "wash_opt"):
@@ -297,7 +296,7 @@ def mix_collective_blocked(
         return params, opt_state
 
     n_local = jax.tree_util.tree_leaves(params)[0].shape[0]
-    n = n_local * axis_size(axis_name)
+    n = n_local * lax.axis_size(axis_name)
 
     def _gated(new_tree, old_tree):
         return jax.tree_util.tree_map(

@@ -793,7 +793,6 @@ def make_shardlocal_mixer(
     should use :func:`static_shard_mix_comm` host-side, as the fused
     engine does.
     """
-    from repro.core.compat import shard_map
 
     def _strip(spec):
         return P(*tuple(spec)[1:])
@@ -842,9 +841,9 @@ def make_shardlocal_mixer(
         )
         return new_pop, new_opt, jnp.asarray(n * comm, jnp.float32)
 
-    return shard_map(
+    return jax.shard_map(
         mixer,
-        mesh,
+        mesh=mesh,
         in_specs=(pop_specs, opt_specs, P()),
         out_specs=(pop_specs, opt_specs, P()),
         check_vma=False,

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.compat import axis_size
 from repro.core.schedules import layer_probability, layer_probability_array
 
 PyTree = Any
@@ -182,7 +181,7 @@ def bucketed_apply_collective(
     (n+s)-th neighbour).  Total send volume per member per step:
     k_per * (N-1) scalars = p·d·(N-1)/N — the paper's Table 1 accounting.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     out = x_flat
     for s in range(1, n):
         vals = x_flat[idx[s]]
@@ -216,7 +215,7 @@ def bucketed_apply_collective_blocked(
     m == 1 → jnp.roll (the stacked reference), n_local == 1 → the
     per-member :func:`bucketed_apply_collective`.
     """
-    m = axis_size(axis_name)
+    m = lax.axis_size(axis_name)
     n_local = x_flat.shape[0]
     n = n_local * m
     out = x_flat
@@ -365,7 +364,7 @@ def apply_plan_collective_blocked(
     exchanges always take the ``ppermute`` path — the kernel is a local
     HBM-pass optimization, not a collective.
     """
-    pallas_ok = use_pallas and axis_size(axis_name) == 1
+    pallas_ok = use_pallas and lax.axis_size(axis_name) == 1
 
     def _one(p, leaf):
         if p is None:

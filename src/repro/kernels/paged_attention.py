@@ -15,11 +15,18 @@ contiguous copy of the context ever exists.  Accumulation across a slot's
 pages is the standard streaming softmax (running max / sum / accumulator
 in VMEM scratch, carried across the sequential page axis of the grid).
 
-Layout per grid step ``(b·KV + k, j)``: one (slot, kv-head) pair holds its
-``g = H // KV`` query rows in VMEM and visits page ``page_table[b, j]``.
-Pages past a slot's length are skipped with ``pl.when`` (no DMA'd page is
-wasted on fully-masked work beyond the first); intra-page tail positions
-are masked with position arithmetic.
+Layout per grid step ``(b, j)``: slot ``b`` holds all ``H`` query rows
+in VMEM and visits page ``page_table[b, j]`` with every kv head at once.
+The pool is viewed as ``(P, page_size·KV, hd)`` (a free reshape of the
+contiguous ``(P, page_size, KV, hd)`` layout), so each page is one
+``(page_size·KV, hd)`` tile: its two minor dims are whole arrays or
+multiples of every dtype's (sublane, lane) tiling, which a block that
+squeezes the KV axis out of the pool's second-minor dim is not.  One
+``(H, page_size·KV)`` score matmul covers the page; scores that pair a
+query with another group's kv head are masked like positions past the
+slot's length (GQA groups are ``g = H // KV`` consecutive query rows).
+Pages past a slot's length are skipped with ``pl.when`` (no VPU/MXU work
+is spent on fully-masked pages).
 
 ``interpret=None`` auto-detects like ``wash_shuffle``: compiled on TPU,
 interpret mode elsewhere (CPU timings are correctness-only).  The pure-jnp
@@ -44,7 +51,7 @@ NEG_INF = -1e30
 
 def _paged_kernel(
     *refs,
-    kv: int, page_size: int, scale: float, quantized: bool,
+    page_size: int, scale: float, quantized: bool,
 ):
     # scalar-prefetch refs lead: pt (B, max_pages) i32, lengths (B,) i32,
     # then — quantized pools only — per-slot-per-page dequant scales
@@ -57,12 +64,15 @@ def _paged_kernel(
         pt_ref, len_ref = refs[:2]
         ks_ref = vs_ref = None
         refs = refs[2:]
-    q_ref, k_ref, v_ref = refs[:3]   # (g, hd), (page_size, hd), (page_size, hd)
-    o_ref = refs[3]                  # (g, hd)
-    acc_ref, m_ref, l_ref = refs[4:]  # VMEM scratch: (g, hd), (g, 1), (g, 1)
+    # (1, R) kv head / in-page position of each page row, (H, 1) kv head
+    # of each query row; R = page_size * KV
+    col_head_ref, col_pos_ref, row_head_ref = refs[:3]
+    q_ref, k_ref, v_ref = refs[3:6]  # (H, hd), (R, hd), (R, hd)
+    o_ref = refs[6]                  # (H, hd)
+    acc_ref, m_ref, l_ref = refs[7:]  # VMEM scratch: (H, hd), (H, 1), (H, 1)
 
+    b = pl.program_id(0)
     j = pl.program_id(1)
-    b = pl.program_id(0) // kv
     length = len_ref[b]
 
     @pl.when(j == 0)
@@ -75,16 +85,17 @@ def _paged_kernel(
     # happened, but no VPU/MXU work is spent on fully-masked scores)
     @pl.when(j * page_size < length)
     def _page():
-        g = q_ref.shape[0]
         q = q_ref[...].astype(jnp.float32) * scale
         k = k_ref[...].astype(jnp.float32)
         if quantized:
             k = k * ks_ref[b, j]
-        scores = q @ k.T  # (g, page_size)
-        tpos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (g, page_size), 1
+        scores = jax.lax.dot_general(           # q @ k.T: (H, R)
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
-        scores = jnp.where(tpos < length, scores, NEG_INF)
+        valid = ((col_head_ref[...] == row_head_ref[...])
+                 & (j * page_size + col_pos_ref[...] < length))
+        scores = jnp.where(valid, scores, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -93,7 +104,8 @@ def _paged_kernel(
         vpage = v_ref[...].astype(jnp.float32)
         if quantized:
             vpage = vpage * vs_ref[b, j]
-        acc_ref[...] = acc_ref[...] * alpha + p @ vpage
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p, vpage, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(j == pl.num_programs(1) - 1)
@@ -140,45 +152,51 @@ def paged_attention_pallas(
     if quantized != (v_scale is not None):
         raise ValueError("pass both k_scale and v_scale, or neither")
 
-    qh = q.reshape(B * KV, g, hd)
+    R = page_size * KV
     pt = page_table.astype(jnp.int32)
     kernel = functools.partial(
-        _paged_kernel, kv=KV, page_size=page_size, scale=scale,
-        quantized=quantized,
+        _paged_kernel, page_size=page_size, scale=scale, quantized=quantized,
     )
+    rows = jnp.arange(R, dtype=jnp.int32)[None, :]
+    masks = (rows % KV, rows // KV,
+             jnp.arange(H, dtype=jnp.int32)[:, None] // g)
     # quantized pools prepend two more scalar-prefetch operands (dequant
     # scales pre-gathered to (B, max_pages)); index-map lambdas take one
     # trailing arg per prefetch operand
     n_pref = 4 if quantized else 2
-    def _q_map(h, j, *pref):
-        return (h, 0, 0)
 
-    def _page_map(h, j, *pref):
-        return (pref[0][h // KV, j], 0, h % KV, 0)
+    def _whole(a):
+        return pl.BlockSpec(a.shape, lambda b, j, *pref: (0, 0))
+
+    def _q_map(b, j, *pref):
+        return (b, 0, 0)
+
+    def _page_map(b, j, *pref):
+        return (pref[0][b, j], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_pref,
-        grid=(B * KV, max_pages),
-        in_specs=[
-            pl.BlockSpec((None, g, hd), _q_map),
-            pl.BlockSpec((None, page_size, None, hd), _page_map),
-            pl.BlockSpec((None, page_size, None, hd), _page_map),
+        grid=(B, max_pages),
+        in_specs=[_whole(a) for a in masks] + [
+            pl.BlockSpec((None, H, hd), _q_map),
+            pl.BlockSpec((None, R, hd), _page_map),
+            pl.BlockSpec((None, R, hd), _page_map),
         ],
-        out_specs=pl.BlockSpec((None, g, hd), _q_map),
+        out_specs=pl.BlockSpec((None, H, hd), _q_map),
         scratch_shapes=[
-            pltpu.VMEM((g, hd), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((H, hd), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
         ],
     )
     prefetch = (pt, lengths.astype(jnp.int32))
     if quantized:
         prefetch += (k_scale[pt].astype(jnp.float32),
                      v_scale[pt].astype(jnp.float32))
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * KV, g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
         interpret=resolve_interpret(interpret),
-    )(*prefetch, qh, k_pool, v_pool)
-    return out.reshape(B, H, hd)
+    )(*prefetch, *masks, q, k_pool.reshape(P, R, hd),
+      v_pool.reshape(P, R, hd))

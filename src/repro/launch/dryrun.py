@@ -220,8 +220,7 @@ def compile_once(cfg: ModelConfig, shape: InputShape, mesh, wash: int = 0,
 
     if cfg.shard_hints:
         # with_sharding_constraint(P(...)) needs an ambient mesh
-        from repro.core.compat import use_mesh
-        with use_mesh(mesh), hints.use_hints(data_axes(mesh), "model"):
+        with jax.set_mesh(mesh), hints.use_hints(data_axes(mesh), "model"):
             return _compile_inner(cfg, shape, mesh, wash, mixing_kind, chips,
                                   params_sds, pspecs)
     with contextlib.nullcontext():

@@ -34,8 +34,10 @@ _COLLECTIVES = (
     "all-to-all",
     "collective-permute",
 )
+# the shape group admits the "/*index=5*/" comments XLA prints inside
+# tuple shapes of six or more elements (a combined all-reduce on the TPU)
 _OP_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\([^)]*\)|[\w\[\]{},:()#* ]+?)\s+"
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\([^)]*\)|[\w\[\]{},:()#*/= ]+?)\s+"
     r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)(-start|-done)?\(",
 )
 

@@ -12,12 +12,11 @@ device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
-
-from repro.core.compat import make_mesh
+from jax.sharding import AxisType
 
 
 def _mk(shape, axes):
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def _largest_divisor(x: int, cap: int) -> int:

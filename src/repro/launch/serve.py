@@ -49,6 +49,7 @@ import numpy as np
 from repro.configs import get_arch
 from repro.configs.base import TrainConfig
 from repro.core.mixing import MixingConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.specs import concrete_batch
 from repro.models import transformer as M
 from repro.serving import batching
@@ -332,6 +333,7 @@ def main(argv=None):
                          "instrumented spans into this directory (bounded "
                          "window; view with TensorBoard or Perfetto)")
     args = ap.parse_args(argv)
+    print(f"compile cache: {enable_compile_cache()}")
 
     from repro import obs
 
@@ -386,12 +388,13 @@ def main(argv=None):
     if args.pp_stages:
         if args.mesh != "none":
             ap.error("--pp-stages builds its own (pipe,) mesh; drop --mesh")
-        from repro.core.compat import make_mesh
+        from jax.sharding import AxisType
 
         if args.pp_stages < 1 or args.pp_stages > len(jax.devices()):
             ap.error(f"--pp-stages {args.pp_stages} needs that many "
                      f"devices; this host has {len(jax.devices())}")
-        mesh = make_mesh((args.pp_stages,), ("pipe",))
+        mesh = jax.make_mesh((args.pp_stages,), ("pipe",),
+                             axis_types=(AxisType.Auto,))
         print(f"mesh: {dict(mesh.shape)}")
     elif args.mesh == "data":
         from repro.launch.mesh import make_host_data_mesh

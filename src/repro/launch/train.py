@@ -30,6 +30,7 @@ from repro.configs import INPUT_SHAPES_BY_NAME, get_arch
 from repro.configs.base import TrainConfig
 from repro.core.mixing import MixingConfig
 from repro.data import make_lm_task, sample_tokens
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.specs import concrete_batch
 from repro.models import transformer as M
 from repro.serving import averaged_params
@@ -138,6 +139,7 @@ def main(argv=None):
                          "instrumented spans into this directory (bounded "
                          "window; view with TensorBoard or Perfetto)")
     args = ap.parse_args(argv)
+    print(f"compile cache: {enable_compile_cache()}")
 
     from repro import obs
 

@@ -30,6 +30,8 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+import jax
+
 from .metrics import Registry, DEFAULT_TIME_EDGES
 from .profiler import ProfileWindow
 
@@ -46,18 +48,12 @@ def provenance() -> Dict:
     info: Dict = {"kind": "provenance",
                   "ts": time.time(),
                   "timestamp": time.strftime("%Y-%m-%dT%H:%M:%S%z")}
-    try:
-        import jax
-
-        dev = jax.devices()[0]
-        info.update(jax_version=jax.__version__,
-                    backend=jax.default_backend(),
-                    device_kind=dev.device_kind,
-                    device_count=jax.device_count(),
-                    platform=dev.platform)
-    except Exception:  # jax absent or not initialisable: still stamp time
-        info.update(jax_version=None, backend=None, device_kind=None,
-                    device_count=None, platform=None)
+    dev = jax.devices()[0]
+    info.update(jax_version=jax.__version__,
+                backend=jax.default_backend(),
+                device_kind=dev.device_kind,
+                device_count=jax.device_count(),
+                platform=dev.platform)
     return info
 
 

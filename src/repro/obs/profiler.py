@@ -3,15 +3,16 @@
 The window starts a ``jax.profiler`` trace on construction and stops it
 after ``max_spans`` instrumented spans have passed through — an
 unconditional bound so a long training run can't fill the disk with
-profile data.  Everything is wrapped defensively: if the profiler
-backend is unavailable (some CPU wheels, already-active trace), the
-window degrades to a no-op instead of failing the run.
+profile data.  A trace that was asked for and cannot start (no profiler
+backend, another trace already active) raises: a run that silently
+drops its profile would look like a profiled run.
 """
 
 from __future__ import annotations
 
 import threading
-from typing import Optional
+
+import jax
 
 __all__ = ["ProfileWindow"]
 
@@ -21,15 +22,9 @@ class ProfileWindow:
         self.logdir = logdir
         self.max_spans = max_spans
         self._spans = 0
-        self._active = False
         self._lock = threading.Lock()
-        try:
-            import jax
-
-            jax.profiler.start_trace(logdir)
-            self._active = True
-        except Exception:
-            self._active = False
+        jax.profiler.start_trace(logdir)
+        self._active = True
 
     @property
     def active(self) -> bool:
@@ -40,12 +35,7 @@ class ProfileWindow:
         is open, else None."""
         if not self._active:
             return None
-        try:
-            import jax
-
-            return jax.profiler.TraceAnnotation(name)
-        except Exception:
-            return None
+        return jax.profiler.TraceAnnotation(name)
 
     def tick(self) -> None:
         """Count one completed span; close the window at the bound."""
@@ -64,9 +54,4 @@ class ProfileWindow:
         if not self._active:
             return
         self._active = False
-        try:
-            import jax
-
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
+        jax.profiler.stop_trace()

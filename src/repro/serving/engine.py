@@ -69,7 +69,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import obs
 from repro.configs.base import ModelConfig
 from repro.core import averaging
-from repro.core.compat import donate_argnums, shard_map
+from repro.core.compat import donate_argnums
 from repro.core import population as pop
 from repro.models import transformer as M
 
@@ -328,7 +328,7 @@ def _build_staged_prefill(cfg: ModelConfig, stages: int, B: int, S: int,
         return logits, cache
 
     bspecs = {"tokens": P()}
-    f = shard_map(
+    f = jax.shard_map(
         program, mesh=mesh, in_specs=(pspecs, bspecs),
         out_specs=(P(), cspecs), check_vma=False,
     )
@@ -356,7 +356,7 @@ def _build_staged_decode(cfg: ModelConfig, stages: int, B: int, S: int,
         )
         return jax.lax.dynamic_update_slice(buf, new_toks, (0, S + 1)), cache
 
-    f = shard_map(
+    f = jax.shard_map(
         program, mesh=mesh,
         in_specs=(pspecs, P(), cspecs, P(), P(), P()),
         out_specs=(P(), cspecs), check_vma=False,

@@ -51,26 +51,23 @@ def constrain(x, kind: str):
         return x
     data_axes, model_axis = axes
     da = data_axes if len(data_axes) > 1 else (data_axes[0] if data_axes else None)
-    try:
-        if kind == "moe_buffer":
-            spec = P(da, model_axis, None, None)
-        elif kind == "moe_buffer_local":
-            # groups data-sharded, experts replicated: the dispatch scatter
-            # stays device-local (each model-axis replica redundantly builds
-            # its copy); the subsequent moe_buffer reshard is a local slice.
-            spec = P(da, None, None, None)
-        elif kind == "moe_buffer_global":
-            spec = P(model_axis, None, None)
-        elif kind == "moe_group_dm":
-            # one token-group per chip: dispatch scatters stay fully local
-            # and the expert exchange is a true all-to-all (G over BOTH axes)
-            spec = P(tuple(data_axes) + (model_axis,), None, None, None)
-        elif kind == "tokens_dm":
-            spec = P(tuple(data_axes) + (model_axis,), None, None)
-        elif kind == "activations":
-            spec = P(da, None, None)
-        else:
-            return x
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
-        return x
+    if kind == "moe_buffer":
+        spec = P(da, model_axis, None, None)
+    elif kind == "moe_buffer_local":
+        # groups data-sharded, experts replicated: the dispatch scatter
+        # stays device-local (each model-axis replica redundantly builds
+        # its copy); the subsequent moe_buffer reshard is a local slice.
+        spec = P(da, None, None, None)
+    elif kind == "moe_buffer_global":
+        spec = P(model_axis, None, None)
+    elif kind == "moe_group_dm":
+        # one token-group per chip: dispatch scatters stay fully local
+        # and the expert exchange is a true all-to-all (G over BOTH axes)
+        spec = P(tuple(data_axes) + (model_axis,), None, None, None)
+    elif kind == "tokens_dm":
+        spec = P(tuple(data_axes) + (model_axis,), None, None)
+    elif kind == "activations":
+        spec = P(da, None, None)
+    else:
+        raise ValueError(f"unknown sharding hint kind {kind!r}")
+    return jax.lax.with_sharding_constraint(x, spec)

@@ -55,7 +55,6 @@ from repro import obs
 from repro.configs.base import TrainConfig
 from repro.core import population as pop
 from repro.core import shardplan
-from repro.core.compat import shard_map
 from repro.core.consensus import avg_distance_to_consensus
 from repro.core.layer_index import infer_layer_ids, total_layers
 from repro.core.mixing import (
@@ -265,9 +264,9 @@ def make_fused_chunk_fn(
         )
         return p, s, loss_last
 
-    f = shard_map(
+    f = jax.shard_map(
         chunk_fn,
-        mesh,
+        mesh=mesh,
         in_specs=(pspec, ospec, bspecs, P(), P(), P(), P()),
         out_specs=(pspec, ospec, P()),
         check_vma=False,
@@ -428,14 +427,45 @@ def make_pipelined_chunk_fn(
         )
         return p, s, loss_last
 
-    f = shard_map(
+    f = jax.shard_map(
         chunk_fn,
-        mesh,
+        mesh=mesh,
         in_specs=(pspec, ospec, bspecs, P(), P(), P(), P()),
         out_specs=(pspec, ospec, P()),
         check_vma=False,
     )
     return jax.jit(f, donate_argnums=(0, 1) if donate else ())
+
+
+def _state_shapes(init_fn, opt_init, key, n: int, same_init: bool):
+    """The population + optimizer-state initializer and its output shapes.
+
+    Shapes come from ``eval_shape``: the specs, layer ids and comm
+    accounting are derived before anything is allocated, so the state is
+    built once, directly in its mesh layout (:func:`_init_placed`)."""
+
+    def init_state(k):
+        population = pop.init_population(init_fn, k, n, same_init=same_init)
+        return population, jax.vmap(opt_init)(population)
+
+    population, opt_state = jax.eval_shape(init_state, key)
+    return init_state, population, opt_state
+
+
+def _init_placed(init_state, key, mesh, pspec, ospec):
+    """Run ``init_state`` under a jit whose outputs land in the chunk
+    program's own layout (``NamedSharding(mesh, spec)``): each device
+    materializes only its members, and the first chunk sees the same
+    input shardings as every later one, so it traces once, not twice."""
+    def place(specs):
+        return jax.tree_util.tree_map(
+            lambda s: NamedSharding(mesh, s), specs,
+            is_leaf=lambda x: isinstance(x, P),
+        )
+
+    return jax.jit(
+        init_state, out_shardings=(place(pspec), place(ospec))
+    )(key)
 
 
 def train_population_sharded(
@@ -487,20 +517,19 @@ def train_population_sharded(
             "mesh (e.g. repro.launch.mesh.make_host_mesh) along with them"
         )
 
-    population = pop.init_population(init_fn, key, n, same_init=tcfg.same_init)
-    lids = infer_layer_ids(pop.member(population, 0), num_blocks)
-    tl = total_layers(num_blocks)
-
     opt_init, opt_update = make_optimizer(
         tcfg.optimizer, momentum=tcfg.momentum, weight_decay=tcfg.weight_decay
     )
-    opt_state = jax.vmap(opt_init)(population)
-
+    init_state, population, opt_state = _state_shapes(
+        init_fn, opt_init, key, n, tcfg.same_init
+    )
     # exact per-mix-step comm from the static plan sizes (member template:
     # shapes only, no data copy); never None here — dense WASH was rejected
     member_tpl = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), population
     )
+    lids = infer_layer_ids(member_tpl, num_blocks)
+    tl = total_layers(num_blocks)
 
     use_pallas = pallas_shuffle or mcfg.pallas_shuffle
     if multi:
@@ -535,6 +564,7 @@ def train_population_sharded(
         pop_entry = "ens"
         dp_sizes = 1
     assert comm_per_mix_step is not None
+    population, opt_state = _init_placed(init_state, key, mesh, pspec, ospec)
 
     # Leftover data axes split each member's batch only when EVERY batch
     # leaf's leading dim divides (all-or-nothing, so a split leaf never
@@ -812,8 +842,16 @@ def train_population_pipelined(
             f"mode={mcfg.mode!r}."
         )
 
-    population = pop.init_population(init_fn, key, n, same_init=tcfg.same_init)
-    lids = infer_layer_ids(pop.member(population, 0), num_blocks)
+    opt_init, opt_update = make_optimizer(
+        tcfg.optimizer, momentum=tcfg.momentum, weight_decay=tcfg.weight_decay
+    )
+    init_state, population, opt_state = _state_shapes(
+        init_fn, opt_init, key, n, tcfg.same_init
+    )
+    member_tpl = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), population
+    )
+    lids = infer_layer_ids(member_tpl, num_blocks)
     tl = total_layers(num_blocks)
 
     flat_lids = jax.tree_util.tree_flatten(lids)[0]
@@ -823,22 +861,13 @@ def train_population_pipelined(
             "spanning all blocks along axis 0); this member has only "
             "per-block leaves, which cannot be sharded over the pipe axis"
         )
-    for lid, leaf in zip(flat_lids, jax.tree_util.tree_leaves(
-            pop.member(population, 0))):
+    for lid, leaf in zip(flat_lids, jax.tree_util.tree_leaves(member_tpl)):
         if not isinstance(lid, int) and leaf.shape[0] % S:
             raise ValueError(
                 f"stacked-blocks leaf of {leaf.shape[0]} layers does not "
                 f"split evenly over {S} pipeline stages"
             )
 
-    opt_init, opt_update = make_optimizer(
-        tcfg.optimizer, momentum=tcfg.momentum, weight_decay=tcfg.weight_decay
-    )
-    opt_state = jax.vmap(opt_init)(population)
-
-    member_tpl = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), population
-    )
     member_specs = (
         param_specs if param_specs is not None
         else jax.tree_util.tree_map(lambda _: P(), member_tpl)
@@ -852,6 +881,7 @@ def train_population_pipelined(
     comm_per_mix_step = shardplan.static_shard_mix_comm(
         pplan, opt_state=opt_state
     )
+    population, opt_state = _init_placed(init_state, key, mesh, pspec, ospec)
     pop_entry = (
         pplan.pop_axes[0] if len(pplan.pop_axes) == 1
         else tuple(pplan.pop_axes)

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro import obs
 from repro.configs.base import TrainConfig
 from repro.core import population as pop
+from repro.core.compat import donate_argnums
 from repro.core.consensus import avg_distance_to_consensus
 from repro.core.layer_index import infer_layer_ids, total_layers
 from repro.core.mixing import MixingConfig, mix_once, mixing_due, static_mix_comm
@@ -88,7 +89,9 @@ def train_population(
     )
     opt_state = jax.vmap(opt_init)(population)
 
-    @jax.jit
+    # donated on the chip, where the un-donated step would hold two copies
+    # of the population and its optimizer state at once
+    @functools.partial(jax.jit, donate_argnums=donate_argnums((0, 1)))
     def train_step(population, opt_state, batches, lr):
         def one(p, s, b):
             loss, g = jax.value_and_grad(loss_fn)(p, b)
@@ -98,7 +101,7 @@ def train_population(
         p2, s2, losses = jax.vmap(one, in_axes=(0, 0, 0))(population, opt_state, batches)
         return p2, s2, jnp.mean(losses)
 
-    @functools.partial(jax.jit, static_argnames=())
+    @functools.partial(jax.jit, donate_argnums=donate_argnums((0, 1)))
     def mix_step(population, opt_state, k):
         return mix_once(k, population, opt_state, mcfg, lids, tl)
 

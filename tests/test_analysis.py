@@ -90,6 +90,19 @@ def test_collective_result_dtypes():
                    "all-reduce": {"f32"}}
 
 
+def test_wide_tuple_collective_counted():
+    """XLA numbers the elements of a six-plus-element tuple shape with
+    ``/*index=5*/`` comments; such a combined all-reduce (PAPA's parameter
+    pmean on the TPU) must still be counted, with every element's bytes."""
+    elems = ", ".join(f"bf16[4,{k}]{{1,0:T(8,128)(2,1)}}" for k in range(1, 6))
+    hlo = (f"  %ar = ({elems}, /*index=5*/bf16[4,6]{{1,0}}) "
+           f"all-reduce(%a, %b, %c, %d, %e, %f), replica_groups={{{{0,1}}}}, "
+           f"to_apply=%add\n")
+    assert hlo_stats.collective_counts(hlo)["all-reduce"] == 1
+    assert hlo_stats.collective_bytes(hlo)["all-reduce"] == 4 * 21 * 2
+    assert hlo_stats.collective_result_dtypes(hlo) == {"all-reduce": {"bf16"}}
+
+
 def test_input_output_alias_parsing():
     hlo = ('HloModule m, input_output_alias={ {0}: (0, {}, may-alias), '
            '{1}: (2, {}, must-alias) }, entry_computation_layout={()}\n')

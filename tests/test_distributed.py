@@ -31,7 +31,7 @@ def test_collective_shuffle_equals_stacked_reference():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.core import population as pop
-        from repro.core.compat import make_mesh, shard_map
+        from jax.sharding import AxisType
         from repro.core.mixing import MixingConfig, mix_stacked, mix_collective
         from repro.core.layer_index import infer_layer_ids, total_layers
 
@@ -48,13 +48,13 @@ def test_collective_shuffle_equals_stacked_reference():
         cfg = MixingConfig(kind="wash", base_p=0.5, mode="bucketed")
         ref, _, comm_ref = mix_stacked(1, key, stacked, None, cfg, lids, L)
 
-        mesh = make_mesh((4,), ("ens",))
+        mesh = jax.make_mesh((4,), ("ens",), axis_types=(AxisType.Auto,))
         def member_fn(params):
             params = jax.tree_util.tree_map(lambda x: x[0], params)
             out, _, comm = mix_collective(1, key, params, None, cfg, lids, L, "ens")
             return jax.tree_util.tree_map(lambda x: x[None], out), comm[None]
         specs = jax.tree_util.tree_map(lambda x: P("ens"), stacked)
-        f = shard_map(member_fn, mesh, in_specs=(specs,),
+        f = jax.shard_map(member_fn, mesh=mesh, in_specs=(specs,),
                       out_specs=(specs, P("ens")))
         out, comm = jax.jit(f)(stacked)
         err = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(
@@ -91,8 +91,9 @@ def test_pjit_sharded_population_wash_step_runs():
         cfg = MixingConfig(kind="wash", base_p=0.5, mode="bucketed")
         ref, _, _ = mix_once(key, stacked, None, cfg, lids, L)
 
-        from repro.core.compat import make_mesh
-        mesh = make_mesh((4, 2), ("ens", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4, 2), ("ens", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         sh = jax.tree_util.tree_map(
             lambda x: jax.device_put(x, NamedSharding(mesh, P("ens"))), stacked)
         step = jax.jit(lambda p: mix_once(key, p, None, cfg, lids, L)[0])
@@ -155,7 +156,7 @@ def test_fused_engine_multidevice_matches_reference():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.configs.base import TrainConfig
-        from repro.core.compat import make_mesh, shard_map
+        from jax.sharding import AxisType
         from repro.core import shuffle as shf
         from repro.core.mixing import MixingConfig
         from repro.train import train_population
@@ -195,16 +196,18 @@ def test_fused_engine_multidevice_matches_reference():
         idx = shf.bucketed_plan(jax.random.fold_in(key, 1), D, n, 0.8)
         stacked = shf.bucketed_apply_stacked(x, idx)
         for m in (4, 2, 1):
-            mesh = make_mesh((m,), ("ens",))
-            f = shard_map(
+            mesh = jax.make_mesh((m,), ("ens",), axis_types=(AxisType.Auto,))
+            f = jax.shard_map(
                 lambda xb: shf.bucketed_apply_collective_blocked(xb, idx, "ens"),
-                mesh, in_specs=(P("ens"),), out_specs=P("ens"), check_vma=False)
+                mesh=mesh, in_specs=(P("ens"),), out_specs=P("ens"),
+                check_vma=False)
             err = float(jnp.max(jnp.abs(jax.jit(f)(x) - stacked)))
             assert err == 0.0, (m, err)
-        mesh = make_mesh((4,), ("ens",))
-        f = shard_map(
+        mesh = jax.make_mesh((4,), ("ens",), axis_types=(AxisType.Auto,))
+        f = jax.shard_map(
             lambda xb: shf.bucketed_apply_collective_blocked(xb, idx, "ens"),
-            mesh, in_specs=(P("ens"),), out_specs=P("ens"), check_vma=False)
+            mesh=mesh, in_specs=(P("ens"),), out_specs=P("ens"),
+            check_vma=False)
         txt = jax.jit(f).lower(x).compile().as_text()
         assert "collective-permute" in txt, "fused shuffle did not lower to ppermute"
         print("OK fused engine multidevice")
@@ -229,8 +232,9 @@ def test_shardlocal_mixer_preserves_consensus_distance():
 
         cfg = ModelConfig(num_layers=2, d_model=32, num_heads=2, num_kv_heads=2,
                           d_ff=64, vocab_size=64, dtype="float32")
-        from repro.core.compat import make_mesh
-        mesh = make_mesh((2, 2, 2), ("ens", "data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 2, 2), ("ens", "data", "model"),
+                             axis_types=(AxisType.Auto,) * 3)
         key = jax.random.key(0)
         def init(k):
             return {"embed": {"w": jax.random.normal(k, (64, 32))},

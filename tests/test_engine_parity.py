@@ -10,9 +10,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs.base import TrainConfig
-from repro.core.compat import make_mesh
 from repro.core.mixing import MixingConfig
 from repro.serving import averaged_params
 from repro.train import train_population
@@ -171,7 +171,7 @@ def test_explicit_mesh_roundtrips_through_train_population():
     tcfg = TrainConfig(population=4, optimizer="sgd", lr=0.05, total_steps=6,
                        batch_size=4)
     mcfg = MixingConfig(kind="wash", base_p=0.5, mode="bucketed")
-    mesh = make_mesh((1,), ("ens",))
+    mesh = jax.make_mesh((1,), ("ens",), axis_types=(AxisType.Auto,))
     explicit = train_population(
         KEY, _init, _loss_fn, _data_fn, tcfg, mcfg, 2, record_every=3,
         engine="shard_map", mesh=mesh,

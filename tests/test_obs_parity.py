@@ -114,7 +114,7 @@ def test_pipelined_engine_inert(tmp_path):
         from jax import lax
         from repro import obs
         from repro.configs.base import TrainConfig
-        from repro.core.compat import make_mesh
+        from jax.sharding import AxisType
         from repro.core.mixing import MixingConfig
         from repro.train import StageFns, engine
         from repro.train.engine import train_population_pipelined
@@ -140,7 +140,8 @@ def test_pipelined_engine_inert(tmp_path):
         tcfg = TrainConfig(population=2, optimizer="sgd", lr=0.05,
                            total_steps=6, batch_size=4, seq_len=16, seed=0)
         mcfg = MixingConfig(kind="wash", base_p=0.5, mode="bucketed")
-        mesh = make_mesh((2, 1, 2), ("ens", "data", "pipe"))
+        mesh = jax.make_mesh((2, 1, 2), ("ens", "data", "pipe"),
+                             axis_types=(AxisType.Auto,) * 3)
 
         def run():
             engine.reset_chunk_trace_count()

@@ -262,7 +262,7 @@ _TOY = """
         import numpy as np
         from jax import lax
         from repro.configs.base import TrainConfig
-        from repro.core.compat import make_mesh
+        from jax.sharding import AxisType
         from repro.core.mixing import MixingConfig
         from repro.train import (
             StageFns, train_population_pipelined, train_population_sharded,
@@ -314,10 +314,12 @@ def test_pipelined_engine_s1_m1_delegates_bitwise():
             mcfg = MixingConfig(kind=kind, mode="bucketed", **kw)
             ref = train_population_sharded(
                 KEY, init, loss_fn, data_fn, TCFG, mcfg, L, record_every=3,
-                mesh=make_mesh((2,), ("ens",)))
+                mesh=jax.make_mesh((2,), ("ens",),
+                                   axis_types=(AxisType.Auto,)))
             res = train_population_pipelined(
                 KEY, init, FNS, data_fn, TCFG, mcfg, L, record_every=3,
-                mesh=make_mesh((2, 1, 1), ("ens", "data", "pipe")),
+                mesh=jax.make_mesh((2, 1, 1), ("ens", "data", "pipe"),
+                                   axis_types=(AxisType.Auto,) * 3),
                 microbatches=1)
             for a, b in zip(jax.tree_util.tree_leaves(ref.population),
                             jax.tree_util.tree_leaves(res.population)):
@@ -334,12 +336,14 @@ def test_pipelined_engine_s4_m4_matches_to_tolerance():
     loss and accumulated grads match the single-shot engine to float32
     tolerance; WASH comm never exceeds the single-stage plan's."""
     _run(_TOY + """
-        mesh4 = make_mesh((2, 4), ("ens", "pipe"))
+        mesh4 = jax.make_mesh((2, 4), ("ens", "pipe"),
+                              axis_types=(AxisType.Auto,) * 2)
         for kind, kw in [("none", {}), ("wash", {"base_p": 0.5})]:
             mcfg = MixingConfig(kind=kind, mode="bucketed", **kw)
             ref = train_population_sharded(
                 KEY, init, loss_fn, data_fn, TCFG, mcfg, L, record_every=3,
-                mesh=make_mesh((2,), ("ens",)))
+                mesh=jax.make_mesh((2,), ("ens",),
+                                   axis_types=(AxisType.Auto,)))
             res = train_population_pipelined(
                 KEY, init, FNS, data_fn, TCFG, mcfg, L, record_every=3,
                 mesh=mesh4, microbatches=4)
@@ -362,7 +366,8 @@ def test_pipelined_engine_s4_m4_matches_to_tolerance():
 def test_pipelined_engine_rejects_uneven_split():
     _run(_TOY + """
         mcfg = MixingConfig(kind="none", mode="bucketed")
-        mesh = make_mesh((2, 4), ("ens", "pipe"))
+        mesh = jax.make_mesh((2, 4), ("ens", "pipe"),
+                             axis_types=(AxisType.Auto,) * 2)
         try:
             train_population_pipelined(
                 KEY, init, FNS, data_fn, TCFG, mcfg, 4, record_every=3,
@@ -395,7 +400,7 @@ def test_staged_decode_bitwise_and_traces():
         import jax, jax.numpy as jnp
         import numpy as np
         from repro.configs.base import ModelConfig
-        from repro.core.compat import make_mesh
+        from jax.sharding import AxisType
         from repro.models import transformer as M
         from repro.serving import engine as E
 
@@ -404,7 +409,7 @@ def test_staged_decode_bitwise_and_traces():
                           max_position=128)
         params = M.init_params(jax.random.key(0), cfg)
         batch = {"tokens": jax.random.randint(jax.random.key(1), (2, 6), 0, 64)}
-        mesh = make_mesh((4,), ("pipe",))
+        mesh = jax.make_mesh((4,), ("pipe",), axis_types=(AxisType.Auto,))
 
         ref = E.generate(params, cfg, batch, 8)
         out = E.generate(params, cfg, batch, 8, mesh=mesh)
@@ -417,7 +422,8 @@ def test_staged_decode_bitwise_and_traces():
         np.testing.assert_array_equal(np.asarray(rt), np.asarray(st))
 
         out1 = E.generate(params, cfg, batch, 8,
-                          mesh=make_mesh((1,), ("pipe",)))
+                          mesh=jax.make_mesh((1,), ("pipe",),
+                                             axis_types=(AxisType.Auto,)))
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(out1))
 
         E.reset_trace_counts(); E.clear_executable_cache()
@@ -453,11 +459,12 @@ def test_hlo_collectives_stay_in_stage_rings():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.analysis import contracts
-        from repro.core.compat import make_mesh
+        from jax.sharding import AxisType
         from repro.core.mixing import MixingConfig
         from repro.core import shardplan
 
-        mesh = make_mesh((2, 4), ("ens", "pipe"))
+        mesh = jax.make_mesh((2, 4), ("ens", "pipe"),
+                             axis_types=(AxisType.Auto,) * 2)
         L, D = 8, 16
         pop_sds = {"blocks": {"w": jax.ShapeDtypeStruct((2, L, D),
                                                         jnp.float32)}}
@@ -485,7 +492,7 @@ def test_hlo_collectives_stay_in_stage_rings():
                           max_position=128)
         params_sds = jax.eval_shape(
             lambda: M.init_params(jax.random.key(0), cfg))
-        pmesh = make_mesh((4,), ("pipe",))
+        pmesh = jax.make_mesh((4,), ("pipe",), axis_types=(AxisType.Auto,))
         E.clear_executable_cache()
         _, decode = E._programs(cfg, False, 2, 4, 8, 16, True, pmesh,
                                 stages=4, params=params_sds)

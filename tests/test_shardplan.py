@@ -224,7 +224,7 @@ def test_engine_accepts_degenerate_3d_mesh_bitwise():
     mix_collective_blocked)."""
     from conftest import tiny_data_fn, tiny_init, tiny_loss_fn
     from repro.configs.base import TrainConfig
-    from repro.core.compat import make_mesh
+    from jax.sharding import AxisType
     from repro.train.engine import train_population_sharded
 
     key = jax.random.key(0)
@@ -237,9 +237,11 @@ def test_engine_accepts_degenerate_3d_mesh_bitwise():
             key, tiny_init, tiny_loss_fn, tiny_data_fn, tcfg, mcfg, 2,
             record_every=3,
         )
+        mesh = jax.make_mesh((1, 1, 1), ("ens", "data", "model"),
+                             axis_types=(AxisType.Auto,) * 3)
         got = train_population_sharded(
             key, tiny_init, tiny_loss_fn, tiny_data_fn, tcfg, mcfg, 2,
-            record_every=3, mesh=make_mesh((1, 1, 1), ("ens", "data", "model")),
+            record_every=3, mesh=mesh,
         )
         for a, b in zip(jax.tree_util.tree_leaves(ref.population),
                         jax.tree_util.tree_leaves(got.population)):
@@ -272,7 +274,7 @@ _COMMON = """
         import numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.configs.base import TrainConfig
-        from repro.core.compat import make_mesh
+        from jax.sharding import AxisType
         from repro.core.mixing import MixingConfig
         from repro.train import engine as engine_mod
         from repro.train.engine import train_population_sharded
@@ -303,7 +305,8 @@ _COMMON = """
         def leaves_np(tree):
             return [np.asarray(x) for x in jax.tree_util.tree_leaves(tree)]
 
-        MESH3 = make_mesh((2, 2, 2), ("ens", "data", "model"))
+        MESH3 = jax.make_mesh((2, 2, 2), ("ens", "data", "model"),
+                              axis_types=(AxisType.Auto,) * 3)
 """
 
 
@@ -314,7 +317,7 @@ def test_3d_mesh_bitwise_parity_all_mixing_modes():
     4 mixing modes, with identical exact comm accounting and ≤ 2 chunk
     traces per run."""
     out = _run(_COMMON + """
-        mesh1 = make_mesh((4,), ("ens",))
+        mesh1 = jax.make_mesh((4,), ("ens",), axis_types=(AxisType.Auto,))
         for kind, kw in [("wash", dict(base_p=0.5)),
                          ("wash_opt", dict(base_p=0.5)),
                          ("papa", dict(papa_every=3, papa_alpha=0.9)),
@@ -352,7 +355,7 @@ def test_3d_mesh_sharded_members():
     population not dividing ens×data, batches split over the data axis and
     parity is numeric (mean-of-means), not bitwise."""
     out = _run(_COMMON + """
-        mesh1 = make_mesh((4,), ("ens",))
+        mesh1 = jax.make_mesh((4,), ("ens",), axis_types=(AxisType.Auto,))
         for kind, kw in [("none", dict()),
                          ("papa", dict(papa_every=3, papa_alpha=0.9))]:
             tcfg = TrainConfig(population=4, optimizer="sgd", lr=0.05,
@@ -389,9 +392,9 @@ def test_3d_mesh_sharded_members():
         tcfg = TrainConfig(population=2, optimizer="sgd", lr=0.05,
                            total_steps=5, batch_size=4)
         mcfg = MixingConfig(kind="wash", mode="bucketed", base_p=0.5)
+        mesh2 = jax.make_mesh((2,), ("ens",), axis_types=(AxisType.Auto,))
         ref = train_population_sharded(KEY, init, loss_fn, data_fn, tcfg,
-                                       mcfg, 2, record_every=2,
-                                       mesh=make_mesh((2,), ("ens",)))
+                                       mcfg, 2, record_every=2, mesh=mesh2)
         got = train_population_sharded(KEY, init, loss_fn, data_fn, tcfg,
                                        mcfg, 2, record_every=2, mesh=MESH3)
         for a, b in zip(leaves_np(ref.population), leaves_np(got.population)):
@@ -411,11 +414,12 @@ def test_shard_plans_fold_position_per_shard():
         import numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.core import shardplan, shuffle as shf
-        from repro.core.compat import make_mesh, shard_map
+        from jax.sharding import AxisType
         from repro.core.layer_index import infer_layer_ids, total_layers
         from repro.core.mixing import MixingConfig
 
-        mesh = make_mesh((2, 2, 2), ("ens", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("ens", "data", "model"),
+                             axis_types=(AxisType.Auto,) * 3)
         member = {"a": jax.ShapeDtypeStruct((32, 16), jnp.float32),
                   "b": jax.ShapeDtypeStruct((16, 8), jnp.float32)}
         specs = {"a": P(None, "model"), "b": P()}
@@ -431,7 +435,7 @@ def test_shard_plans_fold_position_per_shard():
             plans = shardplan.build_local_plans(key, pplan)
             return {k: v[None] for k, v in plans.items() if v is not None}
 
-        f = shard_map(probe, mesh, in_specs=(),
+        f = jax.shard_map(probe, mesh=mesh, in_specs=(),
                       out_specs={"a": P("model"), "b": P("model")},
                       check_vma=False)
         per_shard = jax.jit(f)()
@@ -467,7 +471,7 @@ def test_checkpoint_roundtrip_sharded_population():
         import os, tempfile
         from repro.train import checkpoint
 
-        mesh = make_mesh((4,), ("ens",))
+        mesh = jax.make_mesh((4,), ("ens",), axis_types=(AxisType.Auto,))
         tcfg = TrainConfig(population=4, optimizer="sgd", lr=0.05,
                            total_steps=4, batch_size=4)
         mcfg = MixingConfig(kind="wash", base_p=0.5, mode="bucketed")
@@ -514,9 +518,9 @@ def test_averaged_params_runs_mean_before_gather():
         mcfg = MixingConfig(kind="wash", base_p=0.5, mode="bucketed")
         ref = train_population(KEY, init, loss_fn, data_fn, tcfg, mcfg, 2,
                                record_every=3)
+        mesh4 = jax.make_mesh((4,), ("ens",), axis_types=(AxisType.Auto,))
         fused = train_population_sharded(KEY, init, loss_fn, data_fn, tcfg,
-                                         mcfg, 2, record_every=3,
-                                         mesh=make_mesh((4,), ("ens",)))
+                                         mcfg, 2, record_every=3, mesh=mesh4)
         for leaf in jax.tree_util.tree_leaves(fused.population):
             assert len(leaf.sharding.device_set) > 1
 
@@ -549,7 +553,7 @@ def test_dryrun_shardlocal_delegates_with_identical_hlo_collectives():
         from repro.analysis import contracts
         from repro.configs.base import ModelConfig
         from repro.core import population as pop, shardplan
-        from repro.core.compat import make_mesh
+        from jax.sharding import AxisType
         from repro.core.layer_index import infer_layer_ids, total_layers
         from repro.core.mixing import MixingConfig
         from repro.launch.dryrun import make_shardlocal_mixer, params_shapes
@@ -558,7 +562,8 @@ def test_dryrun_shardlocal_delegates_with_identical_hlo_collectives():
         cfg = ModelConfig(num_layers=2, d_model=32, num_heads=2,
                           num_kv_heads=2, d_ff=64, vocab_size=64,
                           dtype="float32")
-        mesh = make_mesh((2, 2, 2), ("ens", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("ens", "data", "model"),
+                             axis_types=(AxisType.Auto,) * 3)
         params_sds = params_shapes(cfg)
         pspecs = rules.param_pspecs(params_sds, cfg, mesh)
         add_ens = lambda tree: jax.tree_util.tree_map(
