@@ -64,23 +64,27 @@ def momentum_like_leaves(opt_state: PyTree, params: PyTree) -> PyTree:
 def _wash_step_stacked(
     key, params, opt_state, cfg: MixingConfig, layer_ids, total_layers
 ) -> Tuple[PyTree, PyTree, jax.Array]:
-    plan = shf.make_plan(
-        key, params, layer_ids, total_layers, cfg.base_p, cfg.schedule, cfg.mode
-    )
     n = jax.tree_util.tree_leaves(params)[0].shape[0]
-    new_params = shf.apply_plan_stacked(
-        plan, params, cfg.mode, use_pallas=cfg.pallas_shuffle
-    )
-    new_opt = opt_state
-    comm = shf.plan_sent_scalars(plan, n, cfg.mode)
-    if cfg.shuffles_optimizer() and opt_state is not None:
-        moments = momentum_like_leaves(opt_state, params)
-        new_opt = dict(opt_state)
-        for mk, mv in moments.items():
-            new_opt[mk] = shf.apply_plan_stacked(
-                plan, mv, cfg.mode, use_pallas=cfg.pallas_shuffle
+    with jax.named_scope("wash"):
+        with jax.named_scope("plan"):
+            plan = shf.make_plan(
+                key, params, layer_ids, total_layers, cfg.base_p,
+                cfg.schedule, cfg.mode,
             )
-            comm = comm + shf.plan_sent_scalars(plan, n, cfg.mode)
+        with jax.named_scope("shuffle"):
+            new_params = shf.apply_plan_stacked(
+                plan, params, cfg.mode, use_pallas=cfg.pallas_shuffle
+            )
+            new_opt = opt_state
+            comm = shf.plan_sent_scalars(plan, n, cfg.mode)
+            if cfg.shuffles_optimizer() and opt_state is not None:
+                moments = momentum_like_leaves(opt_state, params)
+                new_opt = dict(opt_state)
+                for mk, mv in moments.items():
+                    new_opt[mk] = shf.apply_plan_stacked(
+                        plan, mv, cfg.mode, use_pallas=cfg.pallas_shuffle
+                    )
+                    comm = comm + shf.plan_sent_scalars(plan, n, cfg.mode)
     return new_params, new_opt, comm
 
 
@@ -235,19 +239,26 @@ def mix_collective(
     d = sum(x.size for x in jax.tree_util.tree_leaves(params))
 
     if cfg.kind in ("wash", "wash_opt"):
-        plan = shf.make_plan(
-            key, params, layer_ids, total_layers, cfg.base_p, cfg.schedule,
-            mode="bucketed", n=n,
-        )
-        new_params = shf.apply_plan_collective(plan, params, axis_name)
-        new_opt = opt_state
-        comm = shf.plan_sent_scalars(plan, n, mode="bucketed")
-        if cfg.shuffles_optimizer() and opt_state is not None:
-            new_opt = dict(opt_state)
-            for mk, mv in momentum_like_leaves(opt_state, params).items():
-                new_opt[mk] = shf.apply_plan_collective(plan, mv, axis_name)
-                comm = comm + shf.plan_sent_scalars(plan, n, mode="bucketed")
-        return new_params, new_opt, zero + comm
+        with jax.named_scope("wash"):
+            with jax.named_scope("plan"):
+                plan = shf.make_plan(
+                    key, params, layer_ids, total_layers, cfg.base_p,
+                    cfg.schedule, mode="bucketed", n=n,
+                )
+            with jax.named_scope("shuffle"):
+                new_params = shf.apply_plan_collective(plan, params,
+                                                       axis_name)
+                new_opt = opt_state
+                comm = shf.plan_sent_scalars(plan, n, mode="bucketed")
+                if cfg.shuffles_optimizer() and opt_state is not None:
+                    new_opt = dict(opt_state)
+                    for mk, mv in momentum_like_leaves(opt_state,
+                                                       params).items():
+                        new_opt[mk] = shf.apply_plan_collective(
+                            plan, mv, axis_name)
+                        comm = comm + shf.plan_sent_scalars(
+                            plan, n, mode="bucketed")
+            return new_params, new_opt, zero + comm
 
     if cfg.kind == "papa" and step % cfg.papa_every == 0 and step > 0:
         pulled = jax.tree_util.tree_map(
@@ -304,25 +315,29 @@ def mix_collective_blocked(
         )
 
     if cfg.kind in ("wash", "wash_opt"):
-        member = jax.tree_util.tree_map(lambda x: x[0], params)
-        plan = shf.make_plan(
-            key, member, layer_ids, total_layers, cfg.base_p, cfg.schedule,
-            mode="bucketed", n=n,
-        )
-        new_params = shf.apply_plan_collective_blocked(
-            plan, params, axis_name, use_pallas=use_pallas
-        )
-        new_opt = opt_state
-        if cfg.shuffles_optimizer() and opt_state is not None:
-            new_opt = dict(opt_state)
-            for mk, mv in momentum_like_leaves(opt_state, params).items():
-                new_opt[mk] = _gated(
-                    shf.apply_plan_collective_blocked(
-                        plan, mv, axis_name, use_pallas=use_pallas
-                    ),
-                    mv,
+        with jax.named_scope("wash"):
+            member = jax.tree_util.tree_map(lambda x: x[0], params)
+            with jax.named_scope("plan"):
+                plan = shf.make_plan(
+                    key, member, layer_ids, total_layers, cfg.base_p,
+                    cfg.schedule, mode="bucketed", n=n,
                 )
-        return _gated(new_params, params), new_opt
+            with jax.named_scope("shuffle"):
+                new_params = shf.apply_plan_collective_blocked(
+                    plan, params, axis_name, use_pallas=use_pallas
+                )
+                new_opt = opt_state
+                if cfg.shuffles_optimizer() and opt_state is not None:
+                    new_opt = dict(opt_state)
+                    for mk, mv in momentum_like_leaves(opt_state,
+                                                       params).items():
+                        new_opt[mk] = _gated(
+                            shf.apply_plan_collective_blocked(
+                                plan, mv, axis_name, use_pallas=use_pallas
+                            ),
+                            mv,
+                        )
+            return _gated(new_params, params), new_opt
 
     if cfg.kind == "papa":
         pulled = jax.tree_util.tree_map(

@@ -622,21 +622,25 @@ def mix_collective_sharded(
         )
 
     if cfg.kind in ("wash", "wash_opt"):
-        plan = build_local_plans(key, pplan)
-        new_params = shf.apply_plan_collective_blocked(
-            plan, params, ax, use_pallas=use_pallas
-        )
-        new_opt = opt_state
-        if cfg.shuffles_optimizer() and opt_state is not None:
-            new_opt = dict(opt_state)
-            for mk, mv in momentum_like_leaves(opt_state, params).items():
-                new_opt[mk] = _gated(
-                    shf.apply_plan_collective_blocked(
-                        plan, mv, ax, use_pallas=use_pallas
-                    ),
-                    mv,
+        with jax.named_scope("wash"):
+            with jax.named_scope("plan"):
+                plan = build_local_plans(key, pplan)
+            with jax.named_scope("shuffle"):
+                new_params = shf.apply_plan_collective_blocked(
+                    plan, params, ax, use_pallas=use_pallas
                 )
-        return _gated(new_params, params), new_opt
+                new_opt = opt_state
+                if cfg.shuffles_optimizer() and opt_state is not None:
+                    new_opt = dict(opt_state)
+                    for mk, mv in momentum_like_leaves(opt_state,
+                                                       params).items():
+                        new_opt[mk] = _gated(
+                            shf.apply_plan_collective_blocked(
+                                plan, mv, ax, use_pallas=use_pallas
+                            ),
+                            mv,
+                        )
+            return _gated(new_params, params), new_opt
 
     if cfg.kind == "papa":
         pulled = jax.tree_util.tree_map(

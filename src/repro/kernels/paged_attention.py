@@ -198,5 +198,6 @@ def paged_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
         interpret=resolve_interpret(interpret),
+        name="paged_attention",
     )(*prefetch, *masks, q, k_pool.reshape(P, R, hd),
       v_pool.reshape(P, R, hd))

@@ -73,9 +73,10 @@ def _bucketed_kernel(x_ref, shift_ref, out_ref, *, n: int):
         out_ref[m] = out
 
 
-def _call(kernel, x, extras, block_rows, interpret):
+def _call(kernel, x, extras, block_rows, interpret, name):
     """Run ``kernel`` over (n, rows, 128) ``x`` plus per-coordinate
-    operands ``extras`` (leading member axis or not), tiled by rows."""
+    operands ``extras`` (leading member axis or not), tiled by rows, as
+    the kernel ``name``."""
     n, rows, _ = x.shape
 
     def spec(a):
@@ -91,6 +92,7 @@ def _call(kernel, x, extras, block_rows, interpret):
         out_specs=spec(x),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=resolve_interpret(interpret),
+        name=name,
     )(x, *extras)
 
 
@@ -109,7 +111,7 @@ def wash_shuffle_pallas(
         _dense_kernel, _to_tiles(x, rows),
         [_to_tiles(perm.astype(jnp.int32), rows),
          _to_tiles(mask.astype(jnp.int32), rows)],
-        block_rows, interpret,
+        block_rows, interpret, "wash_shuffle_dense",
     )
     return out.reshape(n, rows * LANES)[:, :d]
 
@@ -142,6 +144,6 @@ def bucketed_shuffle_pallas(
     block_rows, rows = _tiling(d, block_d)
     out = _call(
         _bucketed_kernel, _to_tiles(x, rows), [_to_tiles(shift, rows)],
-        block_rows, interpret,
+        block_rows, interpret, "wash_shuffle",
     )
     return out.reshape(n, rows * LANES)[:, :d]

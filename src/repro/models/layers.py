@@ -531,16 +531,18 @@ def gqa_decode_paged(p, cfg: ModelConfig, x, k_pool_l, v_pool_l, page_table,
     pos = positions.astype(jnp.int32)
     page_idx = page_table[jnp.arange(B), pos // page_size]  # (B,)
     offset = pos % page_size
-    k_pool_l = paged_store_rows(k_pool_l, page_idx, offset, k[:, 0])
-    v_pool_l = paged_store_rows(v_pool_l, page_idx, offset, v[:, 0])
+    with jax.named_scope("kv_write"):
+        k_pool_l = paged_store_rows(k_pool_l, page_idx, offset, k[:, 0])
+        v_pool_l = paged_store_rows(v_pool_l, page_idx, offset, v[:, 0])
     lengths = pos + 1  # context = everything written so far incl. this token
     attend = paged_attention_pallas if use_pallas else paged_attention_ref
-    if quantized:
-        out = attend(q[:, 0], k_pool_l["q"], v_pool_l["q"], page_table,
-                     lengths, k_scale=k_pool_l["scale"],
-                     v_scale=v_pool_l["scale"])
-    else:
-        out = attend(q[:, 0], k_pool_l, v_pool_l, page_table, lengths)
+    with jax.named_scope("paged_attention"):
+        if quantized:
+            out = attend(q[:, 0], k_pool_l["q"], v_pool_l["q"], page_table,
+                         lengths, k_scale=k_pool_l["scale"],
+                         v_scale=v_pool_l["scale"])
+        else:
+            out = attend(q[:, 0], k_pool_l, v_pool_l, page_table, lengths)
     return out.reshape(B, 1, -1) @ p["wo"], k_pool_l, v_pool_l
 
 
@@ -569,8 +571,9 @@ def gqa_prefill_paged(p, cfg: ModelConfig, x, k_pool_l, v_pool_l, page_table,
     q, k, v = _qkv(p, cfg, x, positions)
     pos = positions.astype(jnp.int32)
     # in-chunk positions are distinct, so the store never scatter-dups
-    k_pool_l = paged_store_chunk(k_pool_l, page_table, pos, k[0])
-    v_pool_l = paged_store_chunk(v_pool_l, page_table, pos, v[0])
+    with jax.named_scope("kv_write"):
+        k_pool_l = paged_store_chunk(k_pool_l, page_table, pos, k[0])
+        v_pool_l = paged_store_chunk(v_pool_l, page_table, pos, v[0])
     if isinstance(k_pool_l, dict):
         kc = kv_dequantize(k_pool_l["q"][page_table],
                            k_pool_l["scale"][page_table][:, None, None, None])

@@ -119,9 +119,10 @@ def init_params(key, cfg: ModelConfig) -> PyTree:
 
 
 def _mlp_apply(p, cfg: ModelConfig, x):
-    if cfg.moe:
-        return MOE.moe_apply(p, cfg, x)
-    return L.swiglu(p, x), jnp.zeros((), jnp.float32)
+    with jax.named_scope("mlp"):
+        if cfg.moe:
+            return MOE.moe_apply(p, cfg, x)
+        return L.swiglu(p, x), jnp.zeros((), jnp.float32)
 
 
 def _block_train(p, cfg: ModelConfig, x, state_l=None):
@@ -133,10 +134,11 @@ def _block_train(p, cfg: ModelConfig, x, state_l=None):
         return x, new_state, jnp.zeros((), jnp.float32)
 
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-    if cfg.mla:
-        a = L.mla_train(p["attn"], cfg, h)
-    else:
-        a = L.gqa_train(p["attn"], cfg, h)
+    with jax.named_scope("attention"):
+        if cfg.mla:
+            a = L.mla_train(p["attn"], cfg, h)
+        else:
+            a = L.gqa_train(p["attn"], cfg, h)
     if cfg.block_kind == "hybrid":
         m, new_ssm = SSM.mamba_prefill(p["mamba"], cfg, h, state_l)
         beta = jax.nn.softmax(p["beta"]).astype(a.dtype)
@@ -191,10 +193,11 @@ def _embed_tokens(params, cfg: ModelConfig, tokens, pos0: int = 0):
 
 
 def _logits(params, cfg: ModelConfig, x):
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        return x @ params["embed"]["tok"].T
-    return x @ params["lm_head"]["w"]
+    with jax.named_scope("logits"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        if cfg.tie_embeddings:
+            return x @ params["embed"]["tok"].T
+        return x @ params["lm_head"]["w"]
 
 
 def _encode(params, cfg: ModelConfig, frames):
@@ -533,10 +536,11 @@ def decode_step_paged(params, cfg: ModelConfig, tokens, positions, pools,
     def body(h, xs):
         block_l, kp_l, vp_l = xs
         a_in = L.rmsnorm(block_l["ln1"], h, cfg.norm_eps)
-        a, kp_l, vp_l = L.gqa_decode_paged(
-            block_l["attn"], cfg, a_in, kp_l, vp_l, page_tables, pos,
-            use_pallas,
-        )
+        with jax.named_scope("attention"):
+            a, kp_l, vp_l = L.gqa_decode_paged(
+                block_l["attn"], cfg, a_in, kp_l, vp_l, page_tables, pos,
+                use_pallas,
+            )
         h = h + a
         y, _ = _mlp_apply(block_l["mlp"], cfg,
                           L.rmsnorm(block_l["ln2"], h, cfg.norm_eps))
@@ -593,9 +597,11 @@ def prefill_paged(params, cfg: ModelConfig, tokens, pos0, pools, page_table):
     def body(h, xs):
         block_l, kp_l, vp_l = xs
         a_in = L.rmsnorm(block_l["ln1"], h, cfg.norm_eps)
-        a, kp_l, vp_l = L.gqa_prefill_paged(
-            block_l["attn"], cfg, a_in, kp_l, vp_l, page_table, positions,
-        )
+        with jax.named_scope("attention"):
+            a, kp_l, vp_l = L.gqa_prefill_paged(
+                block_l["attn"], cfg, a_in, kp_l, vp_l, page_table,
+                positions,
+            )
         h = h + a
         y, _ = _mlp_apply(block_l["mlp"], cfg,
                           L.rmsnorm(block_l["ln2"], h, cfg.norm_eps))

@@ -3,17 +3,24 @@
 A :class:`Telemetry` owns one :class:`~repro.obs.metrics.Registry` and a
 list of sinks.  Instrumentation points in the engines talk to the
 module-level default instance (``repro.obs.get()``); launchers call
-:func:`configure` once to attach sinks from CLI flags.  With no sinks
-attached the hot-path cost of a span is two ``time.perf_counter`` calls
-and a histogram observe — and the *outputs* of instrumented code are
-identical either way, because every hook here is a host-side Python
-effect (see ``tests/test_obs_parity.py``).
+:func:`configure` once to attach sinks from CLI flags.  Every span also
+opens a ``jax.profiler.TraceAnnotation`` (a TraceMe), so whatever
+profiler session is active — a ``--profile-dir`` window, a benchmark's
+trace, an operator's TensorBoard capture — holds the program's spans on
+the device trace's clock; with no session active a TraceMe costs well
+under a microsecond.  The *outputs* of instrumented code are identical
+either way, because every hook here is a host-side Python effect (see
+``tests/test_obs_parity.py``).
 
 Event stream schema (one JSON object per line, validated by
 ``tools/check_metrics_schema.py``):
 
   line 1           ``{"kind": "provenance", "jax_version": ..., ...}``
-  span             ``{"kind": "span", "name", "ts", "dur_s", ...attrs}``
+  span             ``{"kind": "span", "name", "ts", "t0_ns", "dur_s",
+                   "parent", ...attrs}``  (``ts`` at the end, ``t0_ns``
+                   at the start on the profiler's host clock,
+                   ``parent`` the enclosing span on this thread or None;
+                   spans serving one request carry its ``uid``)
   event            ``{"kind": "event", "name", "ts", ...attrs}``
   compile          ``{"kind": "compile", "name", "ts", ...attrs}``
   metric snapshot  ``{"kind": "metric", "name", "ts", ...snapshot}``
@@ -130,7 +137,8 @@ class Telemetry:
     ``enabled=False`` short-circuits every hook to a no-op — the switch
     the serving_bench overhead row flips to measure instrumentation
     cost.  All sink writes happen under one lock: the train staging
-    thread and the driver pump thread report concurrently."""
+    thread and the driver pump thread report concurrently, so the stack
+    of open spans that names each span's ``parent`` is per thread."""
 
     def __init__(self):
         self.registry = Registry()
@@ -138,6 +146,7 @@ class Telemetry:
         self._sinks: List = []
         self._lock = threading.Lock()
         self._profile: Optional[ProfileWindow] = None
+        self._local = threading.local()
 
     # -- configuration ----------------------------------------------------
 
@@ -191,29 +200,37 @@ class Telemetry:
     @contextlib.contextmanager
     def span(self, name: str, edges=DEFAULT_TIME_EDGES, **attrs):
         """Time a host-side region into ``registry.histogram(name)`` and
-        (with sinks) the event stream.  Never adds a device sync: for
-        regions that dispatch async jax work this measures dispatch wall
-        time, which is exactly what the engines' own timers measured.
-        Under an active ``--profile-dir`` window the region is also
-        wrapped in a ``jax.profiler`` trace annotation."""
+        (with sinks) the event stream, and mark it in any active profiler
+        trace as a TraceMe named ``name`` carrying ``attrs``.  Yields the
+        attribute dict: counts known only inside the region may be added
+        to it.  Never adds a device sync: for regions that dispatch async
+        jax work this measures dispatch wall time; a region that reads a
+        result back measures the wait too."""
         if not self.enabled:
-            yield
+            yield attrs
             return
-        prof = self._profile
-        ann = prof.annotation(name) if prof is not None else None
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        parent = stack[-1] if stack else None
+        stack.append(name)
+        n_attrs = len(attrs)
+        t0_ns = time.time_ns()
         t0 = time.perf_counter()
         try:
-            if ann is not None:
-                with ann:
-                    yield
-            else:
-                yield
+            with jax.profiler.TraceAnnotation(name, **attrs) as ann:
+                yield attrs
+                if len(attrs) != n_attrs:
+                    ann.set_metadata(**attrs)
         finally:
             dur = time.perf_counter() - t0
+            stack.pop()
             self.registry.histogram(name, edges).observe(dur)
             if self._sinks:
                 self._emit({"kind": "span", "name": name,
-                            "ts": time.time(), "dur_s": dur, **attrs})
+                            "ts": time.time(), "t0_ns": t0_ns, "dur_s": dur,
+                            "parent": parent, **attrs})
+            prof = self._profile
             if prof is not None:
                 prof.tick()
 

@@ -225,28 +225,6 @@ class Registry:
             return {name: m.snapshot()
                     for name, m in sorted(self._metrics.items())}
 
-    def prometheus_text(self) -> str:
-        """Prometheus text-exposition snapshot (names dot->underscore,
-        histograms in cumulative ``le`` form)."""
-        lines: List[str] = []
-        for name, snap in self.snapshot().items():
-            pname = name.replace(".", "_").replace("-", "_")
-            kind = snap["type"]
-            lines.append(f"# TYPE {pname} {kind}")
-            if kind == "histogram":
-                cum = 0
-                for edge, c in zip(snap["edges"], snap["counts"]):
-                    cum += c
-                    lines.append(f'{pname}_bucket{{le="{edge:g}"}} {cum}')
-                lines.append(
-                    f'{pname}_bucket{{le="+Inf"}} {snap["count"]}')
-                lines.append(f"{pname}_sum {snap['sum']:g}")
-                lines.append(f"{pname}_count {snap['count']}")
-            else:
-                v = snap["value"]
-                lines.append(f"{pname} {'NaN' if v is None else f'{v:g}'}")
-        return "\n".join(lines) + ("\n" if lines else "")
-
 
 # ---------------------------------------------------------------------------
 # exact raw-sample percentiles (the SLO-summary path)

@@ -3,9 +3,11 @@
 The window starts a ``jax.profiler`` trace on construction and stops it
 after ``max_spans`` instrumented spans have passed through — an
 unconditional bound so a long training run can't fill the disk with
-profile data.  A trace that was asked for and cannot start (no profiler
-backend, another trace already active) raises: a run that silently
-drops its profile would look like a profiled run.
+profile data.  The window hands out nothing: every telemetry span opens
+its own TraceMe, which lands in whatever trace is active.  A trace that
+was asked for and cannot start (no profiler backend, another trace
+already active) raises: a run that silently drops its profile would look
+like a profiled run.
 """
 
 from __future__ import annotations
@@ -29,13 +31,6 @@ class ProfileWindow:
     @property
     def active(self) -> bool:
         return self._active
-
-    def annotation(self, name: str):
-        """A ``jax.profiler.TraceAnnotation`` for *name* while the window
-        is open, else None."""
-        if not self._active:
-            return None
-        return jax.profiler.TraceAnnotation(name)
 
     def tick(self) -> None:
         """Count one completed span; close the window at the bound."""

@@ -323,17 +323,19 @@ def _chain_hashes(tokens: np.ndarray, page_size: int) -> List[bytes]:
 
 
 def _sample_steps(last, keys, steps, temperature, greedy: bool):
-    """Next-token ids (B,) from last-position logits (B, V).
+    """Next-token ids (B,) from last-position logits (B, V), under the
+    device scope ``sample``.
 
     Same fold-in scheme as ``engine._sample`` but with a per-slot step
     vector — slots in a continuous batch sit at different depths of their
     streams, yet each stream must equal the request served alone."""
-    if greedy:
-        return jnp.argmax(last, axis=-1).astype(jnp.int32)
-    ks = jax.vmap(jax.random.fold_in)(keys, steps)
-    return jax.vmap(
-        lambda lg, k: jax.random.categorical(k, lg)
-    )(last.astype(jnp.float32) / temperature, ks).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        if greedy:
+            return jnp.argmax(last, axis=-1).astype(jnp.int32)
+        ks = jax.vmap(jax.random.fold_in)(keys, steps)
+        return jax.vmap(
+            lambda lg, k: jax.random.categorical(k, lg)
+        )(last.astype(jnp.float32) / temperature, ks).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -349,8 +351,8 @@ def _build_admit(cfg: ModelConfig, ensemble: bool, S: int, n_pages: int,
     (their content is already in the pool — same tokens, same params, same
     prefill program ⇒ same KV)."""
 
-    def program(params, k_pool, v_pool, tokens, page_ids, write_mask, key,
-                temperature):
+    def cont_prefill_admit(params, k_pool, v_pool, tokens, page_ids,
+                           write_mask, key, temperature):
         _PREFILL_TRACES[0] += 1
         # trace-time host effect: the compile counters mirror the
         # one-executable-per-geometry contract _*_TRACES guard
@@ -391,7 +393,7 @@ def _build_admit(cfg: ModelConfig, ensemble: bool, S: int, n_pages: int,
                                temperature, greedy)[0]
         return k_pool, v_pool, token0
 
-    return jax.jit(program, donate_argnums=donate_argnums((1, 2)))
+    return jax.jit(cont_prefill_admit, donate_argnums=donate_argnums((1, 2)))
 
 
 def _build_chunk(cfg: ModelConfig, ensemble: bool, greedy: bool,
@@ -413,8 +415,8 @@ def _build_chunk(cfg: ModelConfig, ensemble: bool, greedy: bool,
     hit is valid for the draft pool exactly when it is for the verify
     pool."""
 
-    def program(params, draft_params, k_pool, v_pool, dk_pool, dv_pool,
-                tokens, pos0, table, key, temperature):
+    def cont_prefill_chunk(params, draft_params, k_pool, v_pool, dk_pool,
+                           dv_pool, tokens, pos0, table, key, temperature):
         _PREFILL_TRACES[0] += 1
         obs.get().record_compile("cont_prefill_chunk",
                                  T=int(tokens.shape[-1]))
@@ -440,7 +442,8 @@ def _build_chunk(cfg: ModelConfig, ensemble: bool, greedy: bool,
                                temperature, greedy)[0]
         return k_pool, v_pool, dk_pool, dv_pool, token0
 
-    return jax.jit(program, donate_argnums=donate_argnums((2, 3, 4, 5)))
+    return jax.jit(cont_prefill_chunk,
+                   donate_argnums=donate_argnums((2, 3, 4, 5)))
 
 
 def _chunk_program(cfg: ModelConfig, ensemble: bool, T: int, max_pages: int,
@@ -462,8 +465,8 @@ def _build_decode(cfg: ModelConfig, ensemble: bool, greedy: bool,
     the program compiles once per pool geometry and is reused across every
     admission/retirement the stream ever makes."""
 
-    def program(params, k_pool, v_pool, tokens, positions, steps, budgets,
-                active, page_tables, keys, temperature):
+    def cont_decode(params, k_pool, v_pool, tokens, positions, steps,
+                    budgets, active, page_tables, keys, temperature):
         _DECODE_TRACES[0] += 1
         obs.get().record_compile("cont_decode",
                                  slots=int(tokens.shape[0]))
@@ -490,7 +493,7 @@ def _build_decode(cfg: ModelConfig, ensemble: bool, greedy: bool,
         done = active & (steps + 1 >= budgets)
         return sampled, done, k_pool, v_pool
 
-    return program
+    return cont_decode
 
 
 def _build_spec_decode(cfg: ModelConfig, ensemble: bool, greedy: bool,
@@ -506,12 +509,12 @@ def _build_spec_decode(cfg: ModelConfig, ensemble: bool, greedy: bool,
     inner = speculative.build_speculative_decode(
         cfg, ensemble, greedy, use_pallas, draft_k)
 
-    def program(*args):
+    def cont_spec_decode(*args):
         _DECODE_TRACES[0] += 1
         obs.get().record_compile("cont_spec_decode", draft_k=draft_k)
         return inner(*args)
 
-    return program
+    return cont_spec_decode
 
 
 def _programs(cfg: ModelConfig, ensemble: bool, geometry: Tuple,
@@ -799,160 +802,184 @@ class ContinuousServer:
         returned :class:`_Prefill` starts at ``pos = cached_tokens`` and
         only the uncached suffix ever runs through a prefill program.
         Returns None when no slot is free or the worst-case page
-        reservation does not fit."""
-        S = int(req.tokens.shape[0])
-        n_prompt = max(-(-S // self.page_size), 1)
-        total = _total_pages(S, req.max_new, self.page_size)
-        slot_i = self._free_slot()
-        if slot_i is None:
-            return None
+        reservation does not fit.  Timed as the span ``serve.admit``."""
+        with obs.get().span("serve.admit", uid=str(req.uid)) as span:
+            S = int(req.tokens.shape[0])
+            n_prompt = max(-(-S // self.page_size), 1)
+            total = _total_pages(S, req.max_new, self.page_size)
+            slot_i = self._free_slot()
+            if slot_i is None:
+                return None
 
-        digests = _chain_hashes(req.tokens, self.page_size)
-        cached = 0
-        while (cached < len(digests)
-               and digests[cached] in self._pool.prefix):
-            cached += 1
-        # the suffix must keep >= 1 token: its last-position logits sample
-        # the first output token (a fully cached prompt still runs a
-        # 1-token chunk over the final position)
-        cached = min(cached, (S - 1) // self.page_size)
+            digests = _chain_hashes(req.tokens, self.page_size)
+            cached = 0
+            while (cached < len(digests)
+                   and digests[cached] in self._pool.prefix):
+                cached += 1
+            # the suffix must keep >= 1 token: its last-position logits
+            # sample the first output token (a fully cached prompt still
+            # runs a 1-token chunk over the final position)
+            cached = min(cached, (S - 1) // self.page_size)
 
-        # reviving a parked prefix page consumes availability exactly like
-        # an alloc (it leaves the LRU list), so it counts toward need
-        revived = sum(1 for j in range(cached)
-                      if self._pool.prefix[digests[j]] in self._pool.lru)
-        need = (n_prompt - cached) + revived + (total - n_prompt)
-        if self._pool.available_count - self._reserved_pages() < need:
-            return None
+            # reviving a parked prefix page consumes availability exactly
+            # like an alloc (it leaves the LRU list), so it counts toward
+            # need
+            revived = sum(1 for j in range(cached)
+                          if self._pool.prefix[digests[j]] in self._pool.lru)
+            need = (n_prompt - cached) + revived + (total - n_prompt)
+            if self._pool.available_count - self._reserved_pages() < need:
+                return None
 
-        pages: List[int] = []
-        for j in range(cached):
-            pages.append(self._pool.share(digests[j]))
-            self.stats["pages_shared"] += 1
-        for j in range(cached, n_prompt):
-            pages.append(self._pool.alloc())
-            self.stats["pages_allocated"] += 1
-        # NOTE: freshly allocated full pages are NOT registered as sharable
-        # yet — their content does not exist until a prefill chunk writes
-        # it.  ``_prefill_step`` registers each page as its chunk lands,
-        # so a concurrent admission can only dedup onto written pages.
-        self.stats["prefix_tokens_reused"] += cached * self.page_size
-        self._sync_pool_stats()
+            pages: List[int] = []
+            for j in range(cached):
+                pages.append(self._pool.share(digests[j]))
+                self.stats["pages_shared"] += 1
+            for j in range(cached, n_prompt):
+                pages.append(self._pool.alloc())
+                self.stats["pages_allocated"] += 1
+            # NOTE: freshly allocated full pages are NOT registered as
+            # sharable yet — their content does not exist until a prefill
+            # chunk writes it.  ``_prefill_step`` registers each page as its
+            # chunk lands, so a concurrent admission can only dedup onto
+            # written pages.
+            self.stats["prefix_tokens_reused"] += cached * self.page_size
+            span["cached_tokens"] = cached * self.page_size
+            self._sync_pool_stats()
 
-        key = req.key if req.key is not None else jax.random.key(0)
-        pf = _Prefill(uid=req.uid, prompt=req.tokens, max_new=req.max_new,
-                      key=jax.random.split(key, 1)[0], pages=pages,
-                      total_pages=total, pos=cached * self.page_size,
-                      cached_tokens=cached * self.page_size,
-                      slot_index=slot_i, digests=digests)
-        self._reserved_slots.add(slot_i)
-        self._prefills.append(pf)
-        return pf
+            key = req.key if req.key is not None else jax.random.key(0)
+            pf = _Prefill(uid=req.uid, prompt=req.tokens,
+                          max_new=req.max_new,
+                          key=jax.random.split(key, 1)[0], pages=pages,
+                          total_pages=total, pos=cached * self.page_size,
+                          cached_tokens=cached * self.page_size,
+                          slot_index=slot_i, digests=digests)
+            self._reserved_slots.add(slot_i)
+            self._prefills.append(pf)
+            return pf
 
     def _prefill_step(self, pf: _Prefill, max_tokens: Optional[int] = None
                       ) -> bool:
         """Run ONE prompt chunk (at most ``max_tokens``; None = the whole
         remaining suffix) through the chunk program.  On the final chunk,
         samples the first token and installs the slot (or retires it for
-        ``max_new == 1``).  Returns True when the prefill completed."""
-        T = pf.remaining if max_tokens is None else min(max_tokens,
-                                                        pf.remaining)
-        chunk = pf.prompt[pf.pos:pf.pos + T]
-        table = np.full((self.max_pages,), SCRATCH_PAGE, np.int32)
-        table[:len(pf.pages)] = pf.pages
-        program = _chunk_program(self.cfg, self.ensemble, T, self.max_pages,
-                                 self.page_size, self.num_pages, self.greedy,
-                                 self.kv_dtype, self.speculative)
-        (self._k_pool, self._v_pool, self._dk_pool, self._dv_pool,
-         token0) = program(
-            self.params, self._draft_params, self._k_pool, self._v_pool,
-            self._dk_pool, self._dv_pool, jnp.asarray(chunk),
-            jnp.int32(pf.pos), jnp.asarray(table), pf.key,
-            jnp.float32(max(self.temperature, 1e-6)),
-        )
-        written_before = pf.pos
-        pf.pos += T
-        self.stats["prefill_tokens"] += T
-        # register the now-fully-written pages for prefix sharing — never
-        # clobbering a digest already live on another page (possible when
-        # the cached run was capped or LRU eviction broke an older chain:
-        # the old page's release would tear down the new entry)
-        for j in range(written_before // self.page_size,
-                       pf.pos // self.page_size):
-            if j < len(pf.digests) and pf.digests[j] not in self._pool.prefix:
-                self._pool.register(pf.pages[j], pf.digests[j])
-        if pf.remaining:
-            return False
+        ``max_new == 1``).  Returns True when the prefill completed.
+        Timed as the span ``serve.prefill_chunk``; the first token's
+        readback as its child ``serve.prefill_wait``."""
+        with obs.get().span("serve.prefill_chunk", uid=str(pf.uid)) as span:
+            T = pf.remaining if max_tokens is None else min(max_tokens,
+                                                            pf.remaining)
+            span.update(tokens=T, pos=pf.pos)
+            chunk = pf.prompt[pf.pos:pf.pos + T]
+            table = np.full((self.max_pages,), SCRATCH_PAGE, np.int32)
+            table[:len(pf.pages)] = pf.pages
+            program = _chunk_program(self.cfg, self.ensemble, T,
+                                     self.max_pages, self.page_size,
+                                     self.num_pages, self.greedy,
+                                     self.kv_dtype, self.speculative)
+            (self._k_pool, self._v_pool, self._dk_pool, self._dv_pool,
+             token0) = program(
+                self.params, self._draft_params, self._k_pool, self._v_pool,
+                self._dk_pool, self._dv_pool, jnp.asarray(chunk),
+                jnp.int32(pf.pos), jnp.asarray(table), pf.key,
+                jnp.float32(max(self.temperature, 1e-6)),
+            )
+            written_before = pf.pos
+            pf.pos += T
+            self.stats["prefill_tokens"] += T
+            # register the now-fully-written pages for prefix sharing —
+            # never clobbering a digest already live on another page
+            # (possible when the cached run was capped or LRU eviction broke
+            # an older chain: the old page's release would tear down the new
+            # entry)
+            for j in range(written_before // self.page_size,
+                           pf.pos // self.page_size):
+                if (j < len(pf.digests)
+                        and pf.digests[j] not in self._pool.prefix):
+                    self._pool.register(pf.pages[j], pf.digests[j])
+            if pf.remaining:
+                return False
 
-        self._prefills.remove(pf)
-        self._reserved_slots.discard(pf.slot_index)
-        slot = _Slot(uid=pf.uid, prompt=pf.prompt, max_new=pf.max_new,
-                     key=pf.key, pages=pf.pages, total_pages=pf.total_pages,
-                     out=[int(token0)])
-        self.stats["admitted"] += 1
-        if pf.max_new == 1:  # prefill-only request: retire immediately
-            self._retire(slot)
-        else:
-            self._slots[pf.slot_index] = slot
-        return True
+            with obs.get().span("serve.prefill_wait"):
+                token0 = int(token0)
+            self._prefills.remove(pf)
+            self._reserved_slots.discard(pf.slot_index)
+            slot = _Slot(uid=pf.uid, prompt=pf.prompt, max_new=pf.max_new,
+                         key=pf.key, pages=pf.pages,
+                         total_pages=pf.total_pages, out=[token0])
+            self.stats["admitted"] += 1
+            if pf.max_new == 1:  # prefill-only request: retire immediately
+                self._retire(slot)
+            else:
+                self._slots[pf.slot_index] = slot
+            return True
 
     def _try_admit_legacy(self, req: Request) -> bool:
         """Whole-prompt admission through ``M.prefill`` + write-mask dedup
         — the fallback when ``M.paged_prefill_supported`` rejects the
         config (e.g. ``attn_impl="chunked"``, whose prefill numerics the
         paged attend cannot reproduce bitwise).  Shared prefix pages are
-        skipped at WRITE time but their rows are still computed."""
-        S = int(req.tokens.shape[0])
-        n_prompt = max(-(-S // self.page_size), 1)
-        total = _total_pages(S, req.max_new, self.page_size)
-        slot_i = self._free_slot()
-        if slot_i is None:
-            return False
+        skipped at WRITE time but their rows are still computed.  Timed
+        as the span ``serve.admit``, the first token's readback as its
+        child ``serve.prefill_wait``."""
+        with obs.get().span("serve.admit", uid=str(req.uid)) as span:
+            S = int(req.tokens.shape[0])
+            n_prompt = max(-(-S // self.page_size), 1)
+            total = _total_pages(S, req.max_new, self.page_size)
+            slot_i = self._free_slot()
+            if slot_i is None:
+                return False
 
-        digests = _chain_hashes(req.tokens, self.page_size)
-        shared_pages = [self._pool.prefix.get(d) for d in digests]
-        revived = sum(1 for p in shared_pages
-                      if p is not None and p in self._pool.lru)
-        new_now = n_prompt - sum(p is not None for p in shared_pages)
-        need = new_now + revived + (total - n_prompt)
-        if self._pool.available_count - self._reserved_pages() < need:
-            return False
+            digests = _chain_hashes(req.tokens, self.page_size)
+            shared_pages = [self._pool.prefix.get(d) for d in digests]
+            revived = sum(1 for p in shared_pages
+                          if p is not None and p in self._pool.lru)
+            new_now = n_prompt - sum(p is not None for p in shared_pages)
+            need = new_now + revived + (total - n_prompt)
+            if self._pool.available_count - self._reserved_pages() < need:
+                return False
 
-        pages: List[int] = []
-        write_mask = np.ones((n_prompt,), bool)
-        for j in range(n_prompt):
-            page = self._pool.share(digests[j]) if j < len(digests) else None
-            if page is not None:
-                write_mask[j] = False
-                self.stats["pages_shared"] += 1
-            else:
-                page = self._pool.alloc()
-                self.stats["pages_allocated"] += 1
-                if j < len(digests) and digests[j] not in self._pool.prefix:
-                    self._pool.register(page, digests[j])
-            pages.append(page)
-        self._sync_pool_stats()
-        self.stats["prefill_tokens"] += S
+            pages: List[int] = []
+            write_mask = np.ones((n_prompt,), bool)
+            for j in range(n_prompt):
+                page = (self._pool.share(digests[j]) if j < len(digests)
+                        else None)
+                if page is not None:
+                    write_mask[j] = False
+                    self.stats["pages_shared"] += 1
+                else:
+                    page = self._pool.alloc()
+                    self.stats["pages_allocated"] += 1
+                    if (j < len(digests)
+                            and digests[j] not in self._pool.prefix):
+                        self._pool.register(page, digests[j])
+                pages.append(page)
+            self._sync_pool_stats()
+            self.stats["prefill_tokens"] += S
+            span["cached_tokens"] = self.page_size * int(
+                n_prompt - write_mask.sum())
 
-        key = req.key if req.key is not None else jax.random.key(0)
-        slot_key = jax.random.split(key, 1)[0]
-        admit = _admit_program(self.cfg, self.ensemble, S, n_prompt,
-                               self.page_size, self.num_pages, self.greedy)
-        self._k_pool, self._v_pool, token0 = admit(
-            self.params, self._k_pool, self._v_pool,
-            jnp.asarray(req.tokens)[None], jnp.asarray(pages, jnp.int32),
-            jnp.asarray(write_mask), slot_key,
-            jnp.float32(max(self.temperature, 1e-6)),
-        )
-        slot = _Slot(uid=req.uid, prompt=req.tokens, max_new=req.max_new,
-                     key=slot_key, pages=pages, total_pages=total,
-                     out=[int(token0)])
-        self.stats["admitted"] += 1
-        if req.max_new == 1:  # prefill-only request: retire immediately
-            self._retire(slot)
+            key = req.key if req.key is not None else jax.random.key(0)
+            slot_key = jax.random.split(key, 1)[0]
+            admit = _admit_program(self.cfg, self.ensemble, S, n_prompt,
+                                   self.page_size, self.num_pages,
+                                   self.greedy)
+            self._k_pool, self._v_pool, token0 = admit(
+                self.params, self._k_pool, self._v_pool,
+                jnp.asarray(req.tokens)[None], jnp.asarray(pages, jnp.int32),
+                jnp.asarray(write_mask), slot_key,
+                jnp.float32(max(self.temperature, 1e-6)),
+            )
+            with obs.get().span("serve.prefill_wait"):
+                token0 = int(token0)
+            slot = _Slot(uid=req.uid, prompt=req.tokens, max_new=req.max_new,
+                         key=slot_key, pages=pages, total_pages=total,
+                         out=[token0])
+            self.stats["admitted"] += 1
+            if req.max_new == 1:  # prefill-only request: retire immediately
+                self._retire(slot)
+                return True
+            self._slots[slot_i] = slot
             return True
-        self._slots[slot_i] = slot
-        return True
 
     def _try_admit(self, req: Request) -> bool:
         """Fully admit ``req`` (prefill runs to completion within this
@@ -1003,12 +1030,12 @@ class ContinuousServer:
         """Lazy page growth: allocate the write page(s) just before they
         are needed (``extra`` covers a speculative step's lookahead —
         bounded by the budget, so it never exceeds the admission-time
-        worst case).  Cannot fail — admission reserved that worst case."""
+        worst case).  Cannot fail — admission reserved that worst case.
+        The caller syncs the pool statistics once all slots have grown."""
         need_pages = (slot.write_pos + extra) // self.page_size + 1
         while len(slot.pages) < need_pages:
             slot.pages.append(self._pool.alloc())
             self.stats["pages_allocated"] += 1
-        self._sync_pool_stats()
 
     def _shrink(self, slot: _Slot) -> None:
         """Roll back a speculative step's page-table cursor: release the
@@ -1035,12 +1062,36 @@ class ContinuousServer:
 
     def step(self) -> List[Any]:
         """Admit what fits, dispatch ONE decode step for the in-flight set,
-        retire whatever the done-mask finished.  Returns retired uids."""
+        retire whatever the done-mask finished.  Returns retired uids.
+
+        The decode step is timed as the span ``serve.decode_step``, through
+        the readback, with one child span per phase: ``decode_prepare``
+        (host arrays, page growth), ``decode_dispatch`` (transfers and the
+        call), ``decode_wait`` (the readback), ``decode_commit`` (token
+        appends, retirement)."""
         before = set(self._results)
         self._admit()
         if self.active_slots == 0:
             return [u for u in self._results if u not in before]
 
+        tel = obs.get()
+        with tel.span("serve.decode_step", slots=self.active_slots):
+            with tel.span("serve.decode_prepare"):
+                *inputs, n_spec = self._decode_inputs()
+            with tel.span("serve.decode_dispatch"):
+                out = self._dispatch_decode(*inputs)
+            with tel.span("serve.decode_wait"):
+                sampled, done, counts = (
+                    None if x is None else np.asarray(x) for x in out)
+            with tel.span("serve.decode_commit"):
+                self._commit_decode(sampled, done, counts, n_spec)
+        return [u for u in self._results if u not in before]
+
+    def _decode_inputs(self) -> Tuple:
+        """The decode step's host arrays, with each live slot's pages grown
+        to hold this step's writes: ``(tokens, positions, steps, budgets,
+        active, tables, keys, n_spec)``, ``n_spec`` the proposals per slot
+        of a speculative step."""
         B, Pmax = self.max_slots, self.max_pages
         tokens = np.zeros((B,), np.int32)
         positions = np.zeros((B,), np.int32)
@@ -1048,7 +1099,7 @@ class ContinuousServer:
         budgets = np.full((B,), np.iinfo(np.int32).max, np.int32)
         active = np.zeros((B,), bool)
         tables = np.full((B, Pmax), SCRATCH_PAGE, np.int32)
-        n_spec = np.zeros((B,), np.int32)  # proposals per slot this call
+        n_spec = np.zeros((B,), np.int32)
         keys = []
         for i, slot in enumerate(self._slots):
             if slot is None:
@@ -1065,33 +1116,40 @@ class ContinuousServer:
             active[i] = True
             tables[i, :len(slot.pages)] = slot.pages
             keys.append(slot.key)
+        self._sync_pool_stats()
+        return (tokens, positions, steps, budgets, active, tables, keys,
+                n_spec)
 
+    def _dispatch_decode(self, tokens, positions, steps, budgets, active,
+                         tables, keys) -> Tuple:
+        """Enqueue the decode program; returns its device outputs
+        ``(sampled, done, counts)`` (``counts`` None unless speculative)
+        with the pools swapped in."""
+        temperature = jnp.float32(max(self.temperature, 1e-6))
+        if self.speculative:
+            (sampled, counts, done, self._k_pool, self._v_pool,
+             self._dk_pool, self._dv_pool) = self._decode(
+                self.params, self._draft_params,
+                self._k_pool, self._v_pool, self._dk_pool, self._dv_pool,
+                jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.asarray(steps), jnp.asarray(budgets),
+                jnp.asarray(active), jnp.asarray(tables),
+                jnp.stack(keys), temperature,
+            )
+            return sampled, done, counts
+        sampled, done, self._k_pool, self._v_pool = self._decode(
+            self.params, self._k_pool, self._v_pool,
+            jnp.asarray(tokens), jnp.asarray(positions),
+            jnp.asarray(steps), jnp.asarray(budgets),
+            jnp.asarray(active), jnp.asarray(tables),
+            jnp.stack(keys), temperature,
+        )
+        return sampled, done, None
+
+    def _commit_decode(self, sampled, done, counts, n_spec) -> None:
+        """Append each live slot's sampled token(s), roll speculative page
+        cursors back, and retire whatever the done-mask finished."""
         tel = obs.get()
-        with tel.span("serve.decode_step", slots=self.active_slots):
-            if self.speculative:
-                (sampled, counts, done, self._k_pool, self._v_pool,
-                 self._dk_pool, self._dv_pool) = self._decode(
-                    self.params, self._draft_params,
-                    self._k_pool, self._v_pool, self._dk_pool, self._dv_pool,
-                    jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(steps), jnp.asarray(budgets),
-                    jnp.asarray(active), jnp.asarray(tables),
-                    jnp.stack(keys),
-                    jnp.float32(max(self.temperature, 1e-6)),
-                )
-                counts = np.asarray(counts)
-            else:
-                sampled, done, self._k_pool, self._v_pool = self._decode(
-                    self.params, self._k_pool, self._v_pool,
-                    jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(steps), jnp.asarray(budgets),
-                    jnp.asarray(active), jnp.asarray(tables),
-                    jnp.stack(keys),
-                    jnp.float32(max(self.temperature, 1e-6)),
-                )
-                counts = None
-        sampled = np.asarray(sampled)
-        done = np.asarray(done)
         self.stats["decode_steps"] += 1
         if tel.enabled:
             tel.registry.counter("serve.decode_steps").inc()
@@ -1130,7 +1188,6 @@ class ContinuousServer:
                 reg.histogram(
                     "serve.spec_rollback", SPEC_ROLLBACK_EDGES
                 ).observe(drafted - accepted)
-        return [u for u in self._results if u not in before]
 
     def run(self, requests: Optional[List[Request]] = None
             ) -> Dict[Any, Result]:

@@ -225,6 +225,11 @@ class RequestDriver:
             return self._tick()
 
     def _tick(self) -> bool:
+        """The tick, timed as the root span ``serve.tick``."""
+        with obs.get().span("serve.tick"):
+            return self._tick_work()
+
+    def _tick_work(self) -> bool:
         srv = self.server
         worked = False
 
@@ -260,15 +265,16 @@ class RequestDriver:
         if srv.active_slots:
             retired = srv.step()  # server queue is empty: no hidden admits
             now = self._clock()
-            for slot in srv._slots:
-                if slot is not None and slot.uid in self._streams:
-                    self._emit(slot.uid, slot.out, now)
-            for uid in retired:
-                if uid in self._streams:
-                    result = srv._results[uid]
-                    S = len(self._streams[uid].request.tokens)
-                    self._emit(uid, result.tokens[S:], now)
-                    self._finish(uid, result, now)
+            with obs.get().span("serve.emit"):
+                for slot in srv._slots:
+                    if slot is not None and slot.uid in self._streams:
+                        self._emit(slot.uid, slot.out, now)
+                for uid in retired:
+                    if uid in self._streams:
+                        result = srv._results[uid]
+                        S = len(self._streams[uid].request.tokens)
+                        self._emit(uid, result.tokens[S:], now)
+                        self._finish(uid, result, now)
             worked = True
         return worked
 
@@ -277,15 +283,16 @@ class RequestDriver:
         close out max_new==1 requests (already retired by the server)."""
         now = self._clock()
         srv = self.server
-        for slot in srv._slots:
-            if slot is not None and slot.uid == uid:
-                self._emit(uid, slot.out, now)
-                return
-        result = srv._results.get(uid)  # max_new == 1: retired at admit
-        if result is not None and uid in self._streams:
-            S = result.tokens.size - self._streams[uid].request.max_new
-            self._emit(uid, result.tokens[S:], now)
-            self._finish(uid, result, now)
+        with obs.get().span("serve.emit"):
+            for slot in srv._slots:
+                if slot is not None and slot.uid == uid:
+                    self._emit(uid, slot.out, now)
+                    return
+            result = srv._results.get(uid)  # max_new == 1: retired at admit
+            if result is not None and uid in self._streams:
+                S = result.tokens.size - self._streams[uid].request.max_new
+                self._emit(uid, result.tokens[S:], now)
+                self._finish(uid, result, now)
 
     def _emit(self, uid: Any, generated: Sequence[int], now: float) -> None:
         stream = self._streams[uid]

@@ -159,7 +159,18 @@ def make_fused_chunk_fn(
     gathered = pplan is not None and (pplan.any_sharded or bool(dp_axes))
     loss_axes = "ens" if pplan is None else pop_axes + dp_axes
 
-    def chunk_fn(population, opt_state, batches, lrs, keydata, gates, n_valid):
+    def scoped_loss(pm, bm):
+        with jax.named_scope("loss"):
+            return loss_fn(pm, bm)
+
+    def scoped_update(pm, gm, sm, lr):
+        with jax.named_scope("optimizer"):
+            return opt_update(pm, gm, sm, lr)
+
+    # named for the program it compiles to (``jit_train_chunk``), as its
+    # compile counter is
+    def train_chunk(population, opt_state, batches, lrs, keydata, gates,
+                    n_valid):
         _CHUNK_TRACES[0] += 1
         # host-side effect at trace time only: the compile counter mirrors
         # the ≤2-executables contract _CHUNK_TRACES guards
@@ -208,7 +219,7 @@ def make_fused_chunk_fn(
                 # shard of an unsharded member.
                 p_full = shardplan.all_gather_population(p, pplan)
                 losses, g_full = jax.vmap(
-                    lambda pm, bm: jax.value_and_grad(loss_fn)(pm, bm)
+                    lambda pm, bm: jax.value_and_grad(scoped_loss)(pm, bm)
                 )(p_full, batch)
                 if dp_axes:
                     g_full = jax.tree_util.tree_map(
@@ -216,12 +227,12 @@ def make_fused_chunk_fn(
                     )
                 g_loc = shardplan.shard_population(g_full, pplan)
                 p2, s2 = jax.vmap(
-                    lambda pm, gm, sm: opt_update(pm, gm, sm, lr)
+                    lambda pm, gm, sm: scoped_update(pm, gm, sm, lr)
                 )(p, g_loc, s)
             else:
                 def one(pm, sm, bm):
-                    loss, g = jax.value_and_grad(loss_fn)(pm, bm)
-                    p2_, s2_ = opt_update(pm, g, sm, lr)
+                    loss, g = jax.value_and_grad(scoped_loss)(pm, bm)
+                    p2_, s2_ = scoped_update(pm, g, sm, lr)
                     return p2_, s2_, loss
 
                 p2, s2, losses = jax.vmap(one)(p, s, batch)
@@ -265,7 +276,7 @@ def make_fused_chunk_fn(
         return p, s, loss_last
 
     f = jax.shard_map(
-        chunk_fn,
+        train_chunk,
         mesh=mesh,
         in_specs=(pspec, ospec, bspecs, P(), P(), P(), P()),
         out_specs=(pspec, ospec, P()),
@@ -343,7 +354,8 @@ def make_pipelined_chunk_fn(
         ]
         return jax.tree_util.tree_unflatten(td, out)
 
-    def chunk_fn(population, opt_state, batches, lrs, keydata, gates, n_valid):
+    def train_chunk_pipelined(population, opt_state, batches, lrs, keydata,
+                              gates, n_valid):
         _CHUNK_TRACES[0] += 1
         obs.get().record_compile(
             "train_chunk_pipelined", mixing=bool(with_mixing)
@@ -385,6 +397,10 @@ def make_pipelined_chunk_fn(
             # restore the global view
             return acc / num_micro
 
+        def scoped_loss(pm, mb):
+            with jax.named_scope("loss"):
+                return member_loss(pm, mb)
+
         def body(i, carry):
             p, s, _ = carry
             batch, lr, kd, gate = jax.tree_util.tree_map(
@@ -399,16 +415,17 @@ def make_pipelined_chunk_fn(
                 batch,
             )
             losses, g = jax.vmap(
-                lambda pm, bm: jax.value_and_grad(member_loss)(pm, bm)
+                lambda pm, bm: jax.value_and_grad(scoped_loss)(pm, bm)
             )(p, micro)
             g = _sync_pipe_grads(g)
             if dp_axes:
                 g = jax.tree_util.tree_map(
                     lambda x: lax.pmean(x, dp_axes), g
                 )
-            p2, s2 = jax.vmap(
-                lambda pm, gm, sm: opt_update(pm, gm, sm, lr)
-            )(p, g, s)
+            with jax.named_scope("optimizer"):
+                p2, s2 = jax.vmap(
+                    lambda pm, gm, sm: opt_update(pm, gm, sm, lr)
+                )(p, g, s)
             if with_mixing:
                 k = jax.random.wrap_key_data(kd)
                 p3, s3 = shardplan.mix_collective_sharded(
@@ -428,7 +445,7 @@ def make_pipelined_chunk_fn(
         return p, s, loss_last
 
     f = jax.shard_map(
-        chunk_fn,
+        train_chunk_pipelined,
         mesh=mesh,
         in_specs=(pspec, ospec, bspecs, P(), P(), P(), P()),
         out_specs=(pspec, ospec, P()),

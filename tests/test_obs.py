@@ -210,18 +210,6 @@ def test_counter_mirrors_float_accumulation():
     assert c.value == total  # bitwise, not approx
 
 
-def test_prometheus_text():
-    r = Registry()
-    r.counter("a.b").inc(2)
-    r.histogram("lat", (0.1, 1.0)).observe(0.05)
-    txt = r.prometheus_text()
-    assert "# TYPE a_b counter" in txt
-    assert "a_b 2" in txt
-    assert 'lat_bucket{le="0.1"} 1' in txt
-    assert 'lat_bucket{le="+Inf"} 1' in txt
-    assert "lat_count 1" in txt
-
-
 def test_histogram_validation():
     with pytest.raises(ValueError):
         Histogram("bad", ())

@@ -9,7 +9,9 @@ per ``docs/OBSERVABILITY.md``:
     device count, platform, timestamps);
   * every non-provenance record has a ``name`` matching
     ``[a-z0-9_.]+`` (dot-separated lowercase) and a float ``ts``;
-  * spans carry ``dur_s >= 0``;
+  * spans carry ``dur_s >= 0``, an integer ``t0_ns >= 0`` (the start on
+    the profiler's host clock) and ``parent``, the enclosing span's name
+    or null;
   * metric snapshot lines are internally consistent — histograms have
     ``len(counts) == len(edges) + 1`` and ``sum(counts) == count``,
     counters are non-negative;
@@ -100,6 +102,14 @@ def check_stream(path: str, require_comm: bool = False) -> List[str]:
             dur = rec.get("dur_s")
             if not isinstance(dur, (int, float)) or dur < 0:
                 err(i, f"span needs dur_s >= 0, got {dur!r}")
+            t0 = rec.get("t0_ns")
+            if not isinstance(t0, int) or isinstance(t0, bool) or t0 < 0:
+                err(i, f"span needs an integer t0_ns >= 0, got {t0!r}")
+            parent = rec.get("parent", "")
+            if parent is not None and not (isinstance(parent, str)
+                                           and NAME_RE.match(parent)):
+                err(i, f"span parent must be a span name or null, got "
+                       f"{parent!r}")
         elif kind == "event" and name == "train.comm_volume":
             per = rec.get("comm_per_mix_step")
             steps = rec.get("mix_steps")
