@@ -1,8 +1,12 @@
 """Pallas TPU kernel: paged-attention decode over a block-pool KV cache.
 
 The continuous-batching runtime (``repro.serving.batching``) stores KV in a
-shared pool of fixed-size pages, ``(num_pages, page_size, KV, hd)``, with a
-per-slot **page table** mapping logical context positions to pool pages.
+shared pool of fixed-size pages, with a per-slot **page table** mapping
+logical context positions to pool pages.  The kernel reads pages as
+``(num_pages, page_size, KV, hd)``; the runtime hands it the free view
+``(L * P, page_size, KV, hd)`` of its ``(L, P, page_size * KV, hd)``
+pools with the page table offset to one layer's pages
+(``models.layers.paged_kernel_view``).
 Decode-time attention then needs a gather of each slot's pages followed by
 a masked attend — two HBM passes when written naively in jnp (materialize
 ``(B, max_pages·page_size, KV, hd)``, then attend).

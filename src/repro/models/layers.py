@@ -379,16 +379,28 @@ def paged_pools_init(cfg: ModelConfig, num_pages: int, page_size: int,
                      num_layers: int, kv_dtype: str = None):
     """Block-pool KV cache: ``num_pages`` shared fixed-size pages per layer.
 
-    Layout ``(num_layers, num_pages, page_size, KV, hd)`` — the per-slot
-    view is a **page table** of pool indices, not a contiguous slice, so
-    slots with different context lengths share one allocation and common
-    prompt prefixes can share pages (``repro.serving.batching`` owns the
+    Layout ``(num_layers, num_pages, page_size * KV, hd)``: row
+    ``offset * KV + h`` of a page holds kv head ``h`` at in-page position
+    ``offset``.  That is the ``(page_size * KV, hd)`` tile the paged
+    kernel reads, so the pool needs no relayout on its way in, and
+    ``pool.reshape(L * P, page_size, KV, hd)`` is a free view of every
+    layer's pages at once (:func:`paged_kernel_view`).  The per-slot view
+    is a **page table** of pool indices, not a contiguous slice, so slots
+    with different context lengths share one allocation and common prompt
+    prefixes can share pages (``repro.serving.batching`` owns the
     table/refcount bookkeeping).  Page 0 is reserved by the runtime as a
     scratch page for inactive slots.
 
+    The serving programs donate the pools and update them **in place**:
+    the layer loop carries the whole pools, a step scatters only its new
+    rows into ``[layer, page, offset * KV : (offset + 1) * KV]``, and the
+    attend reads the view above with the page table offset by
+    ``layer * num_pages``; nothing slices out, relayouts or writes back a
+    layer's pool.
+
     ``kv_dtype=None`` stores pages in the model's param dtype (bitwise
     path).  ``kv_dtype="int8"`` stores each pool as
-    ``{"q": int8 (L, P, page_size, KV, hd), "scale": f32 (L, P)}`` — one
+    ``{"q": int8 (L, P, page_size * KV, hd), "scale": f32 (L, P)}`` — one
     symmetric scale per (layer, page), written by
     :func:`paged_store_rows` / :func:`paged_store_chunk` and applied at
     read time inside both paged attends.  Page 0's scale is pinned to
@@ -396,7 +408,7 @@ def paged_pools_init(cfg: ModelConfig, num_pages: int, page_size: int,
     if kv_dtype not in KV_DTYPES:
         raise ValueError(f"kv_dtype={kv_dtype!r}; expected one of {KV_DTYPES}")
     hd = cfg.resolved_head_dim
-    shape = (num_layers, num_pages, page_size, cfg.num_kv_heads, hd)
+    shape = (num_layers, num_pages, page_size * cfg.num_kv_heads, hd)
     if kv_dtype is None:
         dtype = param_dtype(cfg)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -422,11 +434,62 @@ def kv_page_scale(x, floor: float = None):
     return jnp.maximum(jnp.max(jnp.abs(x)) / 127.0, floor)
 
 
-def paged_store_rows(pool, page_idx, offset, rows):
-    """Write one (KV, hd) row per batch entry into ``pool`` at
-    ``(page_idx[b], offset[b])`` — the decode-step scatter.
+def _window_scatter(pool, idx, values):
+    """Write ``values[n]`` into the window of ``pool`` that starts at
+    ``idx[n]``: ``idx`` indexes the pool's leading ``k = idx.shape[-1]``
+    axes, the first ``k - 1`` of which the window drops, so ``values[n]``
+    spans the rest of the pool's axes from ``idx[n, k - 1]`` on."""
+    k = idx.shape[-1]
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=tuple(range(1, pool.ndim - k + 2)),
+        inserted_window_dims=tuple(range(k - 1)),
+        scatter_dims_to_operand_dims=tuple(range(k)))
+    return jax.lax.scatter(pool, idx, values.astype(pool.dtype), dnums,
+                           mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
 
-    For plain pools this is the raw ``.at[].set``.  For int8 pools the
+
+@jax.custom_batching.custom_vmap
+def _pool_scatter(pool, idx, values):
+    return _window_scatter(pool, idx, values)
+
+
+@_pool_scatter.def_vmap
+def _pool_scatter_vmap(axis_size, in_batched, pool, idx, values):
+    # Under vmap (ensemble serving vmaps members over stacked pools) the
+    # member becomes one more scatter index.  JAX's own rule would make it
+    # an axis of each update's window, and the TPU compiler then lays the
+    # whole pool out member-minor for the scatter and copies it back for
+    # the kernel every layer.
+    pool, idx, values = (
+        x if b else jnp.broadcast_to(x, (axis_size,) + x.shape)
+        for x, b in zip((pool, idx, values), in_batched))
+    n = idx.shape[1]
+    member = jnp.broadcast_to(
+        jnp.arange(axis_size, dtype=idx.dtype)[:, None, None],
+        (axis_size, n, 1))
+    idx = jnp.concatenate([member, idx], axis=-1).reshape(axis_size * n, -1)
+    values = values.reshape((axis_size * n,) + values.shape[2:])
+    return _window_scatter(pool, idx, values), True
+
+
+def _pool_write(pool, layer, pages, starts, values):
+    """Write ``values[n]`` (rows, hd) into ``pool[layer, pages[n],
+    starts[n] : starts[n] + rows]`` in place: a scatter of those windows
+    alone, whether or not the pool is vmapped over members."""
+    n = values.shape[0]
+    idx = jnp.stack([jnp.broadcast_to(layer, (n,)), pages,
+                     jnp.broadcast_to(starts, (n,))], axis=-1)
+    return _pool_scatter(pool, idx.astype(jnp.int32), values)
+
+
+def paged_store_rows(pool, layer, page_idx, offset, rows):
+    """Write one (KV, hd) row per batch entry into layer ``layer`` of the
+    whole ``pool`` at ``(page_idx[b], offset[b])`` — the decode-step
+    scatter.  Only the ``B`` windows ``[layer, page_idx[b],
+    offset[b] * KV : (offset[b] + 1) * KV]`` are written; the rest of the
+    pool keeps its buffer and its bits.
+
+    For plain pools this is the raw scatter.  For int8 pools the
     written pages' scales grow monotonically to cover the new rows
     (``max(old, amax(row)/127)``): untouched pages keep their bits, and a
     page whose scale does not change keeps its already-written rows
@@ -439,73 +502,110 @@ def paged_store_rows(pool, page_idx, offset, rows):
     the same pre-step page bits and rescales them identically, and the
     new rows land via a per-``(page, offset)`` scatter whose index pairs
     are distinct for live rows."""
+    kv_heads = rows.shape[1]
+    starts = offset * kv_heads
     if not isinstance(pool, dict):
-        return pool.at[page_idx, offset].set(rows.astype(pool.dtype))
+        return _pool_write(pool, layer, page_idx, starts, rows)
     q, scale = pool["q"], pool["scale"]
     rows = rows.astype(jnp.float32)                       # (B, KV, hd)
     row_amax = jnp.max(jnp.abs(rows), axis=(1, 2))        # (B,)
-    s_new = scale.at[page_idx].max(row_amax / 127.0)      # (P,) dup-safe
+    s_old = scale[layer]                                  # (P,)
+    s_new = s_old.at[page_idx].max(row_amax / 127.0)      # dup-safe
     s_new = s_new.at[0].set(KV_SCRATCH_SCALE)
     # rescale the touched pages' existing bits; duplicates gather the same
     # old page and the same (s_old/s_new) ratio, so their scatter-back
     # writes are identical and any winner is correct
-    ratio = (scale / s_new)[page_idx]                     # (B,)
-    pages = jnp.round(q[page_idx].astype(jnp.float32)
-                      * ratio[:, None, None, None])
+    ratio = (s_old / s_new)[page_idx]                     # (B,)
+    pages = jnp.round(q[layer, page_idx].astype(jnp.float32)
+                      * ratio[:, None, None])
     pages = jnp.clip(pages, -127, 127).astype(jnp.int8)
-    q = q.at[page_idx].set(pages)
+    q = _pool_write(q, layer, page_idx, 0, pages)
     qrows = jnp.clip(jnp.round(rows / s_new[page_idx][:, None, None]),
                      -127, 127).astype(jnp.int8)
-    return {"q": q.at[page_idx, offset].set(qrows), "scale": s_new}
+    return {"q": _pool_write(q, layer, page_idx, starts, qrows),
+            "scale": scale.at[layer].set(s_new)}
 
 
-def paged_store_chunk(pool, page_table, positions, rows):
-    """Write a contiguous chunk of rows for ONE slot — the prefill scatter.
-
-    ``positions`` are the rows' absolute positions; their pages are
-    ``page_table[pos // page_size]``.  Same quantization discipline as
-    :func:`paged_store_rows`; the static page-window covers the chunk's
-    worst-case page span, and window entries past the chunk's last page
-    are redirected to the scratch page (page 0) so no live page is ever
-    gather/scattered without rows."""
-    pos = positions.astype(jnp.int32)
-    if not isinstance(pool, dict):
-        page_size = pool.shape[1]
-        return pool.at[page_table[pos // page_size], pos % page_size].set(
-            rows.astype(pool.dtype))
-    q, scale = pool["q"], pool["scale"]
-    page_size = q.shape[1]
+def _chunk_window(page_table, pos, page_size: int):
+    """The pool pages a chunk of rows at positions ``pos`` touches, as a
+    static window of ``T // page_size + 2`` logical pages from the page of
+    ``pos[0]``: ``(pids, local, offs)`` — each window entry's pool page
+    (entries past the chunk's last page redirected to the scratch page 0,
+    so no live page is ever gathered or written without rows), and each
+    row's window entry and in-page offset."""
+    T = pos.shape[0]
     max_pages = page_table.shape[0]
-    rows = rows.astype(jnp.float32)                       # (T, KV, hd)
-    T = rows.shape[0]
-    n_w = T // page_size + 2                              # page-window bound
+    n_w = T // page_size + 2
     first = pos[0] // page_size
     window = first + jnp.arange(n_w)                      # logical pages
     touched = window <= pos[T - 1] // page_size
     pids = jnp.where(touched,
                      page_table[jnp.minimum(window, max_pages - 1)], 0)
-    local = pos // page_size - first                      # (T,) in-window
-    offs = pos % page_size
+    return pids, pos // page_size - first, pos % page_size
+
+
+def paged_store_chunk(pool, layer, page_table, positions, rows):
+    """Write a contiguous chunk of rows for ONE slot into layer ``layer``
+    of the whole ``pool`` — the prefill scatter.  Only the chunk's rows
+    ``[layer, page_table[pos // page_size], (pos % page_size) * KV + h]``
+    change.
+
+    ``positions`` are the rows' absolute positions.  The chunk's pages
+    (:func:`_chunk_window`) are gathered, given their new rows, and
+    written back whole: one window of ``page_size * KV`` rows a page, not
+    one a row, since the chip writes a scatter's windows one at a time.
+    The rest of each page goes back bit for bit.  Same quantization
+    discipline as :func:`paged_store_rows`."""
+    pos = positions.astype(jnp.int32)
+    T, kv_heads, hd = rows.shape
+    quantized = isinstance(pool, dict)
+    q = pool["q"] if quantized else pool
+    page_size = q.shape[2] // kv_heads
+    pids, local, offs = _chunk_window(page_table, pos, page_size)
+    n_w = pids.shape[0]
+    pages = q[layer, pids]                                # (n_w, ps*KV, hd)
+    if not quantized:
+        pages = pages.reshape(n_w, page_size, kv_heads, hd).at[
+            local, offs].set(rows.astype(q.dtype))
+        return _pool_write(q, layer, pids, 0, pages.reshape(n_w, -1, hd))
+    scale = pool["scale"]
+    rows = rows.astype(jnp.float32)                       # (T, KV, hd)
     row_amax = jnp.max(jnp.abs(rows), axis=(1, 2))        # (T,)
     page_amax = jnp.zeros((n_w,), jnp.float32).at[local].max(row_amax)
-    s_old = scale[pids]
+    s_old = scale[layer, pids]
     s_new = jnp.maximum(s_old, page_amax / 127.0)
     s_new = jnp.where(pids == 0, KV_SCRATCH_SCALE, s_new)
-    pages = q[pids].astype(jnp.float32)                   # (n_w, ps, KV, hd)
-    pages = jnp.round(pages * (s_old / s_new)[:, None, None, None])
-    pages = pages.at[local, offs].set(
+    pages = jnp.round(pages.astype(jnp.float32)
+                      * (s_old / s_new)[:, None, None])
+    pages = pages.reshape(n_w, page_size, kv_heads, hd).at[local, offs].set(
         jnp.round(rows / s_new[local][:, None, None]))
     pages = jnp.clip(pages, -127, 127).astype(jnp.int8)
-    return {"q": q.at[pids].set(pages),
-            "scale": scale.at[pids].set(s_new)}
+    return {"q": _pool_write(q, layer, pids, 0, pages.reshape(n_w, -1, hd)),
+            "scale": scale.at[layer, pids].set(s_new)}
 
 
-def gqa_decode_paged(p, cfg: ModelConfig, x, k_pool_l, v_pool_l, page_table,
-                     positions, use_pallas: bool):
+def paged_kernel_view(pool, layer, page_table, kv_heads: int):
+    """What the paged attends read of layer ``layer``: the whole pool
+    viewed as ``(L * P, page_size, KV, hd)`` (a free reshape) with
+    ``page_table`` offset to that layer's pages, and for int8 pools the
+    scales flattened to ``(L * P,)``.  Returns ``(pages, table, scale)``,
+    ``scale`` None for plain pools."""
+    q = pool["q"] if isinstance(pool, dict) else pool
+    n_layers, P, R, hd = q.shape
+    view = q.reshape(n_layers * P, R // kv_heads, kv_heads, hd)
+    scale = (pool["scale"].reshape(n_layers * P) if isinstance(pool, dict)
+             else None)
+    return view, page_table + layer * P, scale
+
+
+def gqa_decode_paged(p, cfg: ModelConfig, x, k_pool, v_pool, layer,
+                     page_table, positions, use_pallas: bool):
     """One-token decode for a batch of slots against the paged pool.
 
       x          : (B, 1, D) — one new token per slot
-      k/v_pool_l : (P, page_size, KV, hd) — this layer's page pool
+      k/v_pool   : (L, P, page_size * KV, hd) — the WHOLE pools
+                   (:func:`paged_pools_init`), updated in place
+      layer      : int32 scalar — this layer's index into the pools
       page_table : (B, max_pages) int32
       positions  : (B,) int32 — absolute write position of each new token
                    (its page is ``page_table[b, pos // page_size]``)
@@ -515,10 +615,11 @@ def gqa_decode_paged(p, cfg: ModelConfig, x, k_pool_l, v_pool_l, page_table,
 
     Every slot's new K/V lands in a page that slot owns exclusively (the
     runtime never hands a shared prefix page out as a write target), so
-    the scatter below cannot collide across slots.  int8 pools
-    (``{"q","scale"}`` dicts — see :func:`paged_pools_init`) quantize the
-    write and dequantize inside the attend.  Returns
-    ``(out (B,1,D), k_pool_l, v_pool_l)``.
+    the scatter below cannot collide across slots.  The attend reads
+    :func:`paged_kernel_view` of the pools.  int8 pools (``{"q","scale"}``
+    dicts — see :func:`paged_pools_init`) quantize the write and
+    dequantize inside the attend.  Returns ``(out (B,1,D), k_pool,
+    v_pool)``.
     """
     from repro.kernels.paged_attention import paged_attention_pallas
     from repro.kernels.ref import paged_attention_ref
@@ -526,32 +627,36 @@ def gqa_decode_paged(p, cfg: ModelConfig, x, k_pool_l, v_pool_l, page_table,
     B, T, _ = x.shape
     assert T == 1
     q, k, v = _qkv(p, cfg, x, positions[:, None])
-    quantized = isinstance(k_pool_l, dict)
-    page_size = (k_pool_l["q"] if quantized else k_pool_l).shape[1]
+    KV = cfg.num_kv_heads
+    page_size = (k_pool["q"] if isinstance(k_pool, dict)
+                 else k_pool).shape[2] // KV
     pos = positions.astype(jnp.int32)
     page_idx = page_table[jnp.arange(B), pos // page_size]  # (B,)
     offset = pos % page_size
     with jax.named_scope("kv_write"):
-        k_pool_l = paged_store_rows(k_pool_l, page_idx, offset, k[:, 0])
-        v_pool_l = paged_store_rows(v_pool_l, page_idx, offset, v[:, 0])
+        k_pool = paged_store_rows(k_pool, layer, page_idx, offset, k[:, 0])
+        v_pool = paged_store_rows(v_pool, layer, page_idx, offset, v[:, 0])
     lengths = pos + 1  # context = everything written so far incl. this token
     attend = paged_attention_pallas if use_pallas else paged_attention_ref
     with jax.named_scope("paged_attention"):
-        if quantized:
-            out = attend(q[:, 0], k_pool_l["q"], v_pool_l["q"], page_table,
-                         lengths, k_scale=k_pool_l["scale"],
-                         v_scale=v_pool_l["scale"])
+        kp, table, ks = paged_kernel_view(k_pool, layer, page_table, KV)
+        vp, _, vs = paged_kernel_view(v_pool, layer, page_table, KV)
+        if ks is not None:
+            out = attend(q[:, 0], kp, vp, table, lengths, k_scale=ks,
+                         v_scale=vs)
         else:
-            out = attend(q[:, 0], k_pool_l, v_pool_l, page_table, lengths)
-    return out.reshape(B, 1, -1) @ p["wo"], k_pool_l, v_pool_l
+            out = attend(q[:, 0], kp, vp, table, lengths)
+    return out.reshape(B, 1, -1) @ p["wo"], k_pool, v_pool
 
 
-def gqa_prefill_paged(p, cfg: ModelConfig, x, k_pool_l, v_pool_l, page_table,
-                      positions):
+def gqa_prefill_paged(p, cfg: ModelConfig, x, k_pool, v_pool, layer,
+                      page_table, positions):
     """Chunk/suffix prefill for ONE slot against the paged pool.
 
       x          : (1, T, D) — hidden states of a contiguous prompt chunk
-      k/v_pool_l : (P, page_size, KV, hd) — this layer's page pool
+      k/v_pool   : (L, P, page_size * KV, hd) — the WHOLE pools, updated
+                   in place
+      layer      : int32 scalar — this layer's index into the pools
       page_table : (max_pages,) int32 — the slot's pages, prompt order
       positions  : (T,) int32 — absolute positions pos0 .. pos0+T-1
                    (traced, so one compile serves every chunk offset)
@@ -559,7 +664,8 @@ def gqa_prefill_paged(p, cfg: ModelConfig, x, k_pool_l, v_pool_l, page_table,
     Writes the chunk's K/V into the slot's pages (all write targets are
     slot-owned — cached prefix pages sit strictly below ``positions[0]``
     and are never written), then attends over the table-gathered context
-    under the causal mask ``j <= position``.  Rows are bitwise-identical
+    ``pool[layer, page_table]`` (the slot's pages, not the pool) under
+    the causal mask ``j <= position``.  Rows are bitwise-identical
     to the same rows of the whole-prompt :func:`gqa_prefill`: q/k/v are
     per-position ops, the gathered context lists positions in order, and
     the masked tail contributes exact zeros to the softmax and the value
@@ -572,22 +678,22 @@ def gqa_prefill_paged(p, cfg: ModelConfig, x, k_pool_l, v_pool_l, page_table,
     pos = positions.astype(jnp.int32)
     # in-chunk positions are distinct, so the store never scatter-dups
     with jax.named_scope("kv_write"):
-        k_pool_l = paged_store_chunk(k_pool_l, page_table, pos, k[0])
-        v_pool_l = paged_store_chunk(v_pool_l, page_table, pos, v[0])
-    if isinstance(k_pool_l, dict):
-        kc = kv_dequantize(k_pool_l["q"][page_table],
-                           k_pool_l["scale"][page_table][:, None, None, None])
-        vc = kv_dequantize(v_pool_l["q"][page_table],
-                           v_pool_l["scale"][page_table][:, None, None, None])
-        kc = kc.reshape(1, -1, cfg.num_kv_heads, k.shape[-1])
-        vc = vc.reshape(1, -1, cfg.num_kv_heads, v.shape[-1])
-    else:
-        kc = k_pool_l[page_table].reshape(1, -1, cfg.num_kv_heads, k.shape[-1])
-        vc = v_pool_l[page_table].reshape(1, -1, cfg.num_kv_heads, v.shape[-1])
+        k_pool = paged_store_chunk(k_pool, layer, page_table, pos, k[0])
+        v_pool = paged_store_chunk(v_pool, layer, page_table, pos, v[0])
+
+    def context(pool):
+        if isinstance(pool, dict):
+            c = kv_dequantize(pool["q"][layer, page_table],
+                              pool["scale"][layer, page_table][:, None, None])
+        else:
+            c = pool[layer, page_table]
+        return c.reshape(1, -1, cfg.num_kv_heads, k.shape[-1])
+
+    kc, vc = context(k_pool), context(v_pool)
     ctx = kc.shape[1]
     mask = jnp.arange(ctx)[None, :] <= pos[:, None]  # (T, ctx)
     out = sdpa(q, kc, vc, mask, cfg.num_kv_heads)
-    return out.reshape(B, T, -1) @ p["wo"], k_pool_l, v_pool_l
+    return out.reshape(B, T, -1) @ p["wo"], k_pool, v_pool
 
 
 # ---------------------------------------------------------------------------

@@ -517,13 +517,17 @@ def decode_step_paged(params, cfg: ModelConfig, tokens, positions, pools,
       positions   : (B,) int32 — each token's absolute write position
                     (per-slot, unlike :func:`decode_step`'s shared scalar —
                     slots in a continuous batch sit at different depths)
-      pools       : {"k","v"}: (L, P, page_size, KV, hd)
+      pools       : {"k","v"}: (L, P, page_size * KV, hd)
                     (:func:`repro.models.layers.paged_pools_init`)
       page_tables : (B, max_pages) int32 pool-page ids per slot
 
     Everything is traced — admissions, retirements, and page-table edits
     change VALUES only, so the continuous-batching runtime compiles this
-    exactly once per pool geometry.  Returns ``(logits (B,1,V), pools)``.
+    exactly once per pool geometry.  The layer loop carries the whole
+    pools (the layer index is the scan's input) and each layer writes
+    only its B new rows into them, so a program that donates the pools
+    updates them in place: no layer's pool is sliced out, relaid out or
+    written back.  Returns ``(logits (B,1,V), pools)``.
     """
     reason = paged_decode_supported(cfg)
     if reason is not None:
@@ -533,22 +537,26 @@ def decode_step_paged(params, cfg: ModelConfig, tokens, positions, pools,
     if cfg.pos_kind == "learned":
         x = x + params["embed"]["pos"][pos][:, None]
 
-    def body(h, xs):
-        block_l, kp_l, vp_l = xs
+    def body(carry, layer):
+        h, kp, vp = carry
+        # indexed by the layer rather than scanned: an ensemble vmaps this
+        # over members, and scanning (N, L, ...) weights would transpose
+        # all of them to (L, N, ...) on every call
+        block_l = jax.tree_util.tree_map(lambda w: w[layer], params["blocks"])
         a_in = L.rmsnorm(block_l["ln1"], h, cfg.norm_eps)
         with jax.named_scope("attention"):
-            a, kp_l, vp_l = L.gqa_decode_paged(
-                block_l["attn"], cfg, a_in, kp_l, vp_l, page_tables, pos,
+            a, kp, vp = L.gqa_decode_paged(
+                block_l["attn"], cfg, a_in, kp, vp, layer, page_tables, pos,
                 use_pallas,
             )
         h = h + a
         y, _ = _mlp_apply(block_l["mlp"], cfg,
                           L.rmsnorm(block_l["ln2"], h, cfg.norm_eps))
-        return h + y, (kp_l, vp_l)
+        return (h + y, kp, vp), None
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        body, x, (params["blocks"], pools["k"], pools["v"]),
-        unroll=cfg.scan_unroll,
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        body, (x, pools["k"], pools["v"]),
+        jnp.arange(cfg.num_layers, dtype=jnp.int32), unroll=cfg.scan_unroll,
     )
     return _logits(params, cfg, x), {"k": k_pool, "v": v_pool}
 
@@ -576,7 +584,9 @@ def prefill_paged(params, cfg: ModelConfig, tokens, pos0, pools, page_table):
       pos0       : int or traced scalar — absolute position of ``tokens[0]``
                    (traced by the serving runtime, so ONE compile per chunk
                    length serves every offset and every slot)
-      pools      : {"k","v"}: (L, P, page_size, KV, hd)
+      pools      : {"k","v"}: (L, P, page_size * KV, hd), carried whole
+                   through the layer loop and written in place (only the
+                   chunk's rows), as in :func:`decode_step_paged`
       page_table : (max_pages,) int32 — the slot's pages in prompt order;
                    entries below ``pos0 // page_size`` may be chain-hash
                    shared prefix pages (read, never written)
@@ -594,22 +604,23 @@ def prefill_paged(params, cfg: ModelConfig, tokens, pos0, pools, page_table):
     positions = pos0 + jnp.arange(T, dtype=jnp.int32)
     x = _embed_tokens(params, cfg, tokens[None], pos0=pos0)
 
-    def body(h, xs):
-        block_l, kp_l, vp_l = xs
+    def body(carry, layer):
+        h, kp, vp = carry
+        block_l = jax.tree_util.tree_map(lambda w: w[layer], params["blocks"])
         a_in = L.rmsnorm(block_l["ln1"], h, cfg.norm_eps)
         with jax.named_scope("attention"):
-            a, kp_l, vp_l = L.gqa_prefill_paged(
-                block_l["attn"], cfg, a_in, kp_l, vp_l, page_table,
+            a, kp, vp = L.gqa_prefill_paged(
+                block_l["attn"], cfg, a_in, kp, vp, layer, page_table,
                 positions,
             )
         h = h + a
         y, _ = _mlp_apply(block_l["mlp"], cfg,
                           L.rmsnorm(block_l["ln2"], h, cfg.norm_eps))
-        return h + y, (kp_l, vp_l)
+        return (h + y, kp, vp), None
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        body, x, (params["blocks"], pools["k"], pools["v"]),
-        unroll=cfg.scan_unroll,
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        body, (x, pools["k"], pools["v"]),
+        jnp.arange(cfg.num_layers, dtype=jnp.int32), unroll=cfg.scan_unroll,
     )
     return _logits(params, cfg, x[:, -1:]), {"k": k_pool, "v": v_pool}
 

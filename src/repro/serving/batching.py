@@ -372,12 +372,12 @@ def _build_admit(cfg: ModelConfig, ensemble: bool, S: int, n_pages: int,
             last = logits[:, -1]
 
         pad = n_pages * page_size - S
-        def paged(a):
+        def paged(a):  # (..., S, KV, hd) -> (..., n_pages, page_size*KV, hd)
             a = jnp.pad(a, [(0, 0)] * (a.ndim - 3) + [(0, pad), (0, 0), (0, 0)])
-            return a.reshape(a.shape[:-3] + (n_pages, page_size) + a.shape[-2:])
+            return a.reshape(a.shape[:-3] + (n_pages, -1, a.shape[-1]))
 
         k_new, v_new = paged(k_new), paged(v_new)
-        sel = write_mask[:, None, None, None]
+        sel = write_mask[:, None, None]
         if ensemble:
             cur_k = k_pool[:, :, page_ids]
             cur_v = v_pool[:, :, page_ids]

@@ -25,7 +25,9 @@ Contracts under test:
     one trace, and same-key servers share one executable;
   * int8 paged KV tracks the fp32 pools within the pinned logit
     tolerance (program-level), and on the pinned mixed stream emits
-    fp32-identical tokens — speculative + int8 compose.
+    fp32-identical tokens — speculative + int8 compose;
+  * a decode step and a prompt chunk write only their own rows of the
+    pools (int8: and rescale only the pages they touch).
 """
 
 import jax
@@ -437,6 +439,85 @@ def test_int8_decode_logits_track_fp32_within_tolerance():
     for a, b in zip(outs[None], outs["int8"]):
         np.testing.assert_allclose(a, b, rtol=0.0, atol=0.1)
     assert np.argmax(outs[None][1]) == np.argmax(outs["int8"][1])
+
+
+def _filled_pools(kv_dtype, num_pages, page_size, seed):
+    """Pools of the serving layout holding random bits everywhere, so a
+    row a step should not touch is told apart from one it rewrote."""
+    from repro.models import layers as L
+
+    pools = L.paged_pools_init(CFG, num_pages, page_size, CFG.num_layers,
+                               kv_dtype=kv_dtype)
+    rng = np.random.default_rng(seed)
+    if kv_dtype is None:
+        return {n: jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+                for n, a in pools.items()}
+    out = {}
+    for n, a in pools.items():
+        q = rng.integers(-127, 128, a["q"].shape).astype(np.int8)
+        scale = rng.uniform(1e-3, 1e-2, a["scale"].shape).astype(np.float32)
+        scale[:, 0] = L.KV_SCRATCH_SCALE
+        out[n] = {"q": jnp.asarray(q), "scale": jnp.asarray(scale)}
+    return out
+
+
+def _assert_only_rows_written(before, after, pages, offsets, kv_dtype):
+    """Every pool row but ``[l, pages[i], offsets[i] * KV + h]`` (all
+    layers l and kv heads h) is bitwise unchanged; an int8 pool may also
+    rescale the touched pages and their scales, and nothing else."""
+    KV = CFG.num_kv_heads
+    for name in ("k", "v"):
+        b, a = before[name], after[name]
+        if kv_dtype is not None:
+            keep = np.ones(b["scale"].shape, bool)
+            keep[:, pages] = False
+            np.testing.assert_array_equal(np.asarray(a["scale"])[keep],
+                                          np.asarray(b["scale"])[keep])
+            b, a = b["q"], a["q"]
+        b, a = np.asarray(b), np.asarray(a)
+        assert a.shape == b.shape
+        keep = np.ones(b.shape[:3], bool)
+        if kv_dtype is None:
+            for page, off in zip(pages, offsets):
+                keep[:, page, off * KV:(off + 1) * KV] = False
+        else:
+            keep[:, pages] = False
+        np.testing.assert_array_equal(a[keep], b[keep])
+        rows = a[:, pages] != b[:, pages]
+        assert rows.any(), f"{name}: the step wrote none of its rows"
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_decode_step_writes_only_its_rows(kv_dtype):
+    """One paged decode step writes each slot's new K/V row into the
+    whole pool in place and leaves every other row's bits alone."""
+    params = _params()
+    ps, num_pages = 4, 12
+    before = _filled_pools(kv_dtype, num_pages, ps, seed=40)
+    tables = jnp.array([[1, 2, 3], [4, 5, 6], [7, 8, 9]], jnp.int32)
+    positions = jnp.array([0, 6, 11], jnp.int32)
+    tokens = jnp.array([3, 14, 15], jnp.int32)
+    _, after = M.decode_step_paged(params, CFG, tokens, positions, before,
+                                   tables)
+    pos = np.asarray(positions)
+    pages = np.asarray(tables)[np.arange(3), pos // ps]
+    _assert_only_rows_written(before, after, pages, pos % ps, kv_dtype)
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_prompt_chunk_writes_only_its_rows(kv_dtype):
+    """One prompt chunk writes its T rows into the slot's pages in place
+    and leaves every other row's bits alone, the cached context below
+    ``pos0`` included."""
+    params = _params()
+    ps, num_pages, pos0, T = 4, 12, 6, 5
+    before = _filled_pools(kv_dtype, num_pages, ps, seed=41)
+    table = jnp.array([3, 7, 2, 9], jnp.int32)
+    tokens = jnp.arange(10, 10 + T, dtype=jnp.int32)
+    _, after = M.prefill_paged(params, CFG, tokens, pos0, before, table)
+    pos = pos0 + np.arange(T)
+    pages = np.asarray(table)[pos // ps]
+    _assert_only_rows_written(before, after, pages, pos % ps, kv_dtype)
 
 
 def test_int8_kv_stream_matches_fp32_tokens_on_pinned_stream():

@@ -9,6 +9,7 @@ from repro.core import shuffle as shf
 from repro.core.consensus import sq_distance_to_consensus
 from repro.core.compat import resolve_interpret
 from repro.kernels import ops, ref
+from repro.kernels.paged_attention import paged_attention_pallas
 from repro.models import layers as L
 
 KEY = jax.random.key(0)
@@ -255,11 +256,21 @@ def _quantized_pool(key, P, ps, KV, hd):
 
 
 def _fresh_int8_pool(P, ps, KV, hd):
-    """A per-layer int8 pool as paged_pools_init lays one out: zero bits,
-    floor scales, page 0 pinned to the scratch scale."""
-    scale = jnp.full((P,), L.KV_SCALE_FLOOR, jnp.float32)
-    scale = scale.at[0].set(L.KV_SCRATCH_SCALE)
-    return {"q": jnp.zeros((P, ps, KV, hd), jnp.int8), "scale": scale}
+    """A one-layer int8 pool as paged_pools_init lays one out: zero bits
+    in (1, P, ps*KV, hd), floor scales, page 0 pinned to the scratch
+    scale."""
+    scale = jnp.full((1, P), L.KV_SCALE_FLOOR, jnp.float32)
+    scale = scale.at[:, 0].set(L.KV_SCRATCH_SCALE)
+    return {"q": jnp.zeros((1, P, ps * KV, hd), jnp.int8), "scale": scale}
+
+
+def _dequantized_layer0(pool, KV):
+    """Layer 0 of an int8 pool read back as (P, ps, KV, hd) floats, and
+    its (P,) scales."""
+    q = pool["q"][0]
+    q = q.reshape(q.shape[0], -1, KV, q.shape[-1])
+    scale = pool["scale"][0]
+    return L.kv_dequantize(q, scale[:, None, None, None]), scale
 
 
 @pytest.mark.parametrize("B,H,KV,hd,P,ps,mp", [
@@ -301,6 +312,49 @@ def test_paged_attention_quantized_tracks_fp32_within_tolerance():
                                rtol=0.0, atol=5e-2)
 
 
+@pytest.mark.parametrize("quantized", [False, True])
+def test_paged_attention_reads_every_layer_through_the_pool_view(quantized):
+    """The serving pools are (L, P, ps*KV, hd); the kernel reads layer l
+    through the free view (L*P, ps, KV, hd) with the page table offset by
+    l*P (and, for int8, the (L, P) scales flattened).  That must equal the
+    oracle on layer l alone, for every layer."""
+    B, H, KV, hd, Lyr, P, ps, mp = 3, 4, 2, 16, 3, 8, 4, 3
+    ks = [jax.random.fold_in(KEY, 70 + i) for i in range(6)]
+    q = jax.random.normal(ks[0], (B, H, hd))
+    kp = jax.random.normal(ks[1], (Lyr, P, ps * KV, hd))
+    vp = jax.random.normal(ks[2], (Lyr, P, ps * KV, hd))
+    if quantized:
+        k_scale = jax.random.uniform(ks[3], (Lyr, P), minval=0.01, maxval=0.1)
+        v_scale = jax.random.uniform(ks[4], (Lyr, P), minval=0.01, maxval=0.1)
+        kp = L.kv_quantize(kp, k_scale[..., None, None])
+        vp = L.kv_quantize(vp, v_scale[..., None, None])
+    pt = jax.random.randint(ks[5], (B, mp), 0, P)
+    lengths = jnp.array([1, ps + 1, mp * ps], jnp.int32)
+    k_view = kp.reshape(Lyr * P, ps, KV, hd)
+    v_view = vp.reshape(Lyr * P, ps, KV, hd)
+    for layer in range(Lyr):
+        view_scales = layer_scales = {}
+        if quantized:
+            view_scales = dict(k_scale=k_scale.reshape(-1),
+                               v_scale=v_scale.reshape(-1))
+            layer_scales = dict(k_scale=k_scale[layer],
+                                v_scale=v_scale[layer])
+        out = paged_attention_pallas(q, k_view, v_view, pt + layer * P,
+                                     lengths, interpret=True, **view_scales)
+        expect = ref.paged_attention_ref(
+            q, kp[layer].reshape(P, ps, KV, hd),
+            vp[layer].reshape(P, ps, KV, hd), pt, lengths, **layer_scales)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                                   rtol=2e-5, atol=2e-5)
+        # the serving layer hands the kernel exactly this view
+        pool = {"q": kp, "scale": k_scale} if quantized else kp
+        view, table, scale = L.paged_kernel_view(pool, layer, pt, KV)
+        np.testing.assert_array_equal(np.asarray(view), np.asarray(k_view))
+        np.testing.assert_array_equal(np.asarray(table),
+                                      np.asarray(pt + layer * P))
+        assert (scale is None) == (not quantized)
+
+
 def test_paged_attention_rejects_half_specified_scales():
     B, H, KV, hd, P, ps, mp = 1, 2, 1, 8, 4, 2, 2
     q = jnp.zeros((B, H, hd))
@@ -321,10 +375,10 @@ def test_kv_store_rows_round_trip_error_bound():
     rows = jax.random.normal(jax.random.fold_in(KEY, 60), (B, KV, hd)) * 3.0
     page_idx = jnp.array([1, 2, 3, 4, 5], jnp.int32)
     offset = jnp.array([0, 1, 2, 3, 0], jnp.int32)
-    pool = L.paged_store_rows(pool, page_idx, offset, rows)
-    got = L.kv_dequantize(pool["q"], pool["scale"][:, None, None, None])
+    pool = L.paged_store_rows(pool, 0, page_idx, offset, rows)
+    got, scale = _dequantized_layer0(pool, KV)
     err = jnp.abs(got[page_idx, offset] - rows)
-    bound = 0.5 * pool["scale"][page_idx][:, None, None] + 1e-6
+    bound = 0.5 * scale[page_idx][:, None, None] + 1e-6
     assert bool(jnp.all(err <= bound)), (
         f"round-trip error {float(err.max()):.4f} exceeds half a "
         f"quantization step {float(bound.max()):.4f}")
@@ -339,10 +393,10 @@ def test_kv_store_rows_duplicate_pages_keep_every_row():
     rows = jax.random.normal(jax.random.fold_in(KEY, 61), (4, KV, hd))
     page_idx = jnp.array([2, 2, 2, 2], jnp.int32)     # one page, 4 rows
     offset = jnp.arange(4, dtype=jnp.int32)
-    pool = L.paged_store_rows(pool, page_idx, offset, rows)
-    got = L.kv_dequantize(pool["q"], pool["scale"][:, None, None, None])
+    pool = L.paged_store_rows(pool, 0, page_idx, offset, rows)
+    got, scale = _dequantized_layer0(pool, KV)
     err = jnp.abs(got[2, :4] - rows)
-    bound = 0.5 * pool["scale"][2] + 1e-6
+    bound = 0.5 * scale[2] + 1e-6
     assert bool(jnp.all(err <= bound)), (
         f"duplicate-page scatter dropped rows: max err {float(err.max()):.4f}")
 
@@ -354,23 +408,23 @@ def test_kv_scratch_page_scale_never_adapts():
     P, ps, KV, hd = 4, 4, 2, 8
     pool = _fresh_int8_pool(P, ps, KV, hd)
     huge = jnp.full((2, KV, hd), 1e4, jnp.float32)
-    pool = L.paged_store_rows(pool, jnp.array([0, 1], jnp.int32),
+    pool = L.paged_store_rows(pool, 0, jnp.array([0, 1], jnp.int32),
                               jnp.array([0, 0], jnp.int32), huge)
-    assert float(pool["scale"][0]) == L.KV_SCRATCH_SCALE
-    assert float(pool["scale"][1]) == pytest.approx(1e4 / 127.0)
+    assert float(pool["scale"][0, 0]) == L.KV_SCRATCH_SCALE
+    assert float(pool["scale"][0, 1]) == pytest.approx(1e4 / 127.0)
     # growing a page's scale keeps previously-written rows within THEIR
     # original bound (rescale is monotone, error only shrinks relatively)
     small = jnp.full((1, KV, hd), 0.5, jnp.float32)
-    pool = L.paged_store_rows(pool, jnp.array([2], jnp.int32),
+    pool = L.paged_store_rows(pool, 0, jnp.array([2], jnp.int32),
                               jnp.array([0], jnp.int32), small)
-    s_before = float(pool["scale"][2])
+    s_before = float(pool["scale"][0, 2])
     big = jnp.full((1, KV, hd), 40.0, jnp.float32)
-    pool = L.paged_store_rows(pool, jnp.array([2], jnp.int32),
+    pool = L.paged_store_rows(pool, 0, jnp.array([2], jnp.int32),
                               jnp.array([1], jnp.int32), big)
-    assert float(pool["scale"][2]) >= s_before
-    got = L.kv_dequantize(pool["q"], pool["scale"][:, None, None, None])
+    assert float(pool["scale"][0, 2]) >= s_before
+    got, scale = _dequantized_layer0(pool, KV)
     assert float(jnp.abs(got[2, 0] - 0.5).max()) <= \
-        0.5 * float(pool["scale"][2]) + 1e-6
+        0.5 * float(scale[2]) + 1e-6
 
 
 # ---------------------------------------------------------------------------

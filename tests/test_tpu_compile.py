@@ -1,11 +1,14 @@
-"""Compile the main path's Pallas kernels for a described TPU v5e.
+"""Compile the main path's Pallas kernels and serving programs for a
+described TPU v5e.
 
 Nothing runs: each test lowers and compiles a kernel at real widths
 (Qwen3-4B: 32 query heads, 8 kv heads, head_dim 128, d_ff 9728) for the
 first chip of a ``v5e:2x2`` topology, which raises what the chip's
 compiler would raise — block shapes off the (sublane, lane) tiling, too
-much VMEM — and which interpret mode on the CPU cannot see.  The topology
-is described inside a fixture only, so each test worker that imports this
+much VMEM — and which interpret mode on the CPU cannot see.  The serving
+programs compile at Qwen1.5-4B widths and are held to the chip's memory
+plan: they update the donated KV pools in place.  The topology is
+described inside a fixture only, so each test worker that imports this
 file collects the same tests and only the worker that runs them loads the
 TPU compiler.
 """
@@ -17,8 +20,13 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.base import ModelConfig
+from repro.kernels import paged_attention
 from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.wash_shuffle import bucketed_shuffle_pallas
+from repro.models import layers as L
+from repro.models import transformer as M
+from repro.serving import batching
 
 H, KV, HD = 32, 8, 128
 D_MODEL, D_FF = 2560, 9728
@@ -83,3 +91,84 @@ def test_bucketed_shuffle_compiles_for_v5e(one_chip):
     # in, out and the per-coordinate shift map: a byte per coordinate,
     # not an (N, D) int32 permutation
     assert mem.temp_size_in_bytes < 2 * d
+
+
+# Qwen1.5-4B widths (MHA, 20 heads of 128, qkv bias), 4 layers.  The
+# vocabulary is cut to 4,096 rows: in ensemble mode the member-vmapped
+# embedding gather relays the table out, a vocabulary-sized copy that is
+# not the pool's and that this test does not measure.
+SERVE_CFG = ModelConfig(
+    name="qwen1.5-4b-widths", family="dense", num_layers=4, d_model=2560,
+    num_heads=20, num_kv_heads=20, head_dim=128, d_ff=6912,
+    vocab_size=4096, qkv_bias=True, tie_embeddings=False, dtype="bfloat16")
+# 32 slots of up to 1,024 tokens over 2,049 pages of 16: one layer's K
+# pool (168 MB in bf16) dwarfs a step's activations
+SERVE_SLOTS, SERVE_PAGES, SERVE_MAX_PAGES, CHUNK = 32, 2049, 64, 512
+
+
+@pytest.fixture
+def chip_programs(monkeypatch):
+    """Build the serving programs as they are on the chip: the kernel
+    compiled (not interpreted) and the pools donated."""
+    monkeypatch.setattr(paged_attention, "resolve_interpret", lambda _: False)
+    monkeypatch.setattr(batching, "donate_argnums", lambda argnums: argnums)
+    batching.clear_executable_cache()
+    yield
+    batching.clear_executable_cache()
+
+
+@pytest.mark.parametrize("program", ["decode", "prompt_chunk"])
+@pytest.mark.parametrize("mode,kv_dtype", [
+    ("soup", None), ("soup", "int8"), ("ensemble", None)])
+def test_serving_programs_update_the_pools_in_place_on_v5e(
+        one_chip, chip_programs, program, mode, kv_dtype):
+    """The decode step and a 512-token prompt chunk need less scratch
+    memory than one layer's K pool: they scatter their rows into the
+    donated pools and read the kernel's view of them, with no per-layer
+    slice, relayout or write-back of a pool."""
+    cfg, B, mp, P, ps = (SERVE_CFG, SERVE_SLOTS, SERVE_MAX_PAGES,
+                         SERVE_PAGES, 16)
+    ensemble = mode == "ensemble"
+    members = 2 if ensemble else 1
+
+    def on_chip(tree, lead=()):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(lead + x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    def arg(shape, dtype=jnp.int32):
+        return on_chip(jax.ShapeDtypeStruct(shape, dtype))
+
+    # ensemble programs hold every member's weights and pools
+    lead = (members,) if ensemble else ()
+    params = on_chip(jax.eval_shape(
+        lambda: M.init_params(jax.random.key(0), cfg)), lead)
+    pools = on_chip(jax.eval_shape(
+        lambda: L.paged_pools_init(cfg, P, ps, cfg.num_layers,
+                                   kv_dtype=kv_dtype)), lead)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    temperature = arg((), jnp.float32)
+    if program == "decode":
+        fn = batching._programs(cfg, ensemble, (B, mp, ps, P), True, True,
+                                kv_dtype)
+        lowered = fn.lower(params, pools["k"], pools["v"], arg((B,)),
+                           arg((B,)), arg((B,)), arg((B,)),
+                           arg((B,), jnp.bool_), arg((B, mp)),
+                           arg((B,), key.dtype), temperature)
+    else:
+        fn = batching._chunk_program(cfg, ensemble, CHUNK, mp, ps, P, True,
+                                     kv_dtype)
+        lowered = fn.lower(params, None, pools["k"], pools["v"], None, None,
+                           arg((CHUNK,)), arg(()), arg((mp,)),
+                           arg((), key.dtype), temperature)
+    compiled = lowered.compile()
+    if program == "decode":  # the attend is the compiled Pallas kernel
+        assert "tpu_custom_call" in compiled.as_text()
+    k_pool = jax.tree_util.tree_leaves(pools["k"])[0]
+    layer_k_bytes = k_pool.size * k_pool.dtype.itemsize // (
+        members * cfg.num_layers)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer_k_bytes, (
+        f"{program} needs {mem.temp_size_in_bytes / 1e9:.2f} GB of "
+        f"temporaries beside one layer's K pool of {layer_k_bytes / 1e9:.2f}"
+        " GB: a layer's pool is copied")
