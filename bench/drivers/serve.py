@@ -1,11 +1,13 @@
 """Serving traffic: the soup of a seeded population behind the
 continuous-batching runtime, driven by the program's request driver.
 
-Set-up makes a two-member population from the seed, averages it with the
-program's ``averaged_params``, builds the server and its driver, and
-serves one short request for every prompt-chunk length the mix will use
-(and so every decode shape): each program the window runs is built, or
-loaded from the persistent cache, before the window opens.
+Set-up makes a two-member population from the seed and averages it with
+the program's ``averaged_params`` one leaf at a time (the device holds the
+soup and one leaf of each member, never a whole member), builds the
+server and its driver, and serves one short request for every
+prompt-chunk length the mix will use (and so every decode shape): each
+program the window runs is built, or loaded from the persistent cache,
+before the window opens.
 
 The window is an open loop: each request is submitted when it is due and
 timed from that due time.  Between submissions the loop ticks the driver:
@@ -13,11 +15,12 @@ one prompt chunk and one decode step a tick, each decode step ending in
 the sampled tokens' readback.  After the window the server is freed, and
 the reference reruns each of a sample of finished requests, drawn from the
 seed with the longest among them, over its prompt and served tokens.
+The architecture (weights, reference, program configuration, counts) is
+the module the cell's configuration names.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 import gc
 import time
@@ -27,9 +30,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import flops, generate, reference as R
+from bench import flops, generate
 from bench.harness import log, median, percentile
-from bench.system import check_layout, program_config
+from bench.reference import member_keys
+from bench.system import check_layout
 
 #: served tokens the check reads at least (the longest request included)
 SAMPLE_TOKENS = 300
@@ -37,26 +41,29 @@ SAMPLE_TOKENS = 300
 REF_BLOCK = 1024
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _members(keys, a, dtype):
-    return jax.vmap(lambda k: R.init_weights(k, a, dtype))(keys)
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _member_leaf(keys, arch, a, j):
+    """Leaf ``j`` (flatten order) of each member's weights, stacked: the
+    members' other leaves are not computed."""
+    return jax.vmap(lambda k: jax.tree_util.tree_leaves(
+        arch.init_weights(k, a))[j])(keys)
 
 
 @jax.jit
-def _soup(members):
-    return jax.tree_util.tree_map(
-        lambda x: jnp.mean(x.astype(jnp.float32), axis=0), members)
+def _mean_f32(x):
+    return jnp.mean(x.astype(jnp.float32), axis=0)
 
 
-@functools.partial(jax.jit, static_argnames=("a",))
-def _ref_logits(weights, tokens, rows, a):
-    return R.logits_at(weights, tokens, rows, a)
+@functools.partial(jax.jit, static_argnames=("arch", "a"))
+def _ref_logits(weights, tokens, rows, arch, a):
+    return arch.logits_at(weights, tokens, rows, a)
 
 
 class Driver:
     def __init__(self, cell, seed: int, spans):
         self.cell, self.seed, self.spans = cell, seed, spans
-        self.arch = R.Arch.from_config(cell.config)
+        self.A = cell.architecture
+        self.arch = self.A.Arch.from_config(cell.config)
         self.traffic = cell.traffic
         w = cell.workload
         self.page_size, self.slots = w["page_size"], w["max_slots"]
@@ -67,10 +74,21 @@ class Driver:
 
     # -- set-up ----------------------------------------------------------
 
-    def _weights(self, dtype=None):
-        """The population's members, stacked, made in one jitted call."""
-        keys = R.member_keys(self.seed, self.population)
-        return _members(jnp.stack(keys), self.arch, dtype)
+    def _averaged(self, average):
+        """The population's weights averaged one leaf at a time: each
+        leaf of every member, stacked, made in one jitted call and passed
+        to ``average``; the device holds the averaged leaves made so far
+        and one leaf of each member."""
+        keys = jnp.stack(member_keys(self.seed, self.population))
+        shape = jax.eval_shape(lambda k: self.A.init_weights(k, self.arch),
+                               keys[0])
+        leaves = []
+        for j in range(len(jax.tree_util.tree_leaves(shape))):
+            stacked = _member_leaf(keys, self.A, self.arch, j)
+            leaves.append(average(stacked))
+            del stacked
+        return jax.tree_util.tree_unflatten(
+            jax.tree_util.tree_structure(shape), leaves)
 
     def max_pages(self) -> int:
         longest = max(p + o for p, o in generate.size_set(self.traffic))
@@ -81,12 +99,10 @@ class Driver:
         from repro.serving.driver import RequestDriver
 
         a = self.arch
-        self.cfg = cfg = program_config(a, self.cell.entry["config"])
+        self.cfg = cfg = self.A.program_config(a, self.cell.entry["config"])
         check_layout(jax.eval_shape(
-            lambda k: R.init_weights(k, a), jax.random.key(0)), cfg)
-        members = self._weights()
-        soup = averaged_params(members)
-        del members
+            lambda k: self.A.init_weights(k, a), jax.random.key(0)), cfg)
+        soup = self._averaged(averaged_params)
         mp = self.max_pages()
         self.server = batching.ContinuousServer(
             soup, cfg, mode="soup", page_size=self.page_size,
@@ -150,7 +166,8 @@ class Driver:
     def _instrument(self) -> None:
         """The benchmark's spans around the driver's calls into the
         runtime, and the work each call does, counted from its inputs."""
-        srv, spans, a, ps = self.server, self.spans, self.arch, self.page_size
+        srv, spans, ps = self.server, self.spans, self.page_size
+        A, a = self.A, self.arch
 
         step, prefill, admit = srv.step, srv._prefill_step, srv._begin_admit
 
@@ -163,18 +180,17 @@ class Driver:
             with spans("bench.decode_step"):
                 out = step()
             self.step_s.append(time.perf_counter() - t0)
-            self.decode_flops += flops.decode_step(a, lengths)
-            self.kernel_flops += a.num_layers * flops.paged_attention_flops(
-                a, lengths)
-            self.kernel_bytes += a.num_layers * flops.paged_attention_bytes(
-                a, dmas, len(srv._slots), ps)
+            self.decode_flops += A.decode_step(a, lengths)
+            self.kernel_flops += A.decode_kernel_flops(a, lengths)
+            self.kernel_bytes += A.decode_kernel_bytes(a, dmas,
+                                                       len(srv._slots), ps)
             self.decode_tokens += len(lengths)
             return out
 
         def timed_prefill(pf, max_tokens=None):
             T = pf.remaining if max_tokens is None else min(max_tokens,
                                                             pf.remaining)
-            self.prefill_flops += flops.prefill_chunk(a, pf.pos, T)
+            self.prefill_flops += A.prefill_chunk(a, pf.pos, T)
             self.prefill_tokens += T
             with spans("bench.prefill_chunk"):
                 return prefill(pf, max_tokens)
@@ -293,14 +309,13 @@ class Driver:
     def gaps(self, uids: List[int], control: bool = False) -> np.ndarray:
         """For each served token of the sampled requests, the gap between
         the reference's best logit and its logit of the served token
-        (``control``: of the token that int8 matmuls put first at that
-        position)."""
-        a = self.arch
+        (``control``: of the token that the architecture's control puts
+        first at that position)."""
+        A, a = self.A, self.arch
         # the members as the program got them (bf16), averaged in float32
-        soup = _soup(self._weights())
-        fwd = functools.partial(_ref_logits, a=a)
-        ctl = functools.partial(_ref_logits,
-                                a=dataclasses.replace(a, int8_matmuls=True))
+        soup = self._averaged(_mean_f32)
+        fwd = functools.partial(_ref_logits, arch=A, a=a)
+        ctl = functools.partial(_ref_logits, arch=A, a=A.control(a))
         out = []
         n_rows = self.traffic["output"]["max"]
         for u in uids:

@@ -13,12 +13,13 @@ step, each leaf's first gradient as the optimizer gets it (SGD's momentum
 after one step is the gradient plus weight decay: its norm, and a fixed
 sample of its elements kept on the host since set-up), and each leaf's
 change after three steps, weights held in the configured dtype between
-steps as the program holds them.
+steps as the program holds them.  The architecture (weights, reference,
+program configuration, counts) is the module the cell's configuration
+names; it has to provide ``loss``.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 import gc
 import time
@@ -28,9 +29,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import flops, reference as R
-from bench.harness import log
-from bench.system import check_layout, program_config
+from bench import flops
+from bench.harness import BenchError, log
+from bench.reference import member_keys, to_f32, wash_apply, wash_plan
+from bench.system import check_layout
 
 #: steps the check follows
 CHECK_STEPS = 3
@@ -90,15 +92,15 @@ def _zeros_f32(tree):
         lambda x: jnp.zeros(x.shape, jnp.float32), tree)
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4), donate_argnums=(0, 1))
-def _ref_step(theta, mu, tokens, a, hyper):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5), donate_argnums=(0, 1))
+def _ref_step(theta, mu, tokens, arch, a, hyper):
     """One SGD-with-momentum step of one member.  The weights come in and
     go out in the configured dtype, as the program stores them: an output
     in that dtype makes the rounding certain, where a round trip through
     it inside the program may be simplified away."""
     lr, momentum, wd = hyper
-    th32 = R.to_f32(theta)
-    lval, g = jax.value_and_grad(lambda th: R.loss(th, tokens, a))(th32)
+    th32 = to_f32(theta)
+    lval, g = jax.value_and_grad(lambda th: arch.loss(th, tokens, a))(th32)
     mu = jax.tree_util.tree_map(lambda m, g_, th: momentum * m + g_ + wd * th,
                                 mu, g, th32)
     theta = jax.tree_util.tree_map(
@@ -108,8 +110,8 @@ def _ref_step(theta, mu, tokens, a, hyper):
 
 @functools.partial(jax.jit, static_argnums=(2, 3), donate_argnums=(1,))
 def _ref_shuffle(key, members, a, base_p):
-    return R.wash_apply(members, R.wash_plan(key, members[0], a.num_layers,
-                                             len(members), base_p))
+    return wash_apply(members, wash_plan(key, members[0], a.num_layers,
+                                         len(members), base_p))
 
 
 @jax.jit
@@ -133,7 +135,11 @@ def _member_delta(theta, theta0):
 class Driver:
     def __init__(self, cell, seed: int, spans):
         self.cell, self.seed, self.spans = cell, seed, spans
-        self.arch = R.Arch.from_config(cell.config)
+        self.A = cell.architecture
+        if not hasattr(self.A, "loss"):
+            raise BenchError(f"architecture {self.A.__name__} has no loss: "
+                             f"it cannot train")
+        self.arch = self.A.Arch.from_config(cell.config)
         w = cell.workload
         self.n = w["population"]
         self.batch, self.seq = cell.traffic["batch"], cell.traffic["seq_len"]
@@ -154,10 +160,10 @@ class Driver:
         from repro.optim import make_optimizer
         from repro.train import engine as T
 
-        a, n = self.arch, self.n
-        cfg = program_config(a, self.cell.entry["config"])
-        member = jax.eval_shape(lambda k: R.init_weights(k, a),
-                                jax.random.key(0))
+        A, a, n = self.A, self.arch, self.n
+        cfg = A.program_config(a, self.cell.entry["config"])
+        member = self.member = jax.eval_shape(lambda k: A.init_weights(k, a),
+                                              jax.random.key(0))
         check_layout(member, cfg)
         self.leaf_names = [jax.tree_util.keystr(p) for p, _ in
                            jax.tree_util.tree_flatten_with_path(member)[0]]
@@ -185,7 +191,7 @@ class Driver:
         # (the state, the change after three steps, the reference), so
         # that they are the same bits: two programs that draw them can
         # round an element differently
-        self.init_theta = jax.jit(lambda k: R.init_weights(k, a))
+        self.init_theta = jax.jit(lambda k: A.init_weights(k, a))
         self.init_state = jax.jit(
             init_state, out_shardings=(shard(pspec), shard(ospec)))
         loss = lambda p, b: M.loss_fn(p, cfg, b)[0]  # noqa: E731
@@ -221,7 +227,7 @@ class Driver:
         steps, one a call, then one whole chunk, through the window's own
         call; the programs stay as they were built."""
         self.seed = seed
-        key = self.key = R.member_keys(seed, 1)[0]
+        key = self.key = member_keys(seed, 1)[0]
         self.data_key = jax.random.fold_in(key, 1)
         self.mix_key = jax.random.fold_in(key, 2)
         self.pop, self.opt = self.init_state(self.init_theta(key))
@@ -279,10 +285,10 @@ class Driver:
         a = self.arch
         self.counters = {
             "window_s": window_s, "steps": steps, "tokens": tokens,
-            "flops": steps * self.n * flops.train_step(
+            "flops": steps * self.n * self.A.train_step(
                 a, self.batch, self.seq),
             "shuffle_bytes": steps * flops.shuffle_step_bytes(
-                a, self.n, self.base_p),
+                self.member, a.num_layers, self.n, self.base_p),
             "last_loss": losses[-1],
         }
         log(f"train: {steps} steps in {window_s:.3f} s, last loss "
@@ -300,11 +306,10 @@ class Driver:
 
     def reference(self, control: bool = False) -> Dict:
         """The reference's readings of the first CHECK_STEPS steps;
-        ``control`` runs every dense matmul on int8 weights and
-        activations."""
-        a, n = self.arch, self.n
+        ``control``: the architecture's control in its place."""
+        A, a, n = self.A, self.arch, self.n
         if control:
-            a = dataclasses.replace(a, int8_matmuls=True)
+            a = A.control(a)
         hyper = (self.lr, self.momentum, self.wd)
         theta0 = self.init_theta(self.key)
         members = [_copy(theta0) for _ in range(n)]
@@ -312,7 +317,7 @@ class Driver:
         losses, grad_norms, grad_sample = [], None, None
         for t in range(CHECK_STEPS):
             toks = self.make_batch(self.data_key, t)["tokens"][0]
-            out = [_ref_step(members[m], mus[m], toks[m], a, hyper)
+            out = [_ref_step(members[m], mus[m], toks[m], A, a, hyper)
                    for m in range(n)]
             losses.append(float(np.mean([float(o[0]) for o in out])))
             members = [o[1] for o in out]
@@ -352,7 +357,7 @@ class Driver:
         }
 
     def check(self, control: bool = False) -> List:
-        """The numbers the cell's ``limits`` name (``control``: the int8
+        """The numbers the cell's ``limits`` name (``control``: the
         control's, in the program's place)."""
         ref = self.reference()
         got = self.compare(self.reference(control=True) if control

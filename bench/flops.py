@@ -3,70 +3,25 @@
 Model FLOPs count each multiply-add as 2 and only the work the algorithm
 needs: causal attention over the positions a query may see, nothing that
 is recomputed, masked or padded.  Bytes count what a kernel must move
-between HBM and the chip at least once.
+between HBM and the chip at least once.  What depends on an
+architecture's sizes is counted by its module,
+``bench/architectures/<name>.py``; what is here reads only shapes.
 """
 
 from __future__ import annotations
 
 from typing import Iterable
 
-from bench.reference import Arch
+import jax
+import numpy as np
 
-
-def block_matmul_params(a: Arch) -> int:
-    """Matmul weights of one decoder block (q, k, v, o and the SwiGLU)."""
-    D, hd = a.d_model, a.head_dim
-    attn = D * (a.num_heads + 2 * a.num_kv_heads) * hd + a.num_heads * hd * D
-    return attn + 3 * D * a.d_ff
-
-
-def head_params(a: Arch) -> int:
-    return a.d_model * a.vocab_size
+from bench.reference import leaf_depths
 
 
 def causal_pairs(start: int, length: int) -> int:
     """(query, key) pairs of ``length`` queries at positions start.. that
     each attend to every position up to their own."""
     return length * start + length * (length + 1) // 2
-
-
-def attention_flops(a: Arch, pairs: int) -> int:
-    """Forward attention of one layer over ``pairs`` (query, key) pairs:
-    scores and the weighted sum of values, 2 * hd each, per head."""
-    return 4 * a.num_heads * a.head_dim * pairs
-
-
-def train_step(a: Arch, batch: int, seq: int) -> int:
-    """Model FLOPs of one member's training step: forward and backward
-    (3x the forward) of every matmul over all tokens, and of causal
-    attention."""
-    tokens = batch * seq
-    matmul = 2 * (a.num_layers * block_matmul_params(a) + head_params(a))
-    attn = a.num_layers * attention_flops(a, causal_pairs(0, seq)) * batch
-    return 3 * (matmul * tokens + attn)
-
-
-def prefill_chunk(a: Arch, start: int, length: int) -> int:
-    """A prompt chunk of ``length`` tokens at position ``start``: every
-    block for each token, causal attention over the context so far, and
-    the head for the chunk's last token only."""
-    matmul = 2 * a.num_layers * block_matmul_params(a) * length
-    attn = a.num_layers * attention_flops(a, causal_pairs(start, length))
-    return matmul + attn + 2 * head_params(a)
-
-
-def decode_step(a: Arch, lengths: Iterable[int]) -> int:
-    """One decode step of the active slots, whose contexts (the new token
-    included) are ``lengths``."""
-    lengths = list(lengths)
-    per_token = 2 * (a.num_layers * block_matmul_params(a) + head_params(a))
-    attn = a.num_layers * sum(attention_flops(a, n) for n in lengths)
-    return per_token * len(lengths) + attn
-
-
-def kv_page_bytes(a: Arch, page_size: int, itemsize: int = 2) -> int:
-    """Bytes of one K or V page of one layer."""
-    return page_size * a.num_kv_heads * a.head_dim * itemsize
 
 
 def paged_attention_dmas(pages_per_slot: Iterable[int], max_pages: int
@@ -87,18 +42,6 @@ def paged_attention_dmas(pages_per_slot: Iterable[int], max_pages: int
     return dmas
 
 
-def paged_attention_bytes(a: Arch, dmas: int, slots: int, page_size: int,
-                          itemsize: int = 2) -> int:
-    """Bytes of one paged-attention call (one layer): ``dmas`` K and V
-    pages, each slot's query read and its output written once."""
-    qo = 2 * slots * a.num_heads * a.head_dim * itemsize
-    return 2 * dmas * kv_page_bytes(a, page_size, itemsize) + qo
-
-
-def paged_attention_flops(a: Arch, lengths: Iterable[int]) -> int:
-    return sum(attention_flops(a, n) for n in lengths)
-
-
 LANES, ROW_TILE, BLOCK_D = 128, 32, 65536
 
 
@@ -111,37 +54,32 @@ def _padded_coords(d: int) -> int:
     return rows * LANES
 
 
-def shuffled_leaves(a: Arch, n: int, base_p: float):
-    """Coordinates of each leaf that a WASH step shuffles: the leaves at
-    a depth whose probability keeps at least one coordinate per member
-    (the embedding and the blocks; the head's depth has probability 0)."""
-    D, hd, F = a.d_model, a.head_dim, a.d_ff
-    L = a.num_layers
-    last = L + 1
-    leaves = [(a.vocab_size * D, [0])]
-    block = [D * a.num_heads * hd, D * a.num_kv_heads * hd,
-             D * a.num_kv_heads * hd, a.num_heads * hd * D,
-             D * F, D * F, F * D, D, D]
-    if a.qkv_bias:
-        block += [a.num_heads * hd, a.num_kv_heads * hd, a.num_kv_heads * hd]
-    if a.qk_norm:
-        block += [hd, hd]
-    leaves += [(d, list(range(1, L + 1))) for d in block]
+def shuffled_leaves(member, num_layers: int, n: int, base_p: float):
+    """Coordinates of each leaf of ``member`` (a tree of arrays or shapes)
+    that a WASH step shuffles: the leaves at a depth whose probability
+    keeps at least one coordinate per member (``reference.wash_plan``'s
+    rule; the head's depth has probability 0)."""
+    last = num_layers + 1
     out = []
-    for d, depths in leaves:
+    for leaf, depth in zip(jax.tree_util.tree_leaves(member),
+                           leaf_depths(member, num_layers)):
+        if isinstance(depth, np.ndarray):     # stacked: one depth a layer
+            d, depths = int(np.prod(leaf.shape[1:])), list(depth)
+        else:
+            d, depths = int(np.prod(leaf.shape)), [depth]
         count = sum(int(round(base_p * (1 - dep / last) * d)) for dep in depths)
         if count // n > 0:
             out.append(d * len(depths))
     return out
 
 
-def shuffle_step_bytes(a: Arch, n: int, base_p: float, itemsize: int = 2
-                       ) -> int:
+def shuffle_step_bytes(member, num_layers: int, n: int, base_p: float,
+                       itemsize: int = 2) -> int:
     """Bytes the bucketed shuffle kernel moves in one WASH step: it reads
     every member's tiles and the int8 shift map and writes every member's
     tiles, for each shuffled leaf."""
     total = 0
-    for d in shuffled_leaves(a, n, base_p):
+    for d in shuffled_leaves(member, num_layers, n, base_p):
         p = _padded_coords(d)
         total += 2 * n * p * itemsize + p
     return int(total)

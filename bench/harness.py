@@ -5,13 +5,17 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import importlib
+import importlib.util
 import json
 import math
 import pathlib
+import re
 import statistics
 import sys
 import time
+import types
 from typing import Any, Dict, List, Optional, Tuple
 
 BENCH = pathlib.Path(__file__).resolve().parent
@@ -38,6 +42,8 @@ class Cell:
     name: str
     entry: Dict
     config: Dict
+    #: the module its configuration names (``bench/architectures``)
+    architecture: types.ModuleType
     traffic: Dict
     workload: Dict
     end_to_end: List[Dict]
@@ -54,8 +60,8 @@ def _applies(metric: Dict, cell: str) -> bool:
 
 def find_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     """The cell ``name`` of ``root/BENCHMARK.json`` and its files:
-    ``bench/workloads/<cell>.json``, the configuration's ``file`` and
-    ``bench/traffic/<traffic>.json``."""
+    ``bench/workloads/<cell>.json``, the configuration's ``file``, the
+    architecture module it names and ``bench/traffic/<traffic>.json``."""
     spec_path = root / "BENCHMARK.json"
     if not spec_path.exists():
         raise BenchError(f"{spec_path} not found")
@@ -66,14 +72,43 @@ def find_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     entry = entries[name]
     configs = {c["name"]: c for c in spec["configs"]}
     bench = root / "bench"
+    config_file = root / configs[entry["config"]]["file"]
+    config = load_json(config_file)
+    if "architecture" not in config:
+        raise BenchError(f"{config_file} names no \"architecture\"")
     return Cell(
-        name=name, entry=entry,
-        config=load_json(root / configs[entry["config"]]["file"]),
+        name=name, entry=entry, config=config,
+        architecture=architecture(config["architecture"], root),
         traffic=load_json(bench / "traffic" / f"{entry['traffic']}.json"),
         workload=load_json(bench / "workloads" / f"{name}.json"),
         end_to_end=[m for m in spec["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in spec["per_layer"] if _applies(m, name)],
     )
+
+
+def architecture(name: str, root: pathlib.Path = ROOT) -> types.ModuleType:
+    """``bench/architectures/<name>.py`` of ``root``, loaded from its file
+    path (the interface is in ``bench/architectures/__init__.py``)."""
+    if not re.fullmatch(r"[A-Za-z_][A-Za-z0-9_]*", str(name)):
+        raise BenchError(f"architecture {name!r} is not a module name")
+    path = root / "bench" / "architectures" / f"{name}.py"
+    if not path.exists():
+        raise BenchError(f"no architecture module {path}")
+    return _load_module(str(path.resolve()), name)
+
+
+@functools.lru_cache(maxsize=None)
+def _load_module(path: str, name: str) -> types.ModuleType:
+    """One module object per file in a process: the drivers' jitted
+    programs take it, and its ``Arch``, as static arguments."""
+    # registered under a name of its own (dataclasses look their module
+    # up there); two checkouts' modules of one name stay apart
+    mod_name = f"bench_architecture_{name}_{abs(hash(path)):x}"
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[mod_name] = mod
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def driver_module(kind: str):

@@ -1,6 +1,6 @@
 """Model FLOPs of the prompt and decode tokens processed in the window
 over window x peak bf16 FLOP/s, in %.  FLOPs from shapes
-(``flops.prefill_chunk``, ``flops.decode_step``)."""
+(the architecture's ``prefill_chunk`` and ``decode_step``)."""
 
 
 def read(ctx):

@@ -1,5 +1,6 @@
 """Model FLOPs of the window's whole training steps over window x chips
-x peak bf16 FLOP/s, in %.  FLOPs from shapes (``flops.train_step``)."""
+x peak bf16 FLOP/s, in %.  FLOPs from shapes (the
+architecture's ``train_step``)."""
 
 
 def read(ctx):

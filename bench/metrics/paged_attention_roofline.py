@@ -1,9 +1,10 @@
 """The paged-attention kernel's share of its roofline, in %: the larger
 of its useful FLOPs (causal attention over each slot's context) over peak
 FLOP/s and the bytes of the pages it reads, plus queries and outputs,
-over peak bytes/s (``flops.paged_attention_bytes``), against its device
-time in the trace.  At these sizes bytes bound it.  The kernel is
-matched by its op's name (``bench.kernels``)."""
+over peak bytes/s (the architecture's ``decode_kernel_flops`` and
+``decode_kernel_bytes``), against its device time in the trace.  At these
+sizes bytes bound it.  The kernel is matched by its op's name
+(``bench.kernels``)."""
 
 from bench import flops
 from bench.kernels import paged_attention as kernel

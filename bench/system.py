@@ -1,30 +1,16 @@
 """The system under test, as the benchmark reaches it: the program's
-``src/`` on the import path, and its model configuration made from a
-configuration file of ``bench/configs``."""
+``src/`` on the import path, and the check that the benchmark's weights
+have the program's layout.  The program's model configuration for a
+cell is its architecture's (``bench/architectures/<name>.py``)."""
 
 from __future__ import annotations
 
 import sys
 
 from bench.harness import ROOT
-from bench.reference import Arch
 
 if str(ROOT / "src") not in sys.path:
     sys.path.insert(0, str(ROOT / "src"))
-
-
-def program_config(a: Arch, name: str):
-    """The program's ``ModelConfig`` for the sizes of ``a``."""
-    from repro.configs.base import ModelConfig
-
-    return ModelConfig(
-        name=name, family="dense", num_layers=a.num_layers,
-        d_model=a.d_model, num_heads=a.num_heads,
-        num_kv_heads=a.num_kv_heads, head_dim=a.head_dim, d_ff=a.d_ff,
-        vocab_size=a.vocab_size, qk_norm=a.qk_norm, qkv_bias=a.qkv_bias,
-        rope_theta=a.rope_theta, norm_eps=a.norm_eps, tie_embeddings=False,
-        dtype=a.dtype,
-    )
 
 
 def check_layout(weights_shape, cfg) -> None:

@@ -1,10 +1,14 @@
-"""A cell, a configuration, a traffic mix and a per-layer metric are
-found by name from files a later change adds, with no existing file
-edited."""
+"""A cell, a configuration, an architecture, a traffic mix and a
+per-layer metric are found by name from files a later change adds, with
+no existing file edited."""
 
 import json
 import pathlib
+import shutil
 import sys
+import time
+
+import pytest
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
@@ -12,6 +16,8 @@ sys.path.insert(0, str(ROOT))
 from bench import harness as H  # noqa: E402
 from bench import run as RUN  # noqa: E402
 from bench.tests import tiny  # noqa: E402
+
+DATA = ROOT / "bench" / "tests" / "data"
 
 
 def test_new_cell_and_metric_are_found_by_name(tmp_path):
@@ -67,3 +73,66 @@ def test_a_family_reader_serves_each_split_name(tmp_path):
     (root / "bench/metrics/idle_share.later_cell.py").write_text(
         "def read(ctx):\n    return 1.0\n")
     assert RUN.load_reader("idle_share.later_cell", root)(ctx) == 1.0
+
+
+def test_an_architecture_comes_in_as_files(tmp_path):
+    """A model unlike the dense one enters as files: ``tiny_moe``
+    (``bench/tests/data/tiny_moe.py``), whose blocks route each token to
+    2 of 4 SwiGLU experts, a configuration that names it and a serve and
+    a train cell.  Both run ``correct`` through ``bench/run.py``'s entry
+    on the CPU; no file that was there changes but ``BENCHMARK.json``,
+    which only gains entries."""
+    root = tiny.write(tmp_path)
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+
+    shutil.copy(DATA / "tiny_moe.py", root / "bench/architectures")
+    (root / "bench/configs/tiny_moe.json").write_text(json.dumps(dict(
+        tiny.TINY_CONFIG, architecture="tiny_moe", n_routed_experts=4,
+        num_experts_per_tok=2, moe_intermediate_size=32)))
+    (root / "bench/workloads/tiny_moe.serve.json").write_text(
+        json.dumps(tiny.SERVE_WORKLOAD))
+    (root / "bench/workloads/tiny_moe.train.json").write_text(
+        json.dumps(tiny.TRAIN_WORKLOAD))
+    new = json.loads(json.dumps(spec))
+    new["configs"].append({"name": "tiny_moe", "source": "test",
+                           "file": "bench/configs/tiny_moe.json",
+                           "reduced": []})
+    new["workloads"] += [
+        {"name": "tiny_moe.serve", "config": "tiny_moe",
+         "traffic": "serve.tiny", "chips": 1},
+        {"name": "tiny_moe.train", "config": "tiny_moe",
+         "traffic": "train.tiny", "chips": 1}]
+    for m in new["end_to_end"]:
+        if m["name"] in ("train_tokens_per_s", "serve_tokens_per_s"):
+            m["workloads"].append(f"tiny_moe.{m['name'].split('_')[0]}")
+    (root / "BENCHMARK.json").write_text(json.dumps(new))
+
+    cell = H.find_cell("tiny_moe.serve", root)
+    assert cell.architecture.__file__ == str(
+        (root / "bench/architectures/tiny_moe.py").resolve())
+    a = cell.architecture.Arch.from_config(cell.config)
+    cfg = cell.architecture.program_config(a, "tiny_moe")
+    assert cfg.moe and cfg.capacity_factor * cfg.top_k == cfg.n_routed_experts
+    for name in ("tiny_moe.serve", "tiny_moe.train"):
+        args = RUN.parse(["--workload", name, "--seed", "3000000021",
+                          "--seconds", "2", "--trace", "0"])
+        result, checks = RUN.run(args, root=root, check_device=False,
+                                 peak=tiny.PEAK, t_start=time.perf_counter())
+        assert result["correct"], (name, checks)
+        assert result["attempted"] > 0
+
+    after = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+    changed = {p for p in before if after.get(p) != before[p]}
+    assert changed == {root / "BENCHMARK.json"}
+    grown = json.loads((root / "BENCHMARK.json").read_text())
+    for key in ("configs", "workloads"):
+        assert grown[key][:len(spec[key])] == spec[key]
+
+
+def test_a_configuration_must_name_its_architecture(tmp_path):
+    config = {k: v for k, v in tiny.TINY_CONFIG.items()
+              if k != "architecture"}
+    root = tiny.write(tmp_path, config=config)
+    with pytest.raises(H.BenchError, match="tiny.json"):
+        H.find_cell("tiny.serve", root)

@@ -11,18 +11,19 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
 from bench import flops  # noqa: E402
+from bench.architectures import dense_gqa as dense  # noqa: E402
 from bench.harness import BenchError  # noqa: E402
 from bench.peaks import peaks  # noqa: E402
-from bench.reference import Arch  # noqa: E402
 
-A = Arch(num_layers=2, d_model=8, num_heads=4, num_kv_heads=2, head_dim=2,
-         d_ff=16, vocab_size=10, qk_norm=True, qkv_bias=True,
-         rope_theta=1e4, norm_eps=1e-6, dtype="bfloat16")
+A = dense.Arch(num_layers=2, d_model=8, num_heads=4, num_kv_heads=2,
+               head_dim=2, d_ff=16, vocab_size=10, qk_norm=True,
+               qkv_bias=True, rope_theta=1e4, norm_eps=1e-6,
+               dtype="bfloat16")
 
 
 def test_block_params_by_hand():
     # q 8x8, k 8x4, v 8x4, o 8x8, three 8x16 SwiGLU matrices
-    assert flops.block_matmul_params(A) == 64 + 32 + 32 + 64 + 3 * 128
+    assert dense.block_matmul_params(A) == 64 + 32 + 32 + 64 + 3 * 128
 
 
 def test_causal_pairs_by_enumeration():
@@ -35,16 +36,16 @@ def test_train_step_by_hand():
     # per token forward: 2 x (2 blocks x 576 + head 80); attention per
     # layer and sequence: 4 x heads 4 x hd 2 x pairs (3 tokens: 6 pairs)
     fwd = 2 * (2 * 576 + 80) * 2 * 3 + 2 * 4 * 4 * 2 * 6 * 2
-    assert flops.train_step(A, batch=2, seq=3) == 3 * fwd
+    assert dense.train_step(A, batch=2, seq=3) == 3 * fwd
 
 
 def test_prefill_and_decode_by_hand():
     # chunk of 2 tokens at position 3: pairs 4 + 5 = 9
     want = 2 * 2 * 576 * 2 + 2 * 4 * 4 * 2 * 9 + 2 * 80
-    assert flops.prefill_chunk(A, 3, 2) == want
+    assert dense.prefill_chunk(A, 3, 2) == want
     # two slots with contexts 3 and 7
     want = 2 * (2 * 576 + 80) * 2 + 2 * 4 * 4 * 2 * (3 + 7)
-    assert flops.decode_step(A, [3, 7]) == want
+    assert dense.decode_step(A, [3, 7]) == want
 
 
 def test_paged_dmas_skip_repeated_pages():
@@ -58,7 +59,7 @@ def test_paged_dmas_skip_repeated_pages():
 def test_paged_bytes_by_hand():
     # page: 16 tokens x 2 kv heads x hd 2 x 2 bytes = 128 B; K and V
     qo = 2 * 3 * 4 * 2 * 2
-    assert flops.paged_attention_bytes(A, 5, 3, 16) == 2 * 5 * 128 + qo
+    assert dense.paged_attention_bytes(A, 5, 3, 16) == 2 * 5 * 128 + qo
 
 
 def test_shuffle_bytes_match_the_programs_plans():
@@ -68,12 +69,12 @@ def test_shuffle_bytes_match_the_programs_plans():
     from repro.core.layer_index import infer_layer_ids, total_layers
     from repro.core.shuffle import make_plan
 
-    from bench.reference import init_weights
-
-    a = Arch(num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
-             head_dim=16, d_ff=128, vocab_size=256, qk_norm=True,
-             qkv_bias=True, rope_theta=1e4, norm_eps=1e-6, dtype="float32")
-    member = jax.eval_shape(lambda k: init_weights(k, a), jax.random.key(0))
+    a = dense.Arch(num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
+                   head_dim=16, d_ff=128, vocab_size=256, qk_norm=True,
+                   qkv_bias=True, rope_theta=1e4, norm_eps=1e-6,
+                   dtype="float32")
+    member = jax.eval_shape(lambda k: dense.init_weights(k, a),
+                            jax.random.key(0))
     for p in (0.01, 0.1, 0.3):
         plan = make_plan(jax.random.key(1), member,
                          infer_layer_ids(member, a.num_layers),
@@ -82,7 +83,8 @@ def test_shuffle_bytes_match_the_programs_plans():
             jax.tree_util.tree_leaves(member),
             jax.tree_util.tree_leaves(plan, is_leaf=lambda v: v is None))
             if q is not None)
-        assert sorted(flops.shuffled_leaves(a, 2, p)) == sizes
+        assert sorted(flops.shuffled_leaves(member, a.num_layers, 2,
+                                            p)) == sizes
 
 
 def test_roofline_share_names_its_bound():

@@ -61,6 +61,39 @@ def test_backlog_run_starts_with_every_slot_decoding(root):
     assert set(result["metrics"]) == {"serve_tokens_per_s", "setup_s"}
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_soups_made_leaf_by_leaf_are_the_whole_populations(tmp_path, dtype):
+    """The served soup and the check's float32 soup, made one leaf at a
+    time, are bit for bit the soups of the whole stacked population:
+    ``averaged_params`` of the vmapped members, and their float32 mean."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bench import harness as H
+    from bench.drivers import serve
+    from bench.reference import member_keys
+    from repro.serving import averaged_params
+
+    root = tiny.write(tmp_path, dict(tiny.TINY_CONFIG, torch_dtype=dtype))
+    drv = serve.Driver(H.find_cell("tiny.serve", root), 3000000011,
+                       H.Spans(False))
+    A, a = drv.A, drv.arch
+    members = jax.jit(jax.vmap(lambda k: A.init_weights(k, a)))(
+        jnp.stack(member_keys(drv.seed, drv.population)))
+    whole_f32 = jax.jit(lambda m: jax.tree_util.tree_map(
+        lambda x: jnp.mean(x.astype(jnp.float32), axis=0), m))(members)
+    for got, want in ((drv._averaged(averaged_params),
+                       averaged_params(members)),
+                      (drv._averaged(serve._mean_f32), whole_f32)):
+        assert (jax.tree_util.tree_structure(got)
+                == jax.tree_util.tree_structure(want))
+        for g, w in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            assert np.array_equal(np.asarray(g), np.asarray(w))
+
+
 # -- faults planted in the program: each must make `correct` false -------
 
 

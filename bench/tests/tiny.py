@@ -1,5 +1,6 @@
 """A tiny copy of the benchmark's cells for runs on the CPU: the same
-drivers, references and checks, at widths a test can hold."""
+drivers, architectures, references and checks, at widths a test can
+hold."""
 
 from __future__ import annotations
 
@@ -10,7 +11,7 @@ import shutil
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 
 TINY_CONFIG = {
-    "source": "tiny dense decoder for tests",
+    "source": "tiny dense decoder for tests", "architecture": "dense_gqa",
     "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
     "vocab_size": 256, "rope_theta": 10000.0, "rms_norm_eps": 1e-6,
@@ -49,8 +50,9 @@ def write(root: pathlib.Path, config: dict = None) -> pathlib.Path:
         p.parent.mkdir(parents=True, exist_ok=True)
         p.write_text(json.dumps(obj))
 
-    shutil.copytree(BENCH / "metrics", root / "bench" / "metrics",
-                    dirs_exist_ok=True)
+    for d in ("metrics", "architectures"):
+        shutil.copytree(BENCH / d, root / "bench" / d, dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     put("bench/configs/tiny.json", config or TINY_CONFIG)
     put("bench/traffic/train.tiny.json", TRAIN_TRAFFIC)
     put("bench/traffic/serve.tiny.json", SERVE_TRAFFIC)

@@ -5,12 +5,12 @@
 
 For each seed, in one process: the cell's set-up and a short window, as a
 run makes them; then every number the check can compare for the program
-against the reference, and the same numbers for the control, the
-reference computed with int8 weights and activations in every dense
-matmul, each side with ``correct`` as a run decides it under the cell's
-limits.  ``--fault`` plants a fault in the
-program (training: ``half_batch``, each member's loss over half its
-rows) and reads the program's numbers only.  One JSON line per seed.
+against the reference, and the same numbers for the control (the
+reference at its architecture's ``control``, the nearest lower
+precision), each side with ``correct`` as a run decides it under the
+cell's limits.  ``--fault`` plants a fault in the program (training:
+``half_batch``, each member's loss over half its rows) and reads the
+program's numbers only.  One JSON line per seed.
 """
 
 from __future__ import annotations
