@@ -258,7 +258,7 @@ def _pool_bytes(server) -> int:
     scales are part of the footprint and are counted)."""
     return sum(x.size * x.dtype.itemsize
                for x in jax.tree_util.tree_leaves(
-                   (server._k_pool, server._v_pool)))
+                   server._pools))
 
 
 def run(quick: bool = True):

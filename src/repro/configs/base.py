@@ -7,6 +7,21 @@ from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling (arXiv:2309.00071), as DeepSeek-V2 configures it:
+    frequencies below the ``beta_slow`` rotation count are divided by
+    ``factor``, those above ``beta_fast`` kept, a linear ramp between;
+    ``mscale`` scales cos/sin and ``mscale_all_dim`` the softmax."""
+
+    factor: float = 1.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """One config covers every assigned architecture family.
 
@@ -36,9 +51,17 @@ class ModelConfig:
     qk_rope_dim: int = 64
     v_head_dim: int = 128
 
-    # MoE
+    # MoE.  ``n_routed_experts`` are the experts this model holds:
+    # ``expert_offset`` .. ``expert_offset + n_routed_experts - 1`` of the
+    # router's ``router_experts`` (None = all of them are held here);
+    # assignments to experts held elsewhere add nothing.  The first
+    # ``first_dense_layers`` blocks have a dense SwiGLU of width ``d_ff``.
     moe: bool = False
     n_routed_experts: int = 0
+    router_experts: Optional[int] = None
+    expert_offset: int = 0
+    first_dense_layers: int = 0
+    norm_topk_prob: bool = True  # renormalise the top-k routing weights
     n_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: Optional[int] = None  # per-expert hidden dim (defaults d_ff)
@@ -80,6 +103,7 @@ class ModelConfig:
 
     # misc
     rope_theta: float = 10000.0
+    rope_scaling: Optional[Yarn] = None
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
@@ -95,6 +119,11 @@ class ModelConfig:
         return self.moe_d_ff if self.moe_d_ff is not None else self.d_ff
 
     @property
+    def resolved_router_experts(self) -> int:
+        return (self.router_experts if self.router_experts is not None
+                else self.n_routed_experts)
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -108,7 +137,8 @@ class ModelConfig:
         return self.block_kind in ("rwkv6", "hybrid") or self.window is not None
 
     def reduced(self, **overrides) -> "ModelConfig":
-        """Smoke-test variant: <=2 layers, d_model<=512, <=4 experts."""
+        """Smoke-test variant: <=2 layers (at most one of them dense),
+        d_model<=512, <=4 experts."""
         small = dict(
             num_layers=2,
             d_model=min(self.d_model, 256),
@@ -122,6 +152,10 @@ class ModelConfig:
             qk_rope_dim=16 if self.mla else self.qk_rope_dim,
             v_head_dim=32 if self.mla else self.v_head_dim,
             n_routed_experts=min(self.n_routed_experts, 4),
+            router_experts=(min(self.router_experts, 8)
+                            if self.router_experts else None),
+            expert_offset=0,
+            first_dense_layers=min(self.first_dense_layers, 1),
             n_shared_experts=min(self.n_shared_experts, 1),
             top_k=min(self.top_k, 2),
             moe_d_ff=min(self.resolved_moe_d_ff, 128) if self.moe else None,

@@ -14,6 +14,10 @@ We infer depths from pytree paths: a leaf whose path contains the dict key
 ``blocks`` (or ``enc_blocks``/``dec_blocks``) followed by a sequence index i
 gets depth i+1; paths containing ``embed`` get 0; everything else gets the
 maximum depth.  Models with unusual structure can provide explicit overrides.
+
+A model with leading dense blocks stacks them apart, under
+``dense_blocks`` (``repro.models.transformer.segments``): its ``k`` blocks
+take depths 1..k, and the ``blocks`` stack after them k+1..num_blocks.
 """
 
 from __future__ import annotations
@@ -72,6 +76,14 @@ def _is_scanned_blocks(path, leaf, num_blocks: int) -> bool:
     return False
 
 
+def _dense_count(flat) -> int:
+    """Leading dense blocks of a params tree: the stack ``dense_blocks``."""
+    for path, leaf in flat:
+        if _path_entries(path)[:1] == ["dense_blocks"]:
+            return int(leaf.shape[0])
+    return 0
+
+
 def infer_layer_ids(params: PyTree, num_blocks: int) -> PyTree:
     """Pytree (same structure as params) of depths.
 
@@ -82,9 +94,15 @@ def infer_layer_ids(params: PyTree, num_blocks: int) -> PyTree:
     import numpy as np
 
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    k = _dense_count(flat)
     depths = []
     for path, leaf in flat:
-        if _is_scanned_blocks(path, leaf, num_blocks):
+        top = _path_entries(path)[:1]
+        if k and top == ["dense_blocks"]:
+            depths.append(np.arange(1, k + 1))
+        elif k and top == ["blocks"]:
+            depths.append(np.arange(k + 1, num_blocks + 1))
+        elif _is_scanned_blocks(path, leaf, num_blocks):
             depths.append(np.arange(1, num_blocks + 1))
         else:
             depths.append(leaf_depth(path, num_blocks))

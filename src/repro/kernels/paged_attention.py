@@ -32,6 +32,10 @@ slot's length (GQA groups are ``g = H // KV`` consecutive query rows).
 Pages past a slot's length are skipped with ``pl.when`` (no VPU/MXU work
 is spent on fully-masked pages).
 
+:func:`latent_attention_pallas` is the same schedule over a latent (MLA)
+pool: one ``(page_size, kv_lora_rank + rope)`` page a grid step, read by
+every head of the slot.
+
 ``interpret=None`` auto-detects like ``wash_shuffle``: compiled on TPU,
 interpret mode elsewhere (CPU timings are correctness-only).  The pure-jnp
 oracle is :func:`repro.kernels.ref.paged_attention_ref`, parity-asserted
@@ -205,3 +209,132 @@ def paged_attention_pallas(
         name="paged_attention",
     )(*prefetch, *masks, q, k_pool.reshape(P, R, hd),
       v_pool.reshape(P, R, hd))
+
+
+# ---------------------------------------------------------------------------
+# latent (MLA) decode
+# ---------------------------------------------------------------------------
+
+
+def _latent_kernel(pt_ref, len_ref, q_ref, page_ref, o_ref, acc_ref, m_ref,
+                   l_ref, *, page_size: int, scale: float):
+    # q (2H, 2W): each head's query in the first and, below, the second
+    # half of a row; page (page_size // 2, 2W): positions 2r and 2r + 1 of
+    # the page in row r.  So one matmul scores the even positions (rows
+    # :H) and the odd ones (rows H:), and p @ page sums their rows
+    # (the half of each row the caller keeps).
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    length = len_ref[b]
+    H = q_ref.shape[0] // 2
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(j * page_size < length)
+    def _page():
+        q = q_ref[...] * scale
+        page = page_ref[...].astype(jnp.float32)
+        scores = jax.lax.dot_general(           # q @ page.T: (2H, ps // 2)
+            q, page, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        row = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+        pair = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        pos = j * page_size + 2 * pair + (row >= H).astype(jnp.int32)
+        scores = jnp.where(pos < length, scores, NEG_INF)
+        s_even, s_odd = scores[:H], scores[H:]
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.maximum(
+            jnp.max(s_even, axis=-1, keepdims=True),
+            jnp.max(s_odd, axis=-1, keepdims=True)))
+        alpha = jnp.exp(m_prev - m_new)
+        p_even = jnp.exp(s_even - m_new)
+        p_odd = jnp.exp(s_odd - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(
+            p_even, axis=-1, keepdims=True) + jnp.sum(
+            p_odd, axis=-1, keepdims=True)
+        m_ref[...] = m_new
+        acc_ref[:H] = acc_ref[:H] * alpha + jnp.dot(
+            p_even, page, preferred_element_type=jnp.float32)
+        acc_ref[H:] = acc_ref[H:] * alpha + jnp.dot(
+            p_odd, page, preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _emit():
+        l = jnp.maximum(l_ref[...], 1e-20)
+        o_ref[:H] = acc_ref[:H] / l
+        o_ref[H:] = acc_ref[H:] / l
+
+
+def latent_attention_pallas(
+    q: jax.Array,
+    pool: jax.Array,
+    page_table: jax.Array,
+    lengths: jax.Array,
+    *,
+    scale: float,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """One-token latent attention (MLA, absorbed form) for a batch of
+    serving slots.
+
+      q          : (B, H, W) — each head's absorbed query
+                   ``[q_nope w_uk^T | q_rope]``, W = kv_lora_rank + rope
+      pool       : (P, page_size // 2, 2W) — latent pages, one ``[c |
+                   k_pe]`` row a position and two positions a row
+                   (``models.layers.paged_pools_init``), shared by every
+                   head
+      page_table : (B, max_pages) int32 — pool page id per logical page
+      lengths    : (B,) int32 — valid context tokens per slot (>= 1)
+
+    Returns (B, H, W) float32: the softmax over the slot's rows of
+    ``scale * q . row``, applied to the rows; the caller keeps the latent
+    part and expands it through ``w_uv``.  Grid ``(B, max_pages)`` as in
+    :func:`paged_attention_pallas`: the page table is scalar prefetch,
+    step ``(b, j)`` copies page ``page_table[b, j]`` (all H heads read
+    it), consecutive visits of one page (the scratch page past a slot's
+    length) copy it once, and pages past the length do no work.  The
+    oracle is :func:`repro.kernels.ref.latent_attention_ref`."""
+    B, H, W = q.shape
+    _, half, W2 = pool.shape
+    max_pages = page_table.shape[1]
+    kernel = functools.partial(_latent_kernel, page_size=2 * half,
+                               scale=scale)
+    q = q.astype(jnp.float32)
+    zeros = jnp.zeros_like(q)
+    # (B, 2H, 2W): [q | 0] over [0 | q]
+    q2 = jnp.concatenate([jnp.concatenate([q, zeros], -1),
+                          jnp.concatenate([zeros, q], -1)], 1)
+
+    def _q_map(b, j, pt, lens):
+        return (b, 0, 0)
+
+    def _page_map(b, j, pt, lens):
+        return (pt[b, j], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, max_pages),
+        in_specs=[pl.BlockSpec((None, 2 * H, W2), _q_map),
+                  pl.BlockSpec((None, half, W2), _page_map)],
+        out_specs=pl.BlockSpec((None, 2 * H, W2), _q_map),
+        scratch_shapes=[
+            pltpu.VMEM((2 * H, W2), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, 2 * H, W2), jnp.float32),
+        interpret=resolve_interpret(interpret),
+        name="latent_attention",
+    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), q2, pool)
+    # even positions' sums in the first half of rows :H, odd in the
+    # second half of rows H:
+    return out[:, :H, :W] + out[:, H:, W:]

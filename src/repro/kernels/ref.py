@@ -69,6 +69,24 @@ def paged_attention_ref(q, k_pool, v_pool, page_table, lengths,
     return out.reshape(B, H, hd).astype(q.dtype)
 
 
+def latent_attention_ref(q, pool, page_table, lengths, *, scale: float
+                         ) -> jax.Array:
+    """Gather-then-attend oracle for the latent (MLA) decode kernel.
+
+    q: (B, H, W) absorbed queries (``[q_nope w_uk^T | q_rope]``); pool:
+    (P, page_size // 2, 2W) latent pages, two ``[c | k_pe]`` rows a row
+    (``models.layers.paged_pools_init``); page_table: (B, max_pages)
+    int32; lengths: (B,) int32 -> (B, H, W) float32, the softmax over
+    each slot's first ``lengths`` rows of ``scale * q . row`` applied to
+    the rows (the caller keeps the latent part)."""
+    B, _, W = q.shape
+    rows = pool[page_table].reshape(B, -1, W).astype(jnp.float32)
+    scores = jnp.einsum("bhd,bsd->bhs", q.astype(jnp.float32), rows) * scale
+    valid = jnp.arange(rows.shape[1]) < lengths[:, None]
+    scores = jnp.where(valid[:, None], scores, NEG_INF)
+    return jnp.einsum("bhs,bsd->bhd", jax.nn.softmax(scores, axis=-1), rows)
+
+
 def rwkv6_scan_ref(r, k, v, w, u) -> jax.Array:
     """r/k/v/w: (B,T,H,hd); u: (H,hd) -> y (B,T,H,hd)."""
     B, T, H, hd = r.shape

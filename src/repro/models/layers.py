@@ -7,12 +7,13 @@ shuffle logic trivial).  Compute-sensitive reductions run in float32.
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, Yarn
 
 NEG_INF = -1e30
 
@@ -55,20 +56,58 @@ def l2norm(x, eps: float = 1e-6):
 # ---------------------------------------------------------------------------
 
 
-def rope_freqs(head_dim: int, theta: float):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_freqs(head_dim: int, theta: float, scaling: Optional[Yarn] = None):
+    """Inverse frequencies of the ``head_dim // 2`` rotated pairs; under
+    YaRN ``scaling`` those of the pairs that turn fewer than ``beta_slow``
+    times over the original context are divided by ``factor``, those that
+    turn more than ``beta_fast`` times kept, with a linear ramp between."""
     half = head_dim // 2
-    return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    inv = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if scaling is None or scaling.factor <= 1:
+        return inv
+
+    def pair(rotations):  # the pair index that turns ``rotations`` times
+        return (head_dim * math.log(scaling.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair(scaling.beta_fast)), 0)
+    high = min(math.ceil(pair(scaling.beta_slow)), head_dim - 1)
+    high = high + 0.001 if high == low else high
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return inv / scaling.factor * ramp + inv * (1.0 - ramp)
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., T, H, hd); positions: (T,) or (..., T) absolute positions."""
+def apply_rope(x, positions, theta: float, scaling: Optional[Yarn] = None,
+               interleaved: bool = False):
+    """x: (..., T, H, hd); positions: (T,) or (..., T) absolute positions.
+
+    Rotates pairs ``(i, i + hd/2)`` (rotate-half), or with ``interleaved``
+    the pairs ``(2i, 2i + 1)``, returned de-interleaved (evens' results,
+    then odds'), as DeepSeek-V2 does.  YaRN ``scaling`` changes the
+    frequencies and scales cos and sin by ``yarn_mscale(factor, mscale) /
+    yarn_mscale(factor, mscale_all_dim)``."""
     hd = x.shape[-1]
-    inv = rope_freqs(hd, theta)  # (hd/2,)
+    inv = rope_freqs(hd, theta, scaling)  # (hd/2,)
     pos = positions.astype(jnp.float32)
     ang = pos[..., :, None] * inv[None, :]  # (..., T, hd/2)
     cos = jnp.cos(ang)[..., :, None, :]  # (..., T, 1, hd/2)
     sin = jnp.sin(ang)[..., :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    if scaling is not None and scaling.factor > 1:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        cos, sin = cos * m, sin * m
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    else:
+        x1, x2 = jnp.split(xf, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
 
@@ -146,8 +185,8 @@ def _qkv(p, cfg: ModelConfig, x, positions):
         q = rmsnorm(p["q_norm"], q, cfg.norm_eps)
         k = rmsnorm(p["k_norm"], k, cfg.norm_eps)
     if cfg.pos_kind == "rope":
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     return q, k, v
 
 
@@ -379,15 +418,26 @@ def paged_pools_init(cfg: ModelConfig, num_pages: int, page_size: int,
                      num_layers: int, kv_dtype: str = None):
     """Block-pool KV cache: ``num_pages`` shared fixed-size pages per layer.
 
-    Layout ``(num_layers, num_pages, page_size * KV, hd)``: row
-    ``offset * KV + h`` of a page holds kv head ``h`` at in-page position
-    ``offset``.  That is the ``(page_size * KV, hd)`` tile the paged
-    kernel reads, so the pool needs no relayout on its way in, and
-    ``pool.reshape(L * P, page_size, KV, hd)`` is a free view of every
-    layer's pages at once (:func:`paged_kernel_view`).  The per-slot view
-    is a **page table** of pool indices, not a contiguous slice, so slots
-    with different context lengths share one allocation and common prompt
-    prefixes can share pages (``repro.serving.batching`` owns the
+    The pools are one pytree, which the serving programs thread whole:
+    ``{"k", "v"}`` for grouped-query attention, ``{"latent"}`` for MLA.
+
+    A GQA pool is laid out ``(num_layers, num_pages, page_size * KV,
+    hd)``: row ``offset * KV + h`` of a page holds kv head ``h`` at
+    in-page position ``offset``.  That is the ``(page_size * KV, hd)`` tile
+    the paged kernel reads, so the pool needs no relayout on its way in,
+    and ``pool.reshape(L * P, page_size, KV, hd)`` is a free view of every
+    layer's pages at once (:func:`paged_kernel_view`).  The MLA pool
+    holds, for each position, one row ``[c | k_pe]`` of ``W =
+    kv_lora_rank + qk_rope_dim`` elements (the normalised latent and the
+    roped rope key, read by every head), two positions a row:
+    ``(num_layers, num_pages, page_size // 2, 2 * W)``, whose elements lie
+    in the order of ``(..., page_size, W)``.  At DeepSeek-V2's W = 576
+    (4.5 lanes) the chip's default layout of a W-wide minor dimension puts
+    the page axis minor, and every program would relay the pool out; 2W =
+    1152 is whole lanes (:func:`latent_pool_rows`).  The per-slot
+    view is a **page table** of pool indices, not a contiguous slice, so
+    slots with different context lengths share one allocation and common
+    prompt prefixes can share pages (``repro.serving.batching`` owns the
     table/refcount bookkeeping).  Page 0 is reserved by the runtime as a
     scratch page for inactive slots.
 
@@ -399,7 +449,7 @@ def paged_pools_init(cfg: ModelConfig, num_pages: int, page_size: int,
     layer's pool.
 
     ``kv_dtype=None`` stores pages in the model's param dtype (bitwise
-    path).  ``kv_dtype="int8"`` stores each pool as
+    path).  ``kv_dtype="int8"`` (GQA only) stores each pool as
     ``{"q": int8 (L, P, page_size * KV, hd), "scale": f32 (L, P)}`` — one
     symmetric scale per (layer, page), written by
     :func:`paged_store_rows` / :func:`paged_store_chunk` and applied at
@@ -407,6 +457,17 @@ def paged_pools_init(cfg: ModelConfig, num_pages: int, page_size: int,
     :data:`KV_SCRATCH_SCALE` and never adapts."""
     if kv_dtype not in KV_DTYPES:
         raise ValueError(f"kv_dtype={kv_dtype!r}; expected one of {KV_DTYPES}")
+    if cfg.mla:
+        if kv_dtype is not None:
+            raise NotImplementedError(
+                f"kv_dtype={kv_dtype!r}: the MLA latent pool has no "
+                "quantized layout")
+        if page_size % 2:
+            raise ValueError(f"page_size={page_size}: the latent pool holds "
+                             "two positions a row")
+        W = cfg.kv_lora_rank + cfg.qk_rope_dim
+        return {"latent": jnp.zeros(
+            (num_layers, num_pages, page_size // 2, 2 * W), param_dtype(cfg))}
     hd = cfg.resolved_head_dim
     shape = (num_layers, num_pages, page_size * cfg.num_kv_heads, hd)
     if kv_dtype is None:
@@ -598,12 +659,12 @@ def paged_kernel_view(pool, layer, page_table, kv_heads: int):
     return view, page_table + layer * P, scale
 
 
-def gqa_decode_paged(p, cfg: ModelConfig, x, k_pool, v_pool, layer,
+def gqa_decode_paged(p, cfg: ModelConfig, x, pools, layer,
                      page_table, positions, use_pallas: bool):
     """One-token decode for a batch of slots against the paged pool.
 
       x          : (B, 1, D) — one new token per slot
-      k/v_pool   : (L, P, page_size * KV, hd) — the WHOLE pools
+      pools      : {"k","v"}: (L, P, page_size * KV, hd) — the WHOLE pools
                    (:func:`paged_pools_init`), updated in place
       layer      : int32 scalar — this layer's index into the pools
       page_table : (B, max_pages) int32
@@ -618,8 +679,7 @@ def gqa_decode_paged(p, cfg: ModelConfig, x, k_pool, v_pool, layer,
     the scatter below cannot collide across slots.  The attend reads
     :func:`paged_kernel_view` of the pools.  int8 pools (``{"q","scale"}``
     dicts — see :func:`paged_pools_init`) quantize the write and
-    dequantize inside the attend.  Returns ``(out (B,1,D), k_pool,
-    v_pool)``.
+    dequantize inside the attend.  Returns ``(out (B,1,D), pools)``.
     """
     from repro.kernels.paged_attention import paged_attention_pallas
     from repro.kernels.ref import paged_attention_ref
@@ -627,6 +687,7 @@ def gqa_decode_paged(p, cfg: ModelConfig, x, k_pool, v_pool, layer,
     B, T, _ = x.shape
     assert T == 1
     q, k, v = _qkv(p, cfg, x, positions[:, None])
+    k_pool, v_pool = pools["k"], pools["v"]
     KV = cfg.num_kv_heads
     page_size = (k_pool["q"] if isinstance(k_pool, dict)
                  else k_pool).shape[2] // KV
@@ -646,16 +707,16 @@ def gqa_decode_paged(p, cfg: ModelConfig, x, k_pool, v_pool, layer,
                          v_scale=vs)
         else:
             out = attend(q[:, 0], kp, vp, table, lengths)
-    return out.reshape(B, 1, -1) @ p["wo"], k_pool, v_pool
+    return out.reshape(B, 1, -1) @ p["wo"], {"k": k_pool, "v": v_pool}
 
 
-def gqa_prefill_paged(p, cfg: ModelConfig, x, k_pool, v_pool, layer,
+def gqa_prefill_paged(p, cfg: ModelConfig, x, pools, layer,
                       page_table, positions):
     """Chunk/suffix prefill for ONE slot against the paged pool.
 
       x          : (1, T, D) — hidden states of a contiguous prompt chunk
-      k/v_pool   : (L, P, page_size * KV, hd) — the WHOLE pools, updated
-                   in place
+      pools      : {"k","v"}: (L, P, page_size * KV, hd) — the WHOLE
+                   pools, updated in place
       layer      : int32 scalar — this layer's index into the pools
       page_table : (max_pages,) int32 — the slot's pages, prompt order
       positions  : (T,) int32 — absolute positions pos0 .. pos0+T-1
@@ -675,6 +736,7 @@ def gqa_prefill_paged(p, cfg: ModelConfig, x, k_pool, v_pool, layer,
     B, T, _ = x.shape
     assert B == 1
     q, k, v = _qkv(p, cfg, x, positions)
+    k_pool, v_pool = pools["k"], pools["v"]
     pos = positions.astype(jnp.int32)
     # in-chunk positions are distinct, so the store never scatter-dups
     with jax.named_scope("kv_write"):
@@ -693,7 +755,7 @@ def gqa_prefill_paged(p, cfg: ModelConfig, x, k_pool, v_pool, layer,
     ctx = kc.shape[1]
     mask = jnp.arange(ctx)[None, :] <= pos[:, None]  # (T, ctx)
     out = sdpa(q, kc, vc, mask, cfg.num_kv_heads)
-    return out.reshape(B, T, -1) @ p["wo"], k_pool, v_pool
+    return out.reshape(B, T, -1) @ p["wo"], {"k": k_pool, "v": v_pool}
 
 
 # ---------------------------------------------------------------------------
@@ -732,6 +794,9 @@ def xattn(p, cfg: ModelConfig, x, kv_feats):
 
 
 def mla_init(key, cfg: ModelConfig):
+    """``w_dkv`` and ``w_krope`` together are DeepSeek-V2's ``kv_a_proj``
+    (the latent ``c`` and the shared rope key), ``kv_norm`` its
+    ``kv_a_layernorm``, ``w_uk`` and ``w_uv`` its ``kv_b_proj``."""
     dtype = param_dtype(cfg)
     H = cfg.num_heads
     ks = jax.random.split(key, 6)
@@ -740,10 +805,26 @@ def mla_init(key, cfg: ModelConfig):
         "wq": dense_init(ks[0], (cfg.d_model, H * qd), dtype),
         "w_dkv": dense_init(ks[1], (cfg.d_model, cfg.kv_lora_rank), dtype),
         "w_krope": dense_init(ks[2], (cfg.d_model, cfg.qk_rope_dim), dtype),
+        "kv_norm": rmsnorm_init(cfg.kv_lora_rank, dtype),
         "w_uk": dense_init(ks[3], (cfg.kv_lora_rank, H * cfg.qk_nope_dim), dtype),
         "w_uv": dense_init(ks[4], (cfg.kv_lora_rank, H * cfg.v_head_dim), dtype),
         "wo": dense_init(ks[5], (H * cfg.v_head_dim, cfg.d_model), dtype),
     }
+
+
+def mla_scale(cfg: ModelConfig) -> float:
+    """The softmax scale: ``(qk_nope + qk_rope)^-0.5``, times
+    ``yarn_mscale(factor, mscale_all_dim)^2`` under YaRN."""
+    s = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        s *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return s
+
+
+def _mla_rope(cfg: ModelConfig, x, positions):
+    return apply_rope(x, positions, cfg.rope_theta, cfg.rope_scaling,
+                      interleaved=True)
 
 
 def _mla_q(p, cfg: ModelConfig, x, positions):
@@ -751,8 +832,34 @@ def _mla_q(p, cfg: ModelConfig, x, positions):
     H = cfg.num_heads
     q = (x @ p["wq"]).reshape(B, T, H, cfg.qk_nope_dim + cfg.qk_rope_dim)
     q_nope, q_rope = jnp.split(q, [cfg.qk_nope_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    return q_nope, q_rope
+    return q_nope, _mla_rope(cfg, q_rope, positions)
+
+
+def _mla_latent(p, cfg: ModelConfig, x, positions):
+    """What the cache holds of x: (B, T, D): the normalised latent ``c``
+    (B, T, r) and the roped rope key (B, T, qk_rope), one for all heads."""
+    c = rmsnorm(p["kv_norm"], x @ p["w_dkv"], cfg.norm_eps)
+    krope = _mla_rope(cfg, (x @ p["w_krope"])[:, :, None, :], positions)
+    return c, krope[:, :, 0]
+
+
+def _mla_absorb(p, cfg: ModelConfig, q_nope, q_rope):
+    """Queries against the latent cache, (..., H, r + qk_rope) float32:
+    ``q_nope`` absorbed through ``w_uk`` (``q_nope . (c w_uk) =
+    (q_nope w_uk^T) . c``), then the rope query."""
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    wk = p["w_uk"].reshape(r, H, cfg.qk_nope_dim).astype(jnp.float32)
+    q_abs = jnp.einsum("...hd,rhd->...hr", q_nope.astype(jnp.float32), wk)
+    return jnp.concatenate([q_abs, q_rope.astype(jnp.float32)], axis=-1)
+
+
+def _mla_out(p, cfg: ModelConfig, lat, dtype):
+    """The latent attention output lat: (..., H, r) expanded through
+    ``w_uv`` and projected: (..., D)."""
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    wv = p["w_uv"].reshape(r, H, cfg.v_head_dim).astype(jnp.float32)
+    out = jnp.einsum("...hr,rhd->...hd", lat.astype(jnp.float32), wv)
+    return out.reshape(out.shape[:-2] + (-1,)).astype(dtype) @ p["wo"]
 
 
 def mla_train(p, cfg: ModelConfig, x):
@@ -761,15 +868,13 @@ def mla_train(p, cfg: ModelConfig, x):
     H = cfg.num_heads
     positions = jnp.arange(T)
     q_nope, q_rope = _mla_q(p, cfg, x, positions)
-    ckv = x @ p["w_dkv"]  # (B,T,r)
-    krope = apply_rope((x @ p["w_krope"])[:, :, None, :], positions, cfg.rope_theta)
+    ckv, krope = _mla_latent(p, cfg, x, positions)
     k_nope = (ckv @ p["w_uk"]).reshape(B, T, H, cfg.qk_nope_dim)
     v = (ckv @ p["w_uv"]).reshape(B, T, H, cfg.v_head_dim)
-    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
     scores = (
         jnp.einsum("bthd,bshd->bhts", q_nope.astype(jnp.float32), k_nope.astype(jnp.float32))
-        + jnp.einsum("bthd,bsxd->bhts", q_rope.astype(jnp.float32), krope.astype(jnp.float32))
-    ) * scale
+        + jnp.einsum("bthd,bsd->bhts", q_rope.astype(jnp.float32), krope.astype(jnp.float32))
+    ) * mla_scale(cfg)
     mask = causal_mask(T)
     scores = jnp.where(mask[None, None], scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
@@ -789,9 +894,7 @@ def mla_cache_init(cfg: ModelConfig, batch: int, capacity: int, num_layers: int)
 def mla_prefill(p, cfg: ModelConfig, x, cache_l):
     B, T, _ = x.shape
     out = mla_train(p, cfg, x)
-    positions = jnp.arange(T)
-    ckv = x @ p["w_dkv"]
-    krope = apply_rope((x @ p["w_krope"])[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    ckv, krope = _mla_latent(p, cfg, x, jnp.arange(T))
     cache_l = {
         "ckv": cache_l["ckv"].at[:, :T].set(ckv.astype(cache_l["ckv"].dtype)),
         "krope": cache_l["krope"].at[:, :T].set(krope.astype(cache_l["krope"].dtype)),
@@ -809,13 +912,9 @@ def mla_decode(p, cfg: ModelConfig, x, cache_l, pos):
     """
     B, T, _ = x.shape
     assert T == 1
-    H = cfg.num_heads
     r = cfg.kv_lora_rank
     q_nope, q_rope = _mla_q(p, cfg, x, jnp.asarray(pos)[None])
-    ckv_t = x @ p["w_dkv"]  # (B,1,r)
-    krope_t = apply_rope(
-        (x @ p["w_krope"])[:, :, None, :], jnp.asarray(pos)[None], cfg.rope_theta
-    )[:, :, 0]
+    ckv_t, krope_t = _mla_latent(p, cfg, x, jnp.asarray(pos)[None])
     cap = cache_l["ckv"].shape[1]
     slot = jnp.asarray(pos, jnp.int32) % cap
     ckv = jax.lax.dynamic_update_index_in_dim(
@@ -829,17 +928,138 @@ def mla_decode(p, cfg: ModelConfig, x, cache_l, pos):
     )
     cache_l = {"ckv": ckv, "krope": krope, "pos_ids": cpos}
 
-    wk = p["w_uk"].reshape(r, H, cfg.qk_nope_dim)
-    q_abs = jnp.einsum("bthd,rhd->bthr", q_nope.astype(jnp.float32), wk.astype(jnp.float32))
-    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
-    scores = (
-        jnp.einsum("bthr,bsr->bhts", q_abs, ckv.astype(jnp.float32))
-        + jnp.einsum("bthd,bsd->bhts", q_rope.astype(jnp.float32), krope.astype(jnp.float32))
-    ) * scale
-    valid = cpos >= 0
-    scores = jnp.where(valid[None, None, None, :], scores, NEG_INF)
+    q = _mla_absorb(p, cfg, q_nope[:, 0], q_rope[:, 0])        # (B,H,r+rope)
+    keys = jnp.concatenate([ckv, krope], -1).astype(jnp.float32)  # (B,S,r+rope)
+    scores = jnp.einsum("bhd,bsd->bhs", q, keys) * mla_scale(cfg)
+    scores = jnp.where((cpos >= 0)[None, None, :], scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
-    lat = jnp.einsum("bhts,bsr->bthr", w, ckv.astype(jnp.float32))  # (B,1,H,r)
-    wv = p["w_uv"].reshape(r, H, cfg.v_head_dim)
-    out = jnp.einsum("bthr,rhd->bthd", lat, wv.astype(jnp.float32)).astype(x.dtype)
-    return out.reshape(B, 1, -1) @ p["wo"], cache_l
+    lat = jnp.einsum("bhs,bsr->bhr", w, keys[..., :r])
+    return _mla_out(p, cfg, lat, x.dtype)[:, None], cache_l
+
+
+# -- MLA over the paged latent pool ------------------------------------------
+
+
+def latent_pool_rows(pool, layer, page_ids):
+    """Pages ``page_ids`` of layer ``layer`` of the latent pool ``(L, P,
+    page_size // 2, 2W)`` as position rows ``(len(page_ids), page_size,
+    W)``: a gather of those pages alone."""
+    pages = pool[layer, page_ids]
+    return pages.reshape(pages.shape[:-2] + (-1, pages.shape[-1] // 2))
+
+
+def _latent_rows(p, cfg: ModelConfig, x, positions):
+    """x's pool rows (B, T, r + qk_rope): ``[c | k_pe]``."""
+    c, krope = _mla_latent(p, cfg, x, positions)
+    return jnp.concatenate([c, krope.astype(c.dtype)], -1)
+
+
+def latent_store_rows(pool, layer, page_idx, offset, rows):
+    """Write one row (W,) per batch entry into layer ``layer`` of the whole
+    latent ``pool`` at position ``offset[b]`` of page ``page_idx[b]``: the
+    window ``[layer, page, offset // 2, (offset % 2) W : + W]`` alone."""
+    n, W = rows.shape
+    idx = jnp.stack([jnp.broadcast_to(layer, (n,)), page_idx, offset // 2,
+                     (offset % 2) * W], axis=-1).astype(jnp.int32)
+    return _window_scatter(pool, idx, rows)
+
+
+def latent_store_chunk(pool, layer, page_table, positions, rows):
+    """Write a contiguous chunk of rows (T, W) for ONE slot into layer
+    ``layer`` of the whole latent ``pool``: the chunk's pages
+    (:func:`_chunk_window`) are gathered, given their rows and written
+    back whole, as :func:`paged_store_chunk` does."""
+    pos = positions.astype(jnp.int32)
+    page_size = 2 * pool.shape[2]
+    pids, local, offs = _chunk_window(page_table, pos, page_size)
+    pages = latent_pool_rows(pool, layer, pids).at[local, offs].set(
+        rows.astype(pool.dtype))
+    return _pool_write(pool, layer, pids, 0,
+                       pages.reshape((pids.shape[0],) + pool.shape[2:]))
+
+
+def mla_decode_paged(p, cfg: ModelConfig, x, pools, layer, page_table,
+                     positions, use_pallas: bool):
+    """One-token MLA decode for a batch of slots against the latent pool.
+
+    Same contract as :func:`gqa_decode_paged`, over ``pools["latent"]``
+    (:func:`paged_pools_init`): each slot's new row ``[c | k_pe]`` is
+    scattered in place, then every head attends over the slot's latent
+    pages in the absorbed form (``kernels.paged_attention.
+    latent_attention_pallas``, or its oracle ``kernels.ref.
+    latent_attention_ref``), and the latent output is expanded through
+    ``w_uv`` here.  Returns ``(out (B,1,D), pools)``."""
+    from repro.kernels.paged_attention import latent_attention_pallas
+    from repro.kernels.ref import latent_attention_ref
+
+    B = x.shape[0]
+    pos = positions.astype(jnp.int32)
+    q_nope, q_rope = _mla_q(p, cfg, x, pos[:, None])
+    pool = pools["latent"]
+    page_size = 2 * pool.shape[2]
+    page_idx = page_table[jnp.arange(B), pos // page_size]
+    with jax.named_scope("kv_write"):
+        pool = latent_store_rows(pool, layer, page_idx, pos % page_size,
+                                 _latent_rows(p, cfg, x, pos[:, None])[:, 0])
+    q = _mla_absorb(p, cfg, q_nope[:, 0], q_rope[:, 0])
+    attend = latent_attention_pallas if use_pallas else latent_attention_ref
+    with jax.named_scope("paged_attention"):
+        # every layer's pages at once: a free view (L * P, page_size // 2,
+        # 2W), the page table offset to this layer's
+        n_layers, P = pool.shape[:2]
+        lat = attend(q, pool.reshape((n_layers * P,) + pool.shape[2:]),
+                     page_table + layer * P, pos + 1, scale=mla_scale(cfg))
+    out = _mla_out(p, cfg, lat[..., :cfg.kv_lora_rank], x.dtype)
+    return out[:, None], {"latent": pool}
+
+
+#: context positions one step of the prompt chunk's latent attend reads
+LATENT_BLOCK = 1024
+
+
+def mla_prefill_paged(p, cfg: ModelConfig, x, pools, layer, page_table,
+                      positions):
+    """Chunk/suffix prefill for ONE slot against the latent pool: the
+    chunk's rows are written, then its queries attend, in the absorbed
+    form, over the slot's context so far, :data:`LATENT_BLOCK` positions
+    at a time with a streaming softmax, reading only the blocks that
+    hold positions up to the chunk's last (a loop of dynamic length).
+    Absorbed rather than expanded through ``w_uk``/``w_uv``: decode
+    reads the pool the same way, and no per-head key or value is
+    materialised for the context.  Returns ``(out (1,T,D), pools)``."""
+    T = x.shape[1]
+    pos = positions.astype(jnp.int32)
+    q_nope, q_rope = _mla_q(p, cfg, x, pos)
+    pool = pools["latent"]
+    with jax.named_scope("kv_write"):
+        pool = latent_store_chunk(pool, layer, page_table, pos,
+                                  _latent_rows(p, cfg, x, pos)[0])
+    q = _mla_absorb(p, cfg, q_nope[0], q_rope[0])       # (T, H, W)
+    H, W = q.shape[1], q.shape[2]
+    page_size = 2 * pool.shape[2]
+    n = max(LATENT_BLOCK // page_size, 1)             # pages a block
+    table = jnp.pad(page_table, (0, -page_table.shape[0] % n))
+    scale = mla_scale(cfg)
+
+    def block(i, carry):
+        acc, m, l = carry
+        pids = jax.lax.dynamic_slice_in_dim(table, i * n, n)
+        keys = latent_pool_rows(pool, layer, pids).reshape(
+            n * page_size, W).astype(jnp.float32)
+        s = jnp.einsum("thd,sd->ths", q, keys) * scale
+        kpos = i * n * page_size + jnp.arange(n * page_size)
+        s = jnp.where(kpos[None, None, :] <= pos[:, None, None], s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, -1, keepdims=True))
+        e = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        return (acc * alpha + jnp.einsum("ths,sd->thd", e, keys),
+                m_new, l * alpha + jnp.sum(e, -1, keepdims=True))
+
+    n_blocks = pos[T - 1] // (n * page_size) + 1
+    acc, _, l = jax.lax.fori_loop(
+        0, n_blocks, block,
+        (jnp.zeros((T, H, W), jnp.float32),
+         jnp.full((T, H, 1), NEG_INF, jnp.float32),
+         jnp.zeros((T, H, 1), jnp.float32)))
+    lat = (acc / l)[..., :cfg.kv_lora_rank]
+    return _mla_out(p, cfg, lat, x.dtype)[None], {"latent": pool}

@@ -4,7 +4,11 @@ Blocks are *scanned*: all layer parameters are stacked along a leading
 ``num_layers`` axis under ``params["blocks"]`` (and ``params["enc_blocks"]``
 for encoder–decoder models).  This keeps HLO size O(1) in depth — required
 for the 61/80-layer dry-runs — and the WASH layer-wise schedule stays exact
-via the layered plans in ``repro.core.shuffle``.
+via the layered plans in ``repro.core.shuffle``.  A model whose first
+``cfg.first_dense_layers`` blocks have a dense MLP before an expert stack
+(DeepSeek-V2) holds them as a second stack, ``params["dense_blocks"]``:
+each path runs the stacks in depth order (:func:`segments`), and the
+layer-stacked caches and pools stay indexed by depth.
 
 Batch dicts:
   dense/moe/ssm/hybrid : {"tokens": (B,S) int32}
@@ -14,7 +18,8 @@ Batch dicts:
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+import dataclasses
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -105,12 +110,55 @@ def init_params(key, cfg: ModelConfig) -> PyTree:
 
     block_init = _dec_block_init if cfg.is_encdec else _block_init
     bkeys = jax.random.split(ks[5], cfg.num_layers)
-    params["blocks"] = jax.vmap(lambda k: block_init(k, cfg))(bkeys)
+    k = cfg.first_dense_layers
+    if k:
+        dense = _dense_cfg(cfg)
+        params["dense_blocks"] = jax.vmap(lambda kk: block_init(kk, dense))(
+            bkeys[:k])
+    params["blocks"] = jax.vmap(lambda kk: block_init(kk, cfg))(bkeys[k:])
     if cfg.is_encdec:
         ekeys = jax.random.split(ks[6], cfg.encoder_layers)
         params["enc_blocks"] = jax.vmap(lambda k: _enc_block_init(k, cfg))(ekeys)
         params["enc_norm"] = L.rmsnorm_init(D, dtype)
     return params
+
+
+# ---------------------------------------------------------------------------
+# stacks of like blocks
+# ---------------------------------------------------------------------------
+
+
+def _dense_cfg(cfg: ModelConfig) -> ModelConfig:
+    """The configuration of the leading dense blocks: a SwiGLU of width
+    ``d_ff`` in place of the experts."""
+    return dataclasses.replace(cfg, moe=False, first_dense_layers=0)
+
+
+def segments(params, cfg: ModelConfig) -> List[Tuple[Any, ModelConfig, int]]:
+    """``(blocks, cfg, first layer)`` of each stack of like blocks, in
+    depth order: the leading dense blocks (if any), then ``blocks``."""
+    k = cfg.first_dense_layers
+    segs = [(params["dense_blocks"], _dense_cfg(cfg), 0)] if k else []
+    return segs + [(params["blocks"], cfg, k)]
+
+
+def _stack_len(blocks) -> int:
+    return jax.tree_util.tree_leaves(blocks)[0].shape[0]
+
+
+def _scan_segments(params, cfg: ModelConfig, x, cache, make_body):
+    """Scan each stack with ``make_body(stack_cfg)`` over ``(block,
+    cache slice)``, the layer-stacked ``cache`` cut to the stack's depths
+    and put back together.  Returns ``(x, cache)``."""
+    outs = []
+    for blocks, scfg, first in segments(params, cfg):
+        n = _stack_len(blocks)
+        part = jax.tree_util.tree_map(lambda c: c[first:first + n], cache)
+        x, new = jax.lax.scan(make_body(scfg), x, (blocks, part),
+                              unroll=cfg.scan_unroll)
+        outs.append(new)
+    return x, jax.tree_util.tree_map(
+        lambda *c: jnp.concatenate(c, axis=0), *outs)
 
 
 # ---------------------------------------------------------------------------
@@ -161,21 +209,29 @@ def _run_blocks_train(params, cfg: ModelConfig, x):
     else:
         init_state = None
 
-    def body(carry, xs):
-        h = carry
-        if init_state is None:
-            block_l = xs
-            h, _, aux = _block_train(block_l, cfg, h, None)
-        else:
+    if init_state is not None:
+        def body(h, xs):
             block_l, state_l = xs
             h, _, aux = _block_train(block_l, cfg, h, state_l)
-        return h, aux
+            return h, aux
 
-    if cfg.remat_blocks:
-        body = jax.checkpoint(body)
-    xs = params["blocks"] if init_state is None else (params["blocks"], init_state)
-    x, auxs = jax.lax.scan(body, x, xs, unroll=cfg.scan_unroll)
-    return x, jnp.sum(auxs)
+        if cfg.remat_blocks:
+            body = jax.checkpoint(body)
+        x, auxs = jax.lax.scan(body, x, (params["blocks"], init_state),
+                               unroll=cfg.scan_unroll)
+        return x, jnp.sum(auxs)
+
+    total = None
+    for blocks, scfg, _ in segments(params, cfg):
+        def body(h, block_l, scfg=scfg):
+            h, _, aux = _block_train(block_l, scfg, h, None)
+            return h, aux
+
+        if cfg.remat_blocks:
+            body = jax.checkpoint(body)
+        x, auxs = jax.lax.scan(body, x, blocks, unroll=cfg.scan_unroll)
+        total = jnp.sum(auxs) if total is None else total + jnp.sum(auxs)
+    return x, total
 
 
 # ---------------------------------------------------------------------------
@@ -278,6 +334,8 @@ def pipeline_supported(cfg: ModelConfig) -> Optional[str]:
     rather than trained wrong."""
     if cfg.block_kind != "attn":
         return f"block_kind={cfg.block_kind!r} carries state across blocks"
+    if cfg.first_dense_layers:
+        return "leading dense blocks are a stack of their own"
     if cfg.is_encdec:
         return "encoder-decoder needs the encoder output on every stage"
     if cfg.frontend is not None:
@@ -394,12 +452,14 @@ def decode_step(params, cfg: ModelConfig, tokens, cache, pos):
         params["embed"]["tok"][tokens]
     )
 
-    def body(h, xs):
-        block_l, cache_l = xs
-        h, new_cache_l = _block_decode(block_l, cfg, h, cache_l, pos)
-        return h, new_cache_l
+    def make_body(scfg):
+        def body(h, xs):
+            block_l, cache_l = xs
+            h, new_cache_l = _block_decode(block_l, scfg, h, cache_l, pos)
+            return h, new_cache_l
+        return body
 
-    x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache), unroll=cfg.scan_unroll)
+    x, new_cache = _scan_segments(params, cfg, x, cache, make_body)
     return _logits(params, cfg, x), new_cache
 
 
@@ -417,6 +477,8 @@ def staged_decode_supported(cfg: ModelConfig) -> Optional[str]:
     stage-dependent.  All rejected loudly rather than served wrong."""
     if cfg.block_kind != "attn":
         return f"block_kind={cfg.block_kind!r} state is not stage-split"
+    if cfg.first_dense_layers:
+        return "leading dense blocks are a stack of their own"
     if cfg.is_encdec:
         return "encoder-decoder cross-attention cache is not stage-split"
     if cfg.frontend is not None:
@@ -491,15 +553,14 @@ def lm_logits(params, cfg: ModelConfig, x):
 def paged_decode_supported(cfg: ModelConfig) -> Optional[str]:
     """None if ``decode_step_paged`` can serve this config, else the reason.
 
-    The paged path covers the GQA decoder-only families (dense + MoE).
-    MLA needs a latent-space pool, SSM/hybrid state is not paged, sliding
-    windows interact with page retirement, and encoder-decoder / modality
-    prefixes need prefix-page plumbing — all future work, all rejected
-    loudly rather than served wrong."""
+    The paged path covers the decoder-only attention families (dense +
+    MoE, grouped-query or latent attention, leading dense blocks).
+    SSM/hybrid state is not paged, sliding windows interact with page
+    retirement, and encoder-decoder / modality prefixes need prefix-page
+    plumbing — all future work, all rejected loudly rather than served
+    wrong."""
     if cfg.block_kind != "attn":
         return f"block_kind={cfg.block_kind!r} state is not paged"
-    if cfg.mla:
-        return "MLA latent cache has no paged layout yet"
     if cfg.is_encdec:
         return "encoder-decoder cross-attention cache is not paged"
     if cfg.frontend is not None:
@@ -507,6 +568,33 @@ def paged_decode_supported(cfg: ModelConfig) -> Optional[str]:
     if cfg.window is not None:
         return "sliding-window ring eviction is not paged"
     return None
+
+
+def _paged_layers(params, cfg: ModelConfig, x, pools, attend):
+    """Every block over x against the whole paged ``pools``: layer ``i``
+    of each stack attends through ``attend(attn_params, cfg, h, pools,
+    depth)`` at its depth in the pools, which it updates in place, and
+    the pools are carried through the layer loop.  Weights are indexed by
+    the loop's layer rather than scanned: an ensemble vmaps this over
+    members, and scanning (N, L, ...) weights would transpose all of them
+    to (L, N, ...) on every call.  Returns ``(x, pools)``."""
+    for blocks, scfg, first in segments(params, cfg):
+        def body(carry, i, blocks=blocks, scfg=scfg, first=first):
+            h, pools = carry
+            block_l = jax.tree_util.tree_map(lambda w: w[i], blocks)
+            a_in = L.rmsnorm(block_l["ln1"], h, cfg.norm_eps)
+            with jax.named_scope("attention"):
+                a, pools = attend(block_l["attn"], scfg, a_in, pools,
+                                  first + i)
+            h = h + a
+            y, _ = _mlp_apply(block_l["mlp"], scfg,
+                              L.rmsnorm(block_l["ln2"], h, cfg.norm_eps))
+            return (h + y, pools), None
+
+        (x, pools), _ = jax.lax.scan(
+            body, (x, pools), jnp.arange(_stack_len(blocks), dtype=jnp.int32),
+            unroll=cfg.scan_unroll)
+    return x, pools
 
 
 def decode_step_paged(params, cfg: ModelConfig, tokens, positions, pools,
@@ -517,8 +605,10 @@ def decode_step_paged(params, cfg: ModelConfig, tokens, positions, pools,
       positions   : (B,) int32 — each token's absolute write position
                     (per-slot, unlike :func:`decode_step`'s shared scalar —
                     slots in a continuous batch sit at different depths)
-      pools       : {"k","v"}: (L, P, page_size * KV, hd)
-                    (:func:`repro.models.layers.paged_pools_init`)
+      pools       : the pool pytree of
+                    :func:`repro.models.layers.paged_pools_init`:
+                    {"k","v"} (L, P, page_size * KV, hd), or MLA's
+                    {"latent"} (L, P, page_size, r + qk_rope)
       page_tables : (B, max_pages) int32 pool-page ids per slot
 
     Everything is traced — admissions, retirements, and page-table edits
@@ -536,42 +626,29 @@ def decode_step_paged(params, cfg: ModelConfig, tokens, positions, pools,
     x = params["embed"]["tok"][tokens[:, None]]
     if cfg.pos_kind == "learned":
         x = x + params["embed"]["pos"][pos][:, None]
+    attend_paged = L.mla_decode_paged if cfg.mla else L.gqa_decode_paged
 
-    def body(carry, layer):
-        h, kp, vp = carry
-        # indexed by the layer rather than scanned: an ensemble vmaps this
-        # over members, and scanning (N, L, ...) weights would transpose
-        # all of them to (L, N, ...) on every call
-        block_l = jax.tree_util.tree_map(lambda w: w[layer], params["blocks"])
-        a_in = L.rmsnorm(block_l["ln1"], h, cfg.norm_eps)
-        with jax.named_scope("attention"):
-            a, kp, vp = L.gqa_decode_paged(
-                block_l["attn"], cfg, a_in, kp, vp, layer, page_tables, pos,
-                use_pallas,
-            )
-        h = h + a
-        y, _ = _mlp_apply(block_l["mlp"], cfg,
-                          L.rmsnorm(block_l["ln2"], h, cfg.norm_eps))
-        return (h + y, kp, vp), None
+    def attend(p, scfg, h, pools, layer):
+        return attend_paged(p, scfg, h, pools, layer, page_tables, pos,
+                            use_pallas)
 
-    (x, k_pool, v_pool), _ = jax.lax.scan(
-        body, (x, pools["k"], pools["v"]),
-        jnp.arange(cfg.num_layers, dtype=jnp.int32), unroll=cfg.scan_unroll,
-    )
-    return _logits(params, cfg, x), {"k": k_pool, "v": v_pool}
+    x, pools = _paged_layers(params, cfg, x, pools, attend)
+    return _logits(params, cfg, x), pools
 
 
 def paged_prefill_supported(cfg: ModelConfig) -> Optional[str]:
     """None if ``prefill_paged`` can serve this config, else the reason.
 
     Everything :func:`paged_decode_supported` rejects, plus non-naive
-    attention: ``attn_impl="chunked"`` prefills through the online-softmax
-    formulation whose numerics differ from the paged gather+sdpa attend,
-    so suffix/chunk prefill could not keep the bitwise parity contract."""
+    grouped-query attention: ``attn_impl="chunked"`` prefills through the
+    online-softmax formulation whose numerics differ from the paged
+    gather+sdpa attend, so suffix/chunk prefill could not keep the bitwise
+    parity contract.  (MLA's chunk attend does not depend on
+    ``attn_impl``.)"""
     reason = paged_decode_supported(cfg)
     if reason is not None:
         return reason
-    if cfg.attn_impl != "naive":
+    if cfg.attn_impl != "naive" and not cfg.mla:
         return (f"attn_impl={cfg.attn_impl!r} prefill numerics are not "
                 "bitwise-compatible with the paged gather+sdpa attend")
     return None
@@ -584,9 +661,9 @@ def prefill_paged(params, cfg: ModelConfig, tokens, pos0, pools, page_table):
       pos0       : int or traced scalar — absolute position of ``tokens[0]``
                    (traced by the serving runtime, so ONE compile per chunk
                    length serves every offset and every slot)
-      pools      : {"k","v"}: (L, P, page_size * KV, hd), carried whole
-                   through the layer loop and written in place (only the
-                   chunk's rows), as in :func:`decode_step_paged`
+      pools      : the pool pytree (:func:`decode_step_paged`), carried
+                   whole through the layer loop and written in place (only
+                   the chunk's rows)
       page_table : (max_pages,) int32 — the slot's pages in prompt order;
                    entries below ``pos0 // page_size`` may be chain-hash
                    shared prefix pages (read, never written)
@@ -594,8 +671,9 @@ def prefill_paged(params, cfg: ModelConfig, tokens, pos0, pools, page_table):
     Earlier context — a deduped prefix and/or previously prefilled chunks —
     is read straight from the pool, so a suffix admission skips the cached
     prefix's FLOPs entirely.  Returns ``(logits (1,1,V) for the chunk's
-    last position, pools)``; rows written/read are bitwise-identical to
-    the whole-prompt :func:`prefill` (see ``gqa_prefill_paged``)."""
+    last position, pools)``; for grouped-query attention the rows
+    written/read are bitwise-identical to the whole-prompt :func:`prefill`
+    (see ``gqa_prefill_paged``)."""
     reason = paged_prefill_supported(cfg)
     if reason is not None:
         raise NotImplementedError(f"paged prefill: {reason}")
@@ -603,26 +681,13 @@ def prefill_paged(params, cfg: ModelConfig, tokens, pos0, pools, page_table):
     T = tokens.shape[0]
     positions = pos0 + jnp.arange(T, dtype=jnp.int32)
     x = _embed_tokens(params, cfg, tokens[None], pos0=pos0)
+    attend_paged = L.mla_prefill_paged if cfg.mla else L.gqa_prefill_paged
 
-    def body(carry, layer):
-        h, kp, vp = carry
-        block_l = jax.tree_util.tree_map(lambda w: w[layer], params["blocks"])
-        a_in = L.rmsnorm(block_l["ln1"], h, cfg.norm_eps)
-        with jax.named_scope("attention"):
-            a, kp, vp = L.gqa_prefill_paged(
-                block_l["attn"], cfg, a_in, kp, vp, layer, page_table,
-                positions,
-            )
-        h = h + a
-        y, _ = _mlp_apply(block_l["mlp"], cfg,
-                          L.rmsnorm(block_l["ln2"], h, cfg.norm_eps))
-        return (h + y, kp, vp), None
+    def attend(p, scfg, h, pools, layer):
+        return attend_paged(p, scfg, h, pools, layer, page_table, positions)
 
-    (x, k_pool, v_pool), _ = jax.lax.scan(
-        body, (x, pools["k"], pools["v"]),
-        jnp.arange(cfg.num_layers, dtype=jnp.int32), unroll=cfg.scan_unroll,
-    )
-    return _logits(params, cfg, x[:, -1:]), {"k": k_pool, "v": v_pool}
+    x, pools = _paged_layers(params, cfg, x, pools, attend)
+    return _logits(params, cfg, x[:, -1:]), pools
 
 
 def decode_scan(params, cfg: ModelConfig, first, cache, start_pos, num_steps,
@@ -698,32 +763,34 @@ def prefill(params, cfg: ModelConfig, batch, capacity: Optional[int] = None):
         x = jnp.concatenate([patches.astype(x.dtype), x], axis=1)
         n_prefix = patches.shape[1]
 
-    def body(h, xs):
-        block_l, cache_l = xs
-        new_cache_l = dict(cache_l)
-        a_in = L.rmsnorm(block_l["ln1"], h, cfg.norm_eps)
-        if cfg.mla:
-            a, new_cache_l["kv"] = L.mla_prefill(block_l["attn"], cfg, a_in, cache_l["kv"])
-        else:
-            a, new_cache_l["kv"] = L.gqa_prefill(block_l["attn"], cfg, a_in, cache_l["kv"])
-        if cfg.block_kind == "hybrid":
-            m, new_cache_l["ssm"] = SSM.mamba_prefill(block_l["mamba"], cfg, a_in, cache_l["ssm"])
-            beta = jax.nn.softmax(block_l["beta"]).astype(a.dtype)
-            a = beta[0] * a + beta[1] * m
-        h = h + a
-        if cfg.is_encdec:
-            hx = L.rmsnorm(block_l["ln_x"], h, cfg.norm_eps)
-            c = L.xattn(block_l["xattn"], cfg, hx, enc_out)
-            h = h + c
-            B_, = (h.shape[0],)
-            new_cache_l["xk"] = (enc_out @ block_l["xattn"]["wk"]).reshape(
-                B_, S, cfg.num_kv_heads, hd
-            ).astype(cache_l["xk"].dtype)
-            new_cache_l["xv"] = (enc_out @ block_l["xattn"]["wv"]).reshape(
-                B_, S, cfg.num_kv_heads, hd
-            ).astype(cache_l["xv"].dtype)
-        y, _ = _mlp_apply(block_l["mlp"], cfg, L.rmsnorm(block_l["ln2"], h, cfg.norm_eps))
-        return h + y, new_cache_l
+    def make_body(cfg):
+        def body(h, xs):
+            block_l, cache_l = xs
+            new_cache_l = dict(cache_l)
+            a_in = L.rmsnorm(block_l["ln1"], h, cfg.norm_eps)
+            if cfg.mla:
+                a, new_cache_l["kv"] = L.mla_prefill(block_l["attn"], cfg, a_in, cache_l["kv"])
+            else:
+                a, new_cache_l["kv"] = L.gqa_prefill(block_l["attn"], cfg, a_in, cache_l["kv"])
+            if cfg.block_kind == "hybrid":
+                m, new_cache_l["ssm"] = SSM.mamba_prefill(block_l["mamba"], cfg, a_in, cache_l["ssm"])
+                beta = jax.nn.softmax(block_l["beta"]).astype(a.dtype)
+                a = beta[0] * a + beta[1] * m
+            h = h + a
+            if cfg.is_encdec:
+                hx = L.rmsnorm(block_l["ln_x"], h, cfg.norm_eps)
+                c = L.xattn(block_l["xattn"], cfg, hx, enc_out)
+                h = h + c
+                B_, = (h.shape[0],)
+                new_cache_l["xk"] = (enc_out @ block_l["xattn"]["wk"]).reshape(
+                    B_, S, cfg.num_kv_heads, hd
+                ).astype(cache_l["xk"].dtype)
+                new_cache_l["xv"] = (enc_out @ block_l["xattn"]["wv"]).reshape(
+                    B_, S, cfg.num_kv_heads, hd
+                ).astype(cache_l["xv"].dtype)
+            y, _ = _mlp_apply(block_l["mlp"], cfg, L.rmsnorm(block_l["ln2"], h, cfg.norm_eps))
+            return h + y, new_cache_l
+        return body
 
-    x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache), unroll=cfg.scan_unroll)
+    x, new_cache = _scan_segments(params, cfg, x, cache, make_body)
     return _logits(params, cfg, x[:, -1:]), new_cache

@@ -343,6 +343,51 @@ def _sample_steps(last, keys, steps, temperature, greedy: bool):
 # ---------------------------------------------------------------------------
 
 
+def _pool_names(cfg: ModelConfig) -> Tuple[str, ...]:
+    """The pools of ``L.paged_pools_init``'s pytree, in argument order."""
+    return ("latent",) if cfg.mla else ("k", "v")
+
+
+def _pool_argnums(cfg: ModelConfig, at: int,
+                  sets: int = 1) -> Tuple[int, ...]:
+    """The argument positions of ``sets`` pool pytrees from ``at``, one
+    argument a pool (:func:`_pools_apart`)."""
+    return tuple(range(at, at + sets * len(_pool_names(cfg))))
+
+
+def _pools_apart(cfg: ModelConfig, fn: Callable, at: int, sets: int = 1):
+    """``fn``, whose ``sets`` pool pytrees start at argument ``at``, as a
+    program taking each pool as an argument of its own: ``(..., k_pool,
+    v_pool, ...)`` for GQA, ``(..., latent_pool, ...)`` for MLA, ``None``
+    for each pool of an absent set.  The program keeps ``fn``'s name,
+    which the profiler shows."""
+    names = _pool_names(cfg)
+    n = len(names)
+
+    def program(*args):
+        trees = []
+        for s in range(sets):
+            got = args[at + s * n:at + (s + 1) * n]
+            trees.append(None if got[0] is None else dict(zip(names, got)))
+        return fn(*args[:at], *trees, *args[at + sets * n:])
+
+    program.__name__ = program.__qualname__ = fn.__name__
+    return program
+
+
+def _jit_pools(cfg: ModelConfig, fn: Callable, at: int, sets: int = 1):
+    """Jit :func:`_pools_apart` of ``fn``, its pools donated."""
+    return jax.jit(_pools_apart(cfg, fn, at, sets),
+                   donate_argnums=donate_argnums(_pool_argnums(cfg, at, sets)))
+
+
+def _apart(cfg: ModelConfig, pools) -> Tuple:
+    """A pool pytree as the programs take it, one argument a pool."""
+    if pools is None:
+        return (None,) * len(_pool_names(cfg))
+    return tuple(pools[name] for name in _pool_names(cfg))
+
+
 def _build_admit(cfg: ModelConfig, ensemble: bool, S: int, n_pages: int,
                  page_size: int, greedy: bool):
     """Prefill + page-commit + first-token sample, one jit per prompt length.
@@ -351,8 +396,8 @@ def _build_admit(cfg: ModelConfig, ensemble: bool, S: int, n_pages: int,
     (their content is already in the pool — same tokens, same params, same
     prefill program ⇒ same KV)."""
 
-    def cont_prefill_admit(params, k_pool, v_pool, tokens, page_ids,
-                           write_mask, key, temperature):
+    def cont_prefill_admit(params, pools, tokens, page_ids, write_mask, key,
+                           temperature):
         _PREFILL_TRACES[0] += 1
         # trace-time host effect: the compile counters mirror the
         # one-executable-per-geometry contract _*_TRACES guard
@@ -376,24 +421,21 @@ def _build_admit(cfg: ModelConfig, ensemble: bool, S: int, n_pages: int,
             a = jnp.pad(a, [(0, 0)] * (a.ndim - 3) + [(0, pad), (0, 0), (0, 0)])
             return a.reshape(a.shape[:-3] + (n_pages, -1, a.shape[-1]))
 
-        k_new, v_new = paged(k_new), paged(v_new)
         sel = write_mask[:, None, None]
-        if ensemble:
-            cur_k = k_pool[:, :, page_ids]
-            cur_v = v_pool[:, :, page_ids]
-            k_pool = k_pool.at[:, :, page_ids].set(jnp.where(sel, k_new, cur_k))
-            v_pool = v_pool.at[:, :, page_ids].set(jnp.where(sel, v_new, cur_v))
-        else:
-            cur_k = k_pool[:, page_ids]
-            cur_v = v_pool[:, page_ids]
-            k_pool = k_pool.at[:, page_ids].set(jnp.where(sel, k_new, cur_k))
-            v_pool = v_pool.at[:, page_ids].set(jnp.where(sel, v_new, cur_v))
+        lead = (slice(None),) * (2 if ensemble else 1)
 
+        def commit(pool, new):
+            cur = pool[lead + (page_ids,)]
+            return pool.at[lead + (page_ids,)].set(
+                jnp.where(sel, paged(new), cur))
+
+        pools = {"k": commit(pools["k"], k_new),
+                 "v": commit(pools["v"], v_new)}
         token0 = _sample_steps(last, key[None], jnp.zeros((1,), jnp.int32),
                                temperature, greedy)[0]
-        return k_pool, v_pool, token0
+        return pools, token0
 
-    return jax.jit(cont_prefill_admit, donate_argnums=donate_argnums((1, 2)))
+    return _jit_pools(cfg, cont_prefill_admit, 1)
 
 
 def _build_chunk(cfg: ModelConfig, ensemble: bool, greedy: bool,
@@ -415,35 +457,27 @@ def _build_chunk(cfg: ModelConfig, ensemble: bool, greedy: bool,
     hit is valid for the draft pool exactly when it is for the verify
     pool."""
 
-    def cont_prefill_chunk(params, draft_params, k_pool, v_pool, dk_pool,
-                           dv_pool, tokens, pos0, table, key, temperature):
+    def cont_prefill_chunk(params, draft_params, pools, dpools, tokens, pos0,
+                           table, key, temperature):
         _PREFILL_TRACES[0] += 1
         obs.get().record_compile("cont_prefill_chunk",
                                  T=int(tokens.shape[-1]))
         if ensemble:
-            def member(p, kp, vp):
-                lg, pools = M.prefill_paged(
-                    p, cfg, tokens, pos0, {"k": kp, "v": vp}, table)
-                return lg, pools["k"], pools["v"]
-
-            lgs, k_pool, v_pool = jax.vmap(member)(params, k_pool, v_pool)
+            lgs, pools = jax.vmap(lambda p, pl_: M.prefill_paged(
+                p, cfg, tokens, pos0, pl_, table))(params, pools)
             last = averaging.balanced_mean(lgs)[:, -1]
         else:
-            lg, pools = M.prefill_paged(
-                params, cfg, tokens, pos0, {"k": k_pool, "v": v_pool}, table)
-            k_pool, v_pool = pools["k"], pools["v"]
+            lg, pools = M.prefill_paged(params, cfg, tokens, pos0, pools,
+                                        table)
             last = lg[:, -1]
         if spec:
-            _, dpools = M.prefill_paged(
-                draft_params, cfg, tokens, pos0,
-                {"k": dk_pool, "v": dv_pool}, table)
-            dk_pool, dv_pool = dpools["k"], dpools["v"]
+            _, dpools = M.prefill_paged(draft_params, cfg, tokens, pos0,
+                                        dpools, table)
         token0 = _sample_steps(last, key[None], jnp.zeros((1,), jnp.int32),
                                temperature, greedy)[0]
-        return k_pool, v_pool, dk_pool, dv_pool, token0
+        return pools, dpools, token0
 
-    return jax.jit(cont_prefill_chunk,
-                   donate_argnums=donate_argnums((2, 3, 4, 5)))
+    return _jit_pools(cfg, cont_prefill_chunk, 2, sets=2)
 
 
 def _chunk_program(cfg: ModelConfig, ensemble: bool, T: int, max_pages: int,
@@ -463,37 +497,32 @@ def _build_decode(cfg: ModelConfig, ensemble: bool, greedy: bool,
     Every operand is traced — token ids, write positions, per-slot sample
     steps, budgets, the active mask, page tables, keys, temperature — so
     the program compiles once per pool geometry and is reused across every
-    admission/retirement the stream ever makes."""
+    admission/retirement the stream ever makes.  It takes each pool as an
+    argument of its own (:func:`_pools_apart`)."""
 
-    def cont_decode(params, k_pool, v_pool, tokens, positions, steps,
-                    budgets, active, page_tables, keys, temperature):
+    def cont_decode(params, pools, tokens, positions, steps, budgets, active,
+                    page_tables, keys, temperature):
         _DECODE_TRACES[0] += 1
         obs.get().record_compile("cont_decode",
                                  slots=int(tokens.shape[0]))
         if ensemble:
-            def member(p, kp, vp):
-                lg, pools = M.decode_step_paged(
-                    p, cfg, tokens, positions, {"k": kp, "v": vp},
-                    page_tables, use_pallas,
-                )
-                return lg, pools["k"], pools["v"]
-
-            lgs, k_pool, v_pool = jax.vmap(member)(params, k_pool, v_pool)
+            lgs, pools = jax.vmap(lambda p, pl_: M.decode_step_paged(
+                p, cfg, tokens, positions, pl_, page_tables, use_pallas,
+            ))(params, pools)
             logits = averaging.balanced_mean(lgs)
         else:
             logits, pools = M.decode_step_paged(
-                params, cfg, tokens, positions, {"k": k_pool, "v": v_pool},
-                page_tables, use_pallas,
+                params, cfg, tokens, positions, pools, page_tables,
+                use_pallas,
             )
-            k_pool, v_pool = pools["k"], pools["v"]
 
         sampled = _sample_steps(logits[:, -1], keys, steps, temperature,
                                 greedy)
         sampled = jnp.where(active, sampled, 0)
         done = active & (steps + 1 >= budgets)
-        return sampled, done, k_pool, v_pool
+        return sampled, done, pools
 
-    return cont_decode
+    return _pools_apart(cfg, cont_decode, 1)
 
 
 def _build_spec_decode(cfg: ModelConfig, ensemble: bool, greedy: bool,
@@ -514,7 +543,7 @@ def _build_spec_decode(cfg: ModelConfig, ensemble: bool, greedy: bool,
         obs.get().record_compile("cont_spec_decode", draft_k=draft_k)
         return inner(*args)
 
-    return cont_spec_decode
+    return _pools_apart(cfg, cont_spec_decode, 2, sets=2)
 
 
 def _programs(cfg: ModelConfig, ensemble: bool, geometry: Tuple,
@@ -529,14 +558,12 @@ def _programs(cfg: ModelConfig, ensemble: bool, geometry: Tuple,
         if draft_k is None:
             _EXEC_CACHE[key] = jax.jit(
                 _build_decode(cfg, ensemble, greedy, use_pallas),
-                donate_argnums=donate_argnums((1, 2)),
-            )
+                donate_argnums=donate_argnums(_pool_argnums(cfg, 1)))
         else:
             _EXEC_CACHE[key] = jax.jit(
                 _build_spec_decode(cfg, ensemble, greedy, use_pallas,
                                    draft_k),
-                donate_argnums=donate_argnums((2, 3, 4, 5)),
-            )
+                donate_argnums=donate_argnums(_pool_argnums(cfg, 2, 2)))
     return _EXEC_CACHE[key]
 
 
@@ -644,6 +671,10 @@ class ContinuousServer:
             raise NotImplementedError(
                 f"kv_dtype={kv_dtype!r} needs the suffix-prefill path, "
                 f"but {M.paged_prefill_supported(cfg)}")
+        if cfg.mla and self.ensemble:
+            raise NotImplementedError(
+                "ensemble serving: the MLA latent pool and its kernel serve "
+                "one model (modes soup and member)")
         self.speculative = bool(speculative)
         self.draft_k = int(draft_k)
         if self.speculative:
@@ -663,19 +694,20 @@ class ContinuousServer:
         if self.ensemble:
             pools = jax.tree_util.tree_map(
                 lambda x: jnp.broadcast_to(x, (n_members,) + x.shape), pools)
-        self._k_pool, self._v_pool = pools["k"], pools["v"]
+        #: the device pools, one pytree (``L.paged_pools_init``)
+        self._pools = pools
 
         # the draft side: the population soup drafts for the ensemble; a
         # soup/member server drafts for itself.  Draft pools mirror the
         # verify pools' geometry under the SAME page tables.
         self._draft_params = None
-        self._dk_pool = self._dv_pool = None
+        self._dpools = None
         if self.speculative:
             self._draft_params = (averaged_params(params) if self.ensemble
                                   else params)
-            dpools = L.paged_pools_init(cfg, num_pages, page_size,
-                                        cfg.num_layers, kv_dtype=kv_dtype)
-            self._dk_pool, self._dv_pool = dpools["k"], dpools["v"]
+            self._dpools = L.paged_pools_init(cfg, num_pages, page_size,
+                                              cfg.num_layers,
+                                              kv_dtype=kv_dtype)
 
         self._pool = _PagePool(num_pages, retain=retain_pages)
         self._slots: List[Optional[_Slot]] = [None] * max_slots
@@ -876,10 +908,10 @@ class ContinuousServer:
                                      self.max_pages, self.page_size,
                                      self.num_pages, self.greedy,
                                      self.kv_dtype, self.speculative)
-            (self._k_pool, self._v_pool, self._dk_pool, self._dv_pool,
-             token0) = program(
-                self.params, self._draft_params, self._k_pool, self._v_pool,
-                self._dk_pool, self._dv_pool, jnp.asarray(chunk),
+            self._pools, self._dpools, token0 = program(
+                self.params, self._draft_params,
+                *_apart(self.cfg, self._pools),
+                *_apart(self.cfg, self._dpools), jnp.asarray(chunk),
                 jnp.int32(pf.pos), jnp.asarray(table), pf.key,
                 jnp.float32(max(self.temperature, 1e-6)),
             )
@@ -963,8 +995,8 @@ class ContinuousServer:
             admit = _admit_program(self.cfg, self.ensemble, S, n_prompt,
                                    self.page_size, self.num_pages,
                                    self.greedy)
-            self._k_pool, self._v_pool, token0 = admit(
-                self.params, self._k_pool, self._v_pool,
+            self._pools, token0 = admit(
+                self.params, *_apart(self.cfg, self._pools),
                 jnp.asarray(req.tokens)[None], jnp.asarray(pages, jnp.int32),
                 jnp.asarray(write_mask), slot_key,
                 jnp.float32(max(self.temperature, 1e-6)),
@@ -1127,18 +1159,19 @@ class ContinuousServer:
         with the pools swapped in."""
         temperature = jnp.float32(max(self.temperature, 1e-6))
         if self.speculative:
-            (sampled, counts, done, self._k_pool, self._v_pool,
-             self._dk_pool, self._dv_pool) = self._decode(
+            (sampled, counts, done, self._pools,
+             self._dpools) = self._decode(
                 self.params, self._draft_params,
-                self._k_pool, self._v_pool, self._dk_pool, self._dv_pool,
+                *_apart(self.cfg, self._pools),
+                *_apart(self.cfg, self._dpools),
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(steps), jnp.asarray(budgets),
                 jnp.asarray(active), jnp.asarray(tables),
                 jnp.stack(keys), temperature,
             )
             return sampled, done, counts
-        sampled, done, self._k_pool, self._v_pool = self._decode(
-            self.params, self._k_pool, self._v_pool,
+        sampled, done, self._pools = self._decode(
+            self.params, *_apart(self.cfg, self._pools),
             jnp.asarray(tokens), jnp.asarray(positions),
             jnp.asarray(steps), jnp.asarray(budgets),
             jnp.asarray(active), jnp.asarray(tables),

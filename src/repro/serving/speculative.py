@@ -67,15 +67,21 @@ def speculative_supported(cfg: ModelConfig) -> Optional[str]:
     """None if speculative decode can serve ``cfg``, else the reason.
 
     Needs everything suffix/chunk prefill needs (the draft pools are
-    populated by the same chunk programs), plus dense MLPs: MoE capacity
-    dispatch is batch-shape-dependent, so a ``B·k``-row verify step would
-    not be bitwise the ``k`` sequential ``B``-row steps it replaces."""
+    populated by the same chunk programs), grouped-query attention (the
+    latent pool has no draft side), and dense MLPs: a ``B·k``-row verify
+    step must be bitwise the ``k`` sequential ``B``-row steps it
+    replaces, which the expert layer's grouped matmuls are not shown to
+    be."""
     reason = M.paged_prefill_supported(cfg)
     if reason is not None:
         return reason
+    if cfg.mla:
+        return ("the MLA latent pool has no draft side: the verify step's "
+                "B*k rows are served by the grouped-query path only")
     if cfg.moe:
-        return ("MoE capacity-factor routing depends on batchmates; the "
-                "batched verify step would break bitwise parity")
+        return ("MoE: the expert layer's grouped matmuls over the verify "
+                "step's B*k rows are not shown bitwise equal to k steps of "
+                "B rows")
     return None
 
 
@@ -84,14 +90,14 @@ def build_speculative_decode(cfg: ModelConfig, ensemble: bool, greedy: bool,
     """The speculative continuous decode step (untraced; the runtime wraps
     it with ``jax.jit`` + donation + trace counters).
 
-    ``program(params, draft_params, k_pool, v_pool, dk_pool, dv_pool,
-    tokens, positions, steps, budgets, active, page_tables, keys,
-    temperature)`` returns ``(sampled (B, k), counts (B,), done (B,),
-    k_pool, v_pool, dk_pool, dv_pool)`` — ``sampled[b, :counts[b]]`` are
-    the emitted tokens; entries past ``counts`` are zero-masked.
+    ``program(params, draft_params, pools, dpools, tokens, positions,
+    steps, budgets, active, page_tables, keys, temperature)`` returns
+    ``(sampled (B, k), counts (B,), done (B,), pools, dpools)`` —
+    ``sampled[b, :counts[b]]`` are the emitted tokens; entries past
+    ``counts`` are zero-masked.
 
-    ``params``/pools are the verify side (stacked population when
-    ``ensemble``); ``draft_params``/``dk/dv_pool`` the single-model draft
+    ``params``/``pools`` are the verify side (stacked population when
+    ``ensemble``); ``draft_params``/``dpools`` the single-model draft
     side.  Page tables are SHARED: the draft pools mirror the verify
     pools' geometry and page allocation, so one host page is one logical
     context slice in both.
@@ -107,9 +113,8 @@ def build_speculative_decode(cfg: ModelConfig, ensemble: bool, greedy: bool,
         raise NotImplementedError(f"speculative decode: {reason}")
     k = int(draft_k)
 
-    def program(params, draft_params, k_pool, v_pool, dk_pool, dv_pool,
-                tokens, positions, steps, budgets, active, page_tables,
-                keys, temperature):
+    def program(params, draft_params, pools, dpools, tokens, positions,
+                steps, budgets, active, page_tables, keys, temperature):
         B = tokens.shape[0]
         # proposals this call may emit per slot: never past the budget,
         # so speculative writes stay inside the page reservation
@@ -131,10 +136,8 @@ def build_speculative_decode(cfg: ModelConfig, ensemble: bool, greedy: bool,
         for j in range(k):
             pos_j, tab_j = masked(j < n_valid, positions + j, page_tables)
             lg, dpools = M.decode_step_paged(
-                draft_params, cfg, cur, pos_j,
-                {"k": dk_pool, "v": dv_pool}, tab_j, use_pallas,
+                draft_params, cfg, cur, pos_j, dpools, tab_j, use_pallas,
             )
-            dk_pool, dv_pool = dpools["k"], dpools["v"]
             inputs.append(cur)
             cur = _sample_steps(lg[:, -1], keys, steps + j, temperature,
                                 greedy)
@@ -147,21 +150,14 @@ def build_speculative_decode(cfg: ModelConfig, ensemble: bool, greedy: bool,
                             jnp.repeat(page_tables, k, axis=0))
         vtok = inputs.reshape(B * k)
         if ensemble:
-            def member(p, kp, vp):
-                lg, pools = M.decode_step_paged(
-                    p, cfg, vtok, vpos, {"k": kp, "v": vp}, vtab,
-                    use_pallas,
-                )
-                return lg, pools["k"], pools["v"]
-
-            lgs, k_pool, v_pool = jax.vmap(member)(params, k_pool, v_pool)
+            lgs, pools = jax.vmap(lambda p, pl_: M.decode_step_paged(
+                p, cfg, vtok, vpos, pl_, vtab, use_pallas,
+            ))(params, pools)
             logits = averaging.balanced_mean(lgs)     # (B*k, 1, V)
         else:
             logits, pools = M.decode_step_paged(
-                params, cfg, vtok, vpos, {"k": k_pool, "v": v_pool}, vtab,
-                use_pallas,
+                params, cfg, vtok, vpos, pools, vtab, use_pallas,
             )
-            k_pool, v_pool = pools["k"], pools["v"]
         lg2d = logits[:, -1].reshape(B, k, -1)
         # v_j sampled exactly as the plain path samples output steps+j
         v = jnp.stack(
@@ -177,6 +173,6 @@ def build_speculative_decode(cfg: ModelConfig, ensemble: bool, greedy: bool,
         sampled = jnp.where(valid2d & (jnp.arange(k)[None, :] < m[:, None]),
                             v, 0)
         done = active & (steps + counts >= budgets)
-        return sampled, counts, done, k_pool, v_pool, dk_pool, dv_pool
+        return sampled, counts, done, pools, dpools
 
     return program

@@ -38,6 +38,11 @@ def use_hints(data_axes: Sequence[str], model_axis: Optional[str] = "model"):
         _state.axes = prev
 
 
+def active() -> bool:
+    """Whether hints are active: code is being traced for a mesh."""
+    return _axes() is not None
+
+
 def constrain(x, kind: str):
     """Attach a sharding constraint if hints are active.
 

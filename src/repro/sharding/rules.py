@@ -39,7 +39,8 @@ def _leaf_name(path) -> str:
 
 def _is_blocks_leaf(path) -> bool:
     return any(
-        hasattr(p, "key") and str(p.key) in ("blocks", "enc_blocks") for p in path
+        hasattr(p, "key") and str(p.key) in ("blocks", "dense_blocks", "enc_blocks")
+        for p in path
     )
 
 

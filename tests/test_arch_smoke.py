@@ -65,7 +65,7 @@ def test_exact_assigned_configs():
     spec = {
         "minitron-8b": (32, 4096, 32, 8, 16384, 256000),
         "llama3.2-3b": (28, 3072, 24, 8, 8192, 128256),
-        "deepseek-v2-lite-16b": (27, 2048, 16, 16, 1408, 102400),
+        "deepseek-v2-lite-16b": (27, 2048, 16, 16, 10944, 102400),
         "whisper-medium": (24, 1024, 16, 16, 4096, 51865),
         "qwen3-4b": (36, 2560, 32, 8, 9728, 151936),
         "hymba-1.5b": (32, 1600, 25, 5, 5504, 32001),
@@ -82,6 +82,9 @@ def test_exact_assigned_configs():
     ds = get_arch("deepseek-v2-lite-16b")
     assert ds.mla and ds.kv_lora_rank == 512 and ds.top_k == 6
     assert ds.n_routed_experts == 64 and ds.n_shared_experts == 2
+    assert ds.moe_d_ff == 1408 and ds.first_dense_layers == 1
+    assert not ds.norm_topk_prob and ds.norm_eps == 1e-6
+    assert ds.rope_scaling.factor == 40 and ds.rope_scaling.mscale_all_dim == 0.707
     kimi = get_arch("kimi-k2-1t-a32b")
     assert kimi.n_routed_experts == 384 and kimi.top_k == 8
     assert get_arch("qwen3-4b").qk_norm

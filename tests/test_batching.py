@@ -574,8 +574,11 @@ def test_unsupported_cache_layouts_rejected():
                       d_ff=64, vocab_size=50, mla=True, kv_lora_rank=16,
                       qk_nope_dim=8, qk_rope_dim=8, v_head_dim=8,
                       dtype="float32")
+    # MLA is served from a latent pool, by one model only
+    members = jax.tree_util.tree_map(lambda x: jnp.stack([x, x]),
+                                     M.init_params(KEY, mla))
     with pytest.raises(NotImplementedError, match="MLA"):
-        batching.ContinuousServer(M.init_params(KEY, mla), mla)
+        batching.ContinuousServer(members, mla, mode="ensemble")
     swa = ModelConfig(num_layers=1, d_model=32, num_heads=2, num_kv_heads=2,
                       d_ff=64, vocab_size=50, window=8, dtype="float32")
     with pytest.raises(NotImplementedError, match="window"):

@@ -125,11 +125,18 @@ def test_moe_dispatch_matches_dense_reference():
 
 
 def test_moe_capacity_drops_overflow():
+    """The capacity dispatch, which runs where sharding hints are active
+    (a mesh with an expert axis), drops what overflows its buffers."""
+    from repro.sharding import hints
+
     cfg = ModelConfig(d_model=16, moe=True, n_routed_experts=4, n_shared_experts=0,
                       top_k=2, moe_d_ff=8, capacity_factor=0.01, dtype="float32")
     p = MOE.moe_init(KEY, cfg)
     x = jax.random.normal(KEY, (4, 64, 16))
-    out, _ = MOE.moe_apply(p, cfg, x)  # almost everything dropped
+    mesh = jax.make_mesh((1,), ("model",), devices=jax.devices()[:1],
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    with jax.set_mesh(mesh), hints.use_hints((), "model"):
+        out, _ = MOE.moe_apply(p, cfg, x)  # almost everything dropped
     assert jnp.all(jnp.isfinite(out))
     # with capacity ~0 most outputs are zero (residual-only)
     frac_zero = float(jnp.mean(jnp.all(out == 0.0, axis=-1)))

@@ -191,9 +191,10 @@ def _decode_args(server):
     arrays = [jnp.asarray(a) for a in host[:-1]]
     arrays += [jnp.stack(host[-1]), jnp.float32(1e-6)]
     if server.speculative:
-        return (server.params, server._draft_params, server._k_pool,
-                server._v_pool, server._dk_pool, server._dv_pool, *arrays)
-    return (server.params, server._k_pool, server._v_pool, *arrays)
+        return (server.params, server._draft_params, server._pools["k"],
+                server._pools["v"], server._dpools["k"], server._dpools["v"],
+                *arrays)
+    return (server.params, server._pools["k"], server._pools["v"], *arrays)
 
 
 def _lowered_train_chunk():
@@ -246,11 +247,11 @@ def _lowered(program):
     if program == "cont_prefill_chunk":
         fn = batching._chunk_program(SERVE_CFG, False, 4, server.max_pages,
                                      4, 16, True)
-        return fn.lower(params, None, server._k_pool, server._v_pool, None,
-                        None, jnp.zeros((4,), jnp.int32), jnp.int32(0),
+        return fn.lower(params, None, server._pools["k"], server._pools["v"],
+                        None, None, jnp.zeros((4,), jnp.int32), jnp.int32(0),
                         jnp.zeros((server.max_pages,), jnp.int32), key, temp)
     fn = batching._admit_program(SERVE_CFG, False, 6, 2, 4, 16, True)
-    return fn.lower(params, server._k_pool, server._v_pool,
+    return fn.lower(params, server._pools["k"], server._pools["v"],
                     jnp.zeros((1, 6), jnp.int32), jnp.ones((2,), jnp.int32),
                     jnp.ones((2,), bool), key, temp)
 

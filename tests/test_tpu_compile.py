@@ -22,7 +22,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import ModelConfig
 from repro.kernels import paged_attention
-from repro.kernels.paged_attention import paged_attention_pallas
+from repro.kernels.paged_attention import (latent_attention_pallas,
+                                           paged_attention_pallas)
 from repro.kernels.wash_shuffle import bucketed_shuffle_pallas
 from repro.models import layers as L
 from repro.models import transformer as M
@@ -172,3 +173,75 @@ def test_serving_programs_update_the_pools_in_place_on_v5e(
         f"{program} needs {mem.temp_size_in_bytes / 1e9:.2f} GB of "
         f"temporaries beside one layer's K pool of {layer_k_bytes / 1e9:.2f}"
         " GB: a layer's pool is copied")
+
+
+# DeepSeek-V2-Lite widths (MLA: latent 512 + rope key 64 shared by 16
+# heads; 16 of 64 experts of 1408 held, 2 shared), one dense and one
+# expert layer, the vocabulary cut as above; pages of 64 positions
+MLA_CFG = ModelConfig(
+    name="deepseek-v2-lite-widths", family="moe", num_layers=2,
+    d_model=2048, num_heads=16, num_kv_heads=16, d_ff=10944,
+    vocab_size=4096, mla=True, kv_lora_rank=512, qk_nope_dim=128,
+    qk_rope_dim=64, v_head_dim=128, moe=True, n_routed_experts=16,
+    router_experts=64, n_shared_experts=2, top_k=6, moe_d_ff=1408,
+    first_dense_layers=1, norm_topk_prob=False, dtype="bfloat16")
+MLA_PAGE_SIZE, MLA_MAX_PAGES = 64, 64
+
+
+def test_latent_attention_compiles_for_v5e(one_chip):
+    """The latent kernel at DeepSeek-V2-Lite widths: 16 heads, rows of
+    576, pages of 64 positions (two a row)."""
+    W = 576
+    shapes = [((SLOTS, 16, W), jnp.float32),
+              ((PAGES, MLA_PAGE_SIZE // 2, 2 * W), jnp.bfloat16),
+              ((SLOTS, MAX_PAGES), jnp.int32), ((SLOTS,), jnp.int32)]
+    _compile(lambda q, pool, pt, lengths: latent_attention_pallas(
+        q, pool, pt, lengths, scale=0.11, interpret=False), one_chip,
+        *shapes)
+
+
+@pytest.mark.parametrize("program", ["decode", "prompt_chunk"])
+def test_latent_serving_programs_update_the_pool_in_place_on_v5e(
+        one_chip, chip_programs, program):
+    """The MLA programs need less scratch memory than one layer's latent
+    pool: the pool keeps the chip's default layout through them (a
+    576-wide minor dimension would not: the chip lays the page axis
+    minor, and every program would relay the pool out and back)."""
+    cfg, B, mp, ps = MLA_CFG, SERVE_SLOTS, MLA_MAX_PAGES, MLA_PAGE_SIZE
+    P = B * mp + 1
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    def arg(shape, dtype=jnp.int32):
+        return on_chip(jax.ShapeDtypeStruct(shape, dtype))
+
+    params = on_chip(jax.eval_shape(
+        lambda: M.init_params(jax.random.key(0), cfg)))
+    pools = on_chip(jax.eval_shape(
+        lambda: L.paged_pools_init(cfg, P, ps, cfg.num_layers)))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    temperature = arg((), jnp.float32)
+    if program == "decode":
+        fn = batching._programs(cfg, False, (B, mp, ps, P), True, True)
+        lowered = fn.lower(params, pools["latent"], arg((B,)), arg((B,)),
+                           arg((B,)), arg((B,)), arg((B,), jnp.bool_),
+                           arg((B, mp)),
+                           arg((B,), key.dtype), temperature)
+    else:
+        fn = batching._chunk_program(cfg, False, CHUNK, mp, ps, P, True)
+        lowered = fn.lower(params, None, pools["latent"], None,
+                           arg((CHUNK,)), arg(()), arg((mp,)),
+                           arg((), key.dtype), temperature)
+    compiled = lowered.compile()
+    if program == "decode":
+        assert "tpu_custom_call" in compiled.as_text()
+    pool = pools["latent"]
+    layer_bytes = pool.size * pool.dtype.itemsize // cfg.num_layers
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer_bytes, (
+        f"{program} needs {mem.temp_size_in_bytes / 1e9:.2f} GB of "
+        f"temporaries beside one layer's latent pool of "
+        f"{layer_bytes / 1e9:.2f} GB: the pool is copied")
