@@ -159,7 +159,6 @@ class _Slot:
     uid: Any
     prompt: np.ndarray
     max_new: int
-    key: jax.Array           # per-request sample key (split(req.key, 1)[0])
     pages: List[int]         # pool pages, prompt-order (shared and owned)
     total_pages: int         # worst-case pages this request can ever hold
     out: List[int]           # sampled tokens so far (out[-1] is pending)
@@ -336,6 +335,13 @@ def _sample_steps(last, keys, steps, temperature, greedy: bool):
         return jax.vmap(
             lambda lg, k: jax.random.categorical(k, lg)
         )(last.astype(jnp.float32) / temperature, ks).astype(jnp.int32)
+
+
+@jax.jit
+def _set_slot_key(keys, slot, key):
+    """The slot-key table with entry ``slot`` set to ``key``: one program
+    for every slot (the index is traced)."""
+    return keys.at[slot].set(key)
 
 
 # ---------------------------------------------------------------------------
@@ -715,7 +721,12 @@ class ContinuousServer:
         self._reserved_slots: set = set()
         self._queue: deque = deque()
         self._results: Dict[Any, Result] = {}
-        self._dummy_key = jax.random.split(jax.random.key(0), 1)[0]
+        # each slot's sample key, on the device: written when a slot is
+        # installed, passed whole to every decode step (an idle slot keeps
+        # whatever key it last held; the active mask ignores it)
+        self._keys = jnp.broadcast_to(
+            jax.random.split(jax.random.key(0), 1)[0], (max_slots,))
+        self._temp = jnp.float32(max(self.temperature, 1e-6))
         geometry = (max_slots, self.max_pages, page_size, num_pages)
         self._decode = _programs(cfg, self.ensemble, geometry, self.greedy,
                                  self.use_pallas, kv_dtype,
@@ -911,9 +922,9 @@ class ContinuousServer:
             self._pools, self._dpools, token0 = program(
                 self.params, self._draft_params,
                 *_apart(self.cfg, self._pools),
-                *_apart(self.cfg, self._dpools), jnp.asarray(chunk),
-                jnp.int32(pf.pos), jnp.asarray(table), pf.key,
-                jnp.float32(max(self.temperature, 1e-6)),
+                *_apart(self.cfg, self._dpools),
+                *jax.device_put((chunk, np.int32(pf.pos), table)),
+                pf.key, self._temp,
             )
             written_before = pf.pos
             pf.pos += T
@@ -936,13 +947,13 @@ class ContinuousServer:
             self._prefills.remove(pf)
             self._reserved_slots.discard(pf.slot_index)
             slot = _Slot(uid=pf.uid, prompt=pf.prompt, max_new=pf.max_new,
-                         key=pf.key, pages=pf.pages,
-                         total_pages=pf.total_pages, out=[token0])
+                         pages=pf.pages, total_pages=pf.total_pages,
+                         out=[token0])
             self.stats["admitted"] += 1
             if pf.max_new == 1:  # prefill-only request: retire immediately
                 self._retire(slot)
             else:
-                self._slots[pf.slot_index] = slot
+                self._install(pf.slot_index, slot, pf.key)
             return True
 
     def _try_admit_legacy(self, req: Request) -> bool:
@@ -997,20 +1008,19 @@ class ContinuousServer:
                                    self.greedy)
             self._pools, token0 = admit(
                 self.params, *_apart(self.cfg, self._pools),
-                jnp.asarray(req.tokens)[None], jnp.asarray(pages, jnp.int32),
-                jnp.asarray(write_mask), slot_key,
-                jnp.float32(max(self.temperature, 1e-6)),
+                *jax.device_put((req.tokens[None],
+                                 np.asarray(pages, np.int32), write_mask)),
+                slot_key, self._temp,
             )
             with obs.get().span("serve.prefill_wait"):
                 token0 = int(token0)
             slot = _Slot(uid=req.uid, prompt=req.tokens, max_new=req.max_new,
-                         key=slot_key, pages=pages, total_pages=total,
-                         out=[token0])
+                         pages=pages, total_pages=total, out=[token0])
             self.stats["admitted"] += 1
             if req.max_new == 1:  # prefill-only request: retire immediately
                 self._retire(slot)
                 return True
-            self._slots[slot_i] = slot
+            self._install(slot_i, slot, slot_key)
             return True
 
     def _try_admit(self, req: Request) -> bool:
@@ -1051,6 +1061,16 @@ class ContinuousServer:
                 self.stats["cancelled"] += 1
                 return True
         return False
+
+    def _install(self, i: int, slot: _Slot, key: jax.Array) -> None:
+        """Put ``slot`` live in slot ``i`` and its sample key in the key
+        table: the table's one write per installed slot, counted as
+        ``serve.slot_key_writes``."""
+        self._slots[i] = slot
+        self._keys = _set_slot_key(self._keys, i, key)
+        tel = obs.get()
+        if tel.enabled:
+            tel.registry.counter("serve.slot_key_writes").inc()
 
     def _admit(self) -> None:
         while self._queue and self._free_slot() is not None:
@@ -1099,8 +1119,9 @@ class ContinuousServer:
         The decode step is timed as the span ``serve.decode_step``, through
         the readback, with one child span per phase: ``decode_prepare``
         (host arrays, page growth), ``decode_dispatch`` (transfers and the
-        call), ``decode_wait`` (the readback), ``decode_commit`` (token
-        appends, retirement)."""
+        call; its attribute ``puts`` counts the host-to-device transfers),
+        ``decode_wait`` (the readback), ``decode_commit`` (token appends,
+        retirement)."""
         before = set(self._results)
         self._admit()
         if self.active_slots == 0:
@@ -1110,8 +1131,8 @@ class ContinuousServer:
         with tel.span("serve.decode_step", slots=self.active_slots):
             with tel.span("serve.decode_prepare"):
                 *inputs, n_spec = self._decode_inputs()
-            with tel.span("serve.decode_dispatch"):
-                out = self._dispatch_decode(*inputs)
+            with tel.span("serve.decode_dispatch") as span:
+                out, span["puts"] = self._dispatch_decode(*inputs)
             with tel.span("serve.decode_wait"):
                 sampled, done, counts = (
                     None if x is None else np.asarray(x) for x in out)
@@ -1122,8 +1143,8 @@ class ContinuousServer:
     def _decode_inputs(self) -> Tuple:
         """The decode step's host arrays, with each live slot's pages grown
         to hold this step's writes: ``(tokens, positions, steps, budgets,
-        active, tables, keys, n_spec)``, ``n_spec`` the proposals per slot
-        of a speculative step."""
+        active, tables, n_spec)``, ``n_spec`` the proposals per slot of a
+        speculative step."""
         B, Pmax = self.max_slots, self.max_pages
         tokens = np.zeros((B,), np.int32)
         positions = np.zeros((B,), np.int32)
@@ -1132,10 +1153,8 @@ class ContinuousServer:
         active = np.zeros((B,), bool)
         tables = np.full((B, Pmax), SCRATCH_PAGE, np.int32)
         n_spec = np.zeros((B,), np.int32)
-        keys = []
         for i, slot in enumerate(self._slots):
             if slot is None:
-                keys.append(self._dummy_key)
                 continue
             if self.speculative:
                 n_spec[i] = min(self.draft_k, slot.max_new - len(slot.out))
@@ -1147,37 +1166,28 @@ class ContinuousServer:
             budgets[i] = slot.max_new
             active[i] = True
             tables[i, :len(slot.pages)] = slot.pages
-            keys.append(slot.key)
         self._sync_pool_stats()
-        return (tokens, positions, steps, budgets, active, tables, keys,
-                n_spec)
+        return tokens, positions, steps, budgets, active, tables, n_spec
 
-    def _dispatch_decode(self, tokens, positions, steps, budgets, active,
-                         tables, keys) -> Tuple:
-        """Enqueue the decode program; returns its device outputs
-        ``(sampled, done, counts)`` (``counts`` None unless speculative)
-        with the pools swapped in."""
-        temperature = jnp.float32(max(self.temperature, 1e-6))
+    def _dispatch_decode(self, *host) -> Tuple:
+        """Send the step's host arrays ``(tokens, positions, steps,
+        budgets, active, tables)`` to the device in one batched put and
+        enqueue the decode program with the slot-key table and the
+        temperature, both already on the device.  Returns the program's
+        device outputs ``(sampled, done, counts)`` (``counts`` None unless
+        speculative), with the pools swapped in, and the number of
+        host-to-device puts made (1, whatever the slot count)."""
+        inputs = (*jax.device_put(host), self._keys, self._temp)
         if self.speculative:
             (sampled, counts, done, self._pools,
              self._dpools) = self._decode(
                 self.params, self._draft_params,
                 *_apart(self.cfg, self._pools),
-                *_apart(self.cfg, self._dpools),
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(steps), jnp.asarray(budgets),
-                jnp.asarray(active), jnp.asarray(tables),
-                jnp.stack(keys), temperature,
-            )
-            return sampled, done, counts
+                *_apart(self.cfg, self._dpools), *inputs)
+            return (sampled, done, counts), 1
         sampled, done, self._pools = self._decode(
-            self.params, *_apart(self.cfg, self._pools),
-            jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(steps), jnp.asarray(budgets),
-            jnp.asarray(active), jnp.asarray(tables),
-            jnp.stack(keys), temperature,
-        )
-        return sampled, done, None
+            self.params, *_apart(self.cfg, self._pools), *inputs)
+        return (sampled, done, None), 1
 
     def _commit_decode(self, sampled, done, counts, n_spec) -> None:
         """Append each live slot's sampled token(s), roll speculative page

@@ -27,14 +27,20 @@ Contracts under test:
     tolerance (program-level), and on the pinned mixed stream emits
     fp32-identical tokens — speculative + int8 compose;
   * a decode step and a prompt chunk write only their own rows of the
-    pools (int8: and rescale only the pages they touch).
+    pools (int8: and rescale only the pages they touch);
+  * the decode step's inputs cost no device op per slot: one batched put
+    of its host arrays, and a device-resident slot-key table written once
+    per installed slot and passed as it stands.
 """
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.models import transformer as M
 from repro.serving import batching
@@ -612,3 +618,133 @@ def test_sampling_requires_per_request_key():
                                        num_pages=16)
     with pytest.raises(ValueError, match="per-request PRNG key"):
         server.submit(batching.Request(0, np.zeros((4,), np.int32), 2))
+
+
+# ---------------------------------------------------------------------------
+# decode-step inputs: one batched put, a device-resident slot-key table
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def telemetry():
+    tel = obs.configure(memory=True)
+    yield tel
+    obs.reset()
+
+
+def _binds_in_next_dispatch(server):
+    """Step ``server`` once; returns the names of the primitives bound
+    while its decode step was dispatched."""
+    from jax._src import core
+
+    names = []
+    bind = core.Primitive.bind
+    dispatch = server._dispatch_decode
+
+    def counted(self, *args, **params):
+        names.append(self.name)
+        return bind(self, *args, **params)
+
+    def spy(*host):
+        core.Primitive.bind = counted
+        try:
+            return dispatch(*host)
+        finally:
+            core.Primitive.bind = bind
+
+    server._dispatch_decode = spy
+    try:
+        server.step()
+    finally:
+        del server._dispatch_decode
+    return names
+
+
+@pytest.mark.parametrize("speculative", [False, True], ids=["plain", "spec"])
+def test_decode_dispatch_work_does_not_grow_with_slots(speculative,
+                                                       telemetry):
+    """The dispatch binds the same primitives with 2 and with 8 live slots
+    (no per-slot ``stack`` of keys) and makes one host-to-device put."""
+    rng = np.random.default_rng(31)
+    reqs = [batching.Request(i, rng.integers(0, 50, (5,)).astype(np.int32),
+                             24) for i in range(8)]
+    server = batching.ContinuousServer(
+        _params(), CFG, page_size=4, max_slots=8, num_pages=96,
+        speculative=speculative, draft_k=2)
+    for r in reqs[:2]:
+        server.submit(r)
+    server.step()
+    server.step()  # the decode program is built: measure warm dispatches
+    few = _binds_in_next_dispatch(server)
+    assert server.active_slots == 2
+    for r in reqs[2:]:
+        server.submit(r)
+    many = _binds_in_next_dispatch(server)
+    assert server.active_slots == 8
+    assert few == many, (few, many)
+    assert not {"concatenate", "expand_dims", "broadcast_in_dim"} & set(
+        many), many
+    spans = telemetry._sinks[-1].named("serve.decode_dispatch")
+    assert len(spans) == 4 and all(s["puts"] == 1 for s in spans)
+
+
+@pytest.mark.parametrize("attn_impl", ["naive", "chunked"],
+                         ids=["suffix", "legacy"])
+def test_slot_key_table_written_once_per_installed_slot(attn_impl,
+                                                        telemetry):
+    """Decode steps with no admission between them pass the same key-table
+    array; each installed slot writes it once (a prefill-only request,
+    retired at admission, writes nothing)."""
+    cfg = dataclasses.replace(CFG, attn_impl=attn_impl)
+    server = batching.ContinuousServer(
+        M.init_params(KEY, cfg), cfg, temperature=0.8, page_size=4,
+        max_slots=3, num_pages=32)
+    assert server.suffix_prefill == (attn_impl == "naive")
+    passed = []
+    decode = server._decode
+
+    def spy(*args):
+        passed.append((args[-2], server.stats["admitted"]))
+        return decode(*args)
+
+    server._decode = spy
+    reqs = _mixed_requests(temperature=0.8)
+    server.run(reqs)
+    same = [a is b for (a, n), (b, m) in zip(passed, passed[1:]) if n == m]
+    assert same and all(same)
+    assert any(a is not b for (a, _), (b, _) in zip(passed, passed[1:]))
+    installed = sum(r.max_new > 1 for r in reqs)
+    assert server.stats["admitted"] == len(reqs)
+    assert telemetry.registry.counter(
+        "serve.slot_key_writes").value == installed
+
+
+def test_sampled_slot_reused_after_cancel_samples_with_its_own_key():
+    """A request admitted into the slot a cancelled request held samples
+    with its own key: both sampled streams equal their solo references."""
+    params = _params()
+    rng = np.random.default_rng(23)
+
+    def request(uid, S, max_new, seed):
+        return batching.Request(
+            uid, rng.integers(0, 50, (S,)).astype(np.int32), max_new,
+            key=jax.random.key(seed))
+
+    a, b, c = request("a", 6, 7, 500), request("b", 9, 8, 501), \
+        request("c", 5, 6, 502)
+    server = batching.ContinuousServer(params, CFG, temperature=0.8,
+                                       page_size=4, max_slots=2, num_pages=32)
+    server.submit(a)
+    server.submit(b)
+    server.step()
+    server.step()
+    freed = [s.uid for s in server._slots].index("b")
+    assert server.cancel("b")
+    server.submit(c)
+    server.step()
+    assert server._slots[freed].uid == "c"
+    out = server.run()
+    assert set(out) == {"a", "c"}
+    for r in (a, c):
+        np.testing.assert_array_equal(_reference(params, r, 0.8),
+                                      out[r.uid].tokens)

@@ -188,8 +188,7 @@ def test_program_spans_reach_a_trace_someone_else_started(tmp_path):
 
 def _decode_args(server):
     *host, _ = server._decode_inputs()
-    arrays = [jnp.asarray(a) for a in host[:-1]]
-    arrays += [jnp.stack(host[-1]), jnp.float32(1e-6)]
+    arrays = [*jax.device_put(host), server._keys, server._temp]
     if server.speculative:
         return (server.params, server._draft_params, server._pools["k"],
                 server._pools["v"], server._dpools["k"], server._dpools["v"],
